@@ -387,7 +387,10 @@ def config_from_args(args) -> RunConfig:
 
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
-    from ddlbench_tpu.distributed import apply_platform, initialize
+    from ddlbench_tpu.distributed import (apply_platform,
+                                          backend_provenance,
+                                          enable_compilation_cache,
+                                          initialize)
 
     if args.nan_policy is not None:
         # deprecated alias for the unified guard surface (warn once per run)
@@ -397,9 +400,10 @@ def main(argv=None) -> int:
               f"{args.nan_policy}{tail}", file=sys.stderr, flush=True)
 
     apply_platform(args.platform)
+    enable_compilation_cache()
     if args.comm_buckets > 1:
-        # async-collective overlap flags must land in XLA_FLAGS before the
-        # first backend touch; no-op on cpu-pinned runs
+        # async-collective overlap flags must land in LIBTPU_INIT_ARGS
+        # before the first backend touch; no-op on cpu-pinned runs
         from ddlbench_tpu.distributed import apply_comm_flags
 
         apply_comm_flags(args.platform)
@@ -412,6 +416,8 @@ def main(argv=None) -> int:
         faults.arm(args.inject)
 
     initialize()  # no-op unless DDLB_* multi-host env is set
+    # a run that found no accelerator and was not asked for cpu stops here
+    backend_provenance(args.platform, "train")
     cfg = config_from_args(args)
     cfg.validate()
 

@@ -1,316 +1,33 @@
-"""jax version-compatibility shims (single home, no copies to drift).
+"""shard_map / VMA helpers (single home, no copies to drift).
 
 The strategies' shard_map code speaks the VMA (varying-manual-axes) type
 system: ``jax.typeof(x).vma`` to read a value's varying axes and
 ``lax.pcast(..., to="varying")`` to align switch branches / scan carries.
-Both arrived well after the oldest jax this repo must run under (the
-baked-in toolchain ships 0.4.x, which has neither ``jax.typeof`` nor
-``lax.pcast``).
-
-Pre-VMA jax tracks the SAME information inverted: shard_map's check_rep
-machinery assigns every value a REPLICATION set (axes the value is known
-replicated over; varying = mesh axes minus rep), aligns values with an
-explicit ``pbroadcast`` op, and — with ``check_rep=True`` — traces user
-code under a RewriteTrace whose tracers expose their rep set through
-``get_replication``. :func:`pcast_varying` uses that to emulate ``pcast``
-exactly: cast only the axes the value is still replicated over, so the
-transpose (a real ``psum``) runs only where mathematically required —
-e.g. the cast on gpipe's stage-sharded/data-replicated params transposes
-to the DP gradient all-reduce over 'data' alone, and values that are
-already fully varying get NO cast (keeping collectives out of
-device-divergent ``lax.switch`` branches, which would otherwise deadlock
-the mesh in the backward pass).
-
-Three stock 0.4.x rules are patched at import (see ``_install_prevma``):
-the pbroadcast check (relaxed to idempotent-cast semantics), the cond
-check (stock demands exact rep equality across branches, including grad
-residuals where one branch saves a constant and another a computed
-value; jax's own rewrite path and-merges instead), and the
-pbroadcast/psum2 transposes (Zero-cotangent handling for
-multiple-results primitives).
 """
 
 from __future__ import annotations
 
-from functools import partial
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
 from jax import lax
 
-_TYPEOF = getattr(jax, "typeof", None)
-_HAS_VMA = _TYPEOF is not None and hasattr(lax, "pcast")
-
-try:  # jax >= 0.4.35 exposes shard_map at top level
-    from jax import shard_map as _jax_shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _jax_shard_map
-
 
 def shard_map(f=None, **kw):
-    """``jax.shard_map``; every strategy imports this one symbol so any
-    future version-specific policy lives in exactly one place."""
+    """``jax.shard_map``, also usable as ``@shard_map(mesh=..., ...)``;
+    every strategy imports this one symbol."""
     if f is None:
-        return lambda g: _jax_shard_map(g, **kw)
-    return _jax_shard_map(f, **kw)
-
-
-def typeof(x):
-    """``jax.typeof`` where available, else the abstract value the old way
-    (``x.aval`` for tracers/arrays, ``jax.core.get_aval`` for literals)."""
-    if _TYPEOF is not None:
-        return _TYPEOF(x)
-    aval = getattr(x, "aval", None)
-    if aval is not None:
-        return aval
-    return jax.core.get_aval(x)
+        return lambda g: jax.shard_map(g, **kw)
+    return jax.shard_map(f, **kw)
 
 
 def vma_of(x) -> Tuple:
-    """The value's varying-manual-axes as a tuple; () on pre-VMA jax (whose
-    avals have no ``vma`` attribute) and outside shard_map."""
-    return tuple(getattr(typeof(x), "vma", ()) or ())
+    """The value's varying-manual-axes as a tuple; () outside shard_map."""
+    return tuple(getattr(jax.typeof(x), "vma", ()) or ())
 
 
 def pcast_varying(v, axes):
     """Mark ``v`` varying over any of ``axes`` it is not already varying
-    over (shard_map branches/carries must agree on VMA types).
-
-    On pre-VMA jax, "not already varying over" is read from the check_rep
-    RewriteTracer's replication set (``get_replication``); values the
-    trace cannot attribute a rep set to (constants created inside the
-    traced function) are fully replicated by definition. The cast is the
-    old ``pbroadcast`` op over exactly the still-replicated axes — the
-    precise analog of ``lax.pcast(..., to="varying")``, including its
-    transpose (psum over the same axes).
-    """
-    if not axes:
-        return v
-    if _HAS_VMA:
-        missing = tuple(a for a in axes if a not in vma_of(v))
-        return lax.pcast(v, missing, to="varying") if missing else v
-    sm = _prevma_shard_map()
-    if sm is None:  # no VMA and no check_rep machinery: nothing to align
-        return v
-    try:
-        sm.get_replication(v)
-    except ValueError:
-        # Trace constant (no rep attribution): replicated over every mesh
-        # axis, and — because const-only subgraphs land in the known/
-        # forward jaxpr — its cast is identity end to end, never a
-        # collective in the backward.
-        return sm.pbroadcast(v, tuple(axes))
-    # Tracers keep their own replication accounting: an explicit
-    # pbroadcast here would transpose to a REAL psum, which inside a
-    # device-divergent lax.switch branch deadlocks the mesh. The lenient
-    # cond/scan check rules (installed in _install_prevma) join the
-    # resulting rep differences the same way jax's own rewrite pass does.
-    return v
-
-
-_SM_MOD: Optional[object] = None
-
-
-def _prevma_shard_map():
-    """The old shard_map module with our compat rules installed, or None
-    when unavailable. Installation happens once, on first use."""
-    global _SM_MOD
-    if _SM_MOD is None:
-        _SM_MOD = _install_prevma()
-    return _SM_MOD if _SM_MOD is not False else None
-
-
-def _install_prevma():
-    try:
-        from jax.experimental import shard_map as sm
-
-        sm.pbroadcast_p, sm.psum2_p, sm.get_replication  # probe the surface
-    except (ImportError, AttributeError):  # pragma: no cover
-        return False
-
-    # pbroadcast check, relaxed to idempotent-cast semantics: stock ERRORS
-    # when a value is already varying over every broadcast axis; pcast
-    # treats that as a no-op. (Belt to get_replication's braces — e.g.
-    # values whose rep the eager/vmap paths cannot attribute.)
-    def _pbroadcast_check(mesh, *in_rep, axes, axis_index_groups):
-        return [(set(mesh.axis_names) if r is None else r) - set(axes)
-                for r in in_rep]
-
-    # register_check()/register_norewrite() are setdefault-only, and the
-    # norewrite entry froze a reference to the stock check at jax import —
-    # replace both registry entries directly.
-    sm._check_rules[sm.pbroadcast_p] = _pbroadcast_check
-    sm._rewrite_rules[sm.pbroadcast_p] = partial(
-        sm._no_rewrite, sm.pbroadcast_p, _pbroadcast_check)
-
-    # standard per-primitive check, relaxed to intersection-join semantics:
-    # stock demands every argument's rep set be IDENTICAL, but our lenient
-    # cond/scan joins below (and the identity pbroadcast transpose) re-walk
-    # rewritten jaxprs under and-merged reps, where a pbroadcast that
-    # aligned two args at trace time no longer produces equal sets — e.g.
-    # tpp's pad_vec pads a 'model'-replicated activation with a scan-carry
-    # zero that the join demoted to fully varying. The sound output rep
-    # under mixed inputs is the intersection (an output can only be known
-    # replicated over axes EVERY input is), which is exactly what jax's own
-    # rewrite pass converges to.
-    def _lenient_standard(prim, mesh, *in_rep, **__):
-        in_rep_ = [r for r in in_rep if r is not None]
-        if not in_rep_:
-            return None
-        out = set(in_rep_[0])
-        for r in in_rep_[1:]:
-            out &= r
-        return out
-
-    for prim, rule in list(sm._check_rules.items()):
-        if getattr(rule, "func", None) is sm._standard_check:
-            sm._check_rules[prim] = partial(_lenient_standard, prim)
-
-    # cond check: stock demands EXACT rep equality across branches —
-    # including grad residuals, where one branch may save a constant (rep
-    # None) and another a computed value (rep set()). jax's own rewrite
-    # path (_cond_rewrite) and-merges branch reps; give the check pass the
-    # same join semantics.
-    cond_p = sm.control_flow.conditionals.cond_p
-
-    def _cond_join(mesh, *in_rep, branches):
-        _, *args_rep = in_rep
-        out = None
-        for br in branches:
-            rep = [set(mesh.axis_names) if r is None else r
-                   for r in sm._check_rep(mesh, br.jaxpr, args_rep)]
-            out = rep if out is None else [a & b for a, b in zip(out, rep)]
-        return out
-
-    sm._check_rules[cond_p] = _cond_join
-
-    # scan check: same story for carries — stock demands carry-in rep ==
-    # carry-out rep exactly; the rewrite pass (_scan_rewrite) runs an
-    # and-merge fixpoint instead. Mirror the fixpoint in the check.
-    scan_p = sm.control_flow.loops.scan_p
-
-    def _scan_join(mesh, *in_rep, jaxpr, num_consts, num_carry, **_):
-        full = set(mesh.axis_names)
-        norm = lambda r: full if r is None else r
-        const_rep, carry_in, xs_rep = sm.split_list(
-            list(in_rep), [num_consts, num_carry])
-        carry_in = [norm(r) for r in carry_in]
-        ys_rep = []
-        for _i in range(1 + num_carry):
-            out_rep = sm._check_rep(
-                mesh, jaxpr.jaxpr, [*const_rep, *carry_in, *xs_rep])
-            carry_out, ys_rep = sm.split_list(list(out_rep), [num_carry])
-            carry_out = [a & norm(b) for a, b in zip(carry_in, carry_out)]
-            if carry_out == carry_in:
-                break
-            carry_in = carry_out
-        return [*carry_in, *[norm(r) for r in ys_rep]]
-
-    sm._check_rules[scan_p] = _scan_join
-
-    # pbroadcast transpose: stock binds psum2 on the cotangents — a REAL
-    # collective. The check_rep rewrite inserts pbroadcasts inside
-    # lax.switch branches (to match branch reps), and cond partial-eval
-    # keeps whole switches in the unknown jaxpr, so those transposes land
-    # INSIDE device-divergent branches where each device would execute a
-    # different collective sequence: guaranteed mesh deadlock. Transpose
-    # as identity instead: each device keeps its LOCAL cotangent, which is
-    # exactly right for the pipeline strategies' stage-local parameters
-    # (only device d executes branch d's compute). What identity cannot
-    # express is an implicit cross-replica gradient all-reduce riding a
-    # cast's transpose — gpipe's dp_replicas path does that, and is
-    # guarded with a clear error on pre-VMA jax (parallel/gpipe.py);
-    # hetero's replica all-reduce is an explicit ppermute ring and stays
-    # correct.
-    Zero = sm.ad_util.Zero
-    sm.ad.deflinear2(sm.pbroadcast_p,
-                     lambda cts, *_, axes, axis_index_groups: cts)
-
-    # psum2 transpose: keep stock semantics (pbroadcast, identity
-    # lowering) but Zero-aware — linear_transpose2's Zero short-circuit
-    # tests the whole cotangent against Zero, which for multiple-results
-    # primitives is a LIST, so symbolic Zeros inside it reach .bind() and
-    # crash.
-    def _psum2_transpose(cts, *_, axes, axis_index_groups):
-        nz = [c for c in cts if type(c) is not Zero]
-        out = iter(sm.pbroadcast_p.bind(
-            *nz, axes=axes,
-            axis_index_groups=axis_index_groups)) if nz else iter(())
-        return [c if type(c) is Zero else next(out) for c in cts]
-
-    sm.ad.deflinear2(sm.psum2_p, _psum2_transpose)
-
-    # Stock _shard_map_transpose mispairs cotangents with in_names: the
-    # backward_pass over the partial-eval'd body returns cts ordered
-    # [residuals..., undefined-args...], which it zips straight against
-    # in_names (ORIGINAL arg order) — wrong whenever the residual list is
-    # not exactly the defined args (i.e. whenever the body computes
-    # anything worth saving). Strategies that grad INSIDE shard_map
-    # (dp/tp/fsdp) never hit this; gpipe/hetero grad THROUGH shard_map and
-    # do. This reimplementation keeps only the undef-arg cotangents and
-    # scatters them back to arg order before the spec mapping.
-    ad, pe, core = sm.ad, sm.pe, sm.core
-
-    def _fixed_shard_map_transpose(out_cts, *args, jaxpr, mesh, in_names,
-                                   out_names, check_rep, rewrite, auto):
-        mb_div = lambda x, y: x / y if y != 1 else x
-        out_cts = [
-            ad.Zero(sm._shard_aval(mesh, ns, x.aval)) if type(x) is ad.Zero
-            else x if rewrite or sm.dtypes.dtype(x) == sm.dtypes.float0
-            else mb_div(x, sm.prod(map(mesh.shape.get,
-                                       sm._unmentioned2(mesh, ns, auto))))
-            for ns, x in zip(out_names, out_cts)]
-        args = [x if type(x) is not ad.UndefinedPrimal else
-                ad.UndefinedPrimal(sm._shard_aval(mesh, ns, x.aval))
-                for ns, x in zip(in_names, args)]
-        all_args, in_tree = sm.tree_flatten((out_cts, args))
-
-        @sm.lu.wrap_init
-        def fun_trans(out_cts, args):
-            undef = list(map(ad.is_undefined_primal, args))
-            res, undefs = sm.partition_list(undef, args)
-            jaxpr_known, jaxpr_unknown, _, _ = pe.partial_eval_jaxpr_nounits(
-                pe.close_jaxpr(jaxpr), undef, False)
-            res_reshaped = core.jaxpr_as_fun(jaxpr_known)(*res)
-            out = ad.backward_pass(
-                jaxpr_unknown.jaxpr, False, (), (*res_reshaped, *undefs),
-                out_cts)
-            undef_cts = iter(list(out)[len(res_reshaped):])
-            out = [next(undef_cts) if u else ad.Zero(a.aval)
-                   for u, a in zip(undef, args)]
-            # Unconditional psum over each input's unmentioned axes (stock
-            # does this only when rewrite=False): with the identity
-            # collective transposes above, every device holds its LOCAL
-            # cotangent, and an input replicated over an axis (dp params,
-            # stage-replicated activations) is consumed by every member of
-            # that axis — its true cotangent is the sum. This is where
-            # e.g. gpipe's hybrid-PPxDP gradient all-reduce happens on
-            # pre-VMA jax, as one uniform top-level collective.
-            out = [
-                ad.Zero(sm._unshard_aval(mesh, ns, x.aval))
-                if type(x) is ad.Zero
-                else jax.lax.psum(x, tuple(sm._unmentioned2(mesh, ns, auto)))
-                for ns, x in zip(in_names, out)]
-            return out
-
-        fun_trans, nz_arg_cts = ad.nonzero_outputs(fun_trans)
-        fun_trans_flat, out_tree = sm.flatten_fun_nokwargs(fun_trans, in_tree)
-        new_in_names = \
-            [n for n, x in zip(out_names, out_cts)
-             if type(x) is not ad.Zero] + \
-            [n for n, x in zip(in_names, args)
-             if type(x) is not ad.UndefinedPrimal]
-
-        def new_out_names_thunk():
-            return tuple(names for names, nz in zip(in_names, nz_arg_cts())
-                         if nz)
-
-        out_flat = sm.shard_map_p.bind(
-            fun_trans_flat, *all_args, mesh=mesh,
-            in_names=tuple(new_in_names),
-            out_names_thunk=new_out_names_thunk, check_rep=check_rep,
-            rewrite=rewrite, auto=auto)
-        return sm.tree_unflatten(out_tree(), out_flat)
-
-    ad.primitive_transposes[sm.shard_map_p] = _fixed_shard_map_transpose
-    return sm
+    over (shard_map branches/carries must agree on VMA types)."""
+    missing = tuple(a for a in axes if a not in vma_of(v))
+    return lax.pcast(v, missing, to="varying") if missing else v

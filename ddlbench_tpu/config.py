@@ -149,6 +149,28 @@ class HardwareModel:
         return levels
 
 
+# Published peaks of the chip a run was MEASURED on, keyed by the exact
+# ``jax.devices()[0].device_kind`` string. Reported utilizations (mfu,
+# hbm_util) divide by these and nothing else: a device that is not in the
+# table is an error, not a default.
+#   "TPU v5 lite" is what a TPU v5e reports through jax 0.9.0 / libtpu
+#   0.0.34 (my chip run, PR 21); 197 TFLOP/s bf16, 819 GB/s HBM, 16 GB —
+#   Google Cloud documentation, "TPU v5e" (the HardwareModel defaults).
+DEVICE_PEAKS = {"TPU v5 lite": HardwareModel()}
+
+
+def device_peaks(device_kind: str) -> HardwareModel:
+    """The published peaks for ``device_kind``; raises on a device the
+    table does not hold."""
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for device_kind {device_kind!r} (known: "
+            f"{sorted(DEVICE_PEAKS)}); add it to config.DEVICE_PEAKS with "
+            f"its source before reporting mfu/hbm_util") from None
+
+
 @dataclasses.dataclass(frozen=True)
 class RunConfig:
     """One benchmark run = dataset x strategy x model x topology.

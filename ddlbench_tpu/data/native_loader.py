@@ -33,9 +33,11 @@ def _load() -> Optional[ctypes.CDLL]:
     if _lib is not None or _lib_failed:
         return _lib
     try:
-        if not os.path.exists(_LIB_PATH):
-            subprocess.run(["make", "-C", _NATIVE_DIR, "-s"], check=True,
-                           capture_output=True, timeout=120)
+        # Always run make (an incremental no-op when current): the .so is
+        # not tracked by git, so an existing one may be stale against the
+        # committed dataloader.cpp and would be called with the wrong ABI.
+        subprocess.run(["make", "-C", _NATIVE_DIR, "-s"], check=True,
+                       capture_output=True, timeout=120)
         lib = ctypes.CDLL(_LIB_PATH)
         lib.dataset_generate.argtypes = [
             ctypes.c_char_p, ctypes.c_int, ctypes.c_int, ctypes.c_int,

@@ -97,11 +97,12 @@ def sync_batch_mean(x, shape, axis, world):
 
     Mirrors the op order of GSPMD's partitioned ``jnp.mean(x, axes,
     dtype=f32)`` — local reduce, cross-replica sum, divide by the GLOBAL
-    count — and defines the matching backward explicitly: the stat
-    cotangents are genuinely partial per device (each device's backward
-    only sees its local rows' contributions), so they are psum'd, divided
-    by the global count, and broadcast over the local rows; exactly the
-    reduce/divide/broadcast sequence of the partitioned transpose.
+    count — and defines the matching backward explicitly. The mean is
+    invariant over ``axis``, so its cotangent arrives already summed over
+    the devices (shard_map's VMA typing psums the per-device partials
+    where the replicated stat meets device-varying rows); the backward
+    divides it by the global count and broadcasts it over the local rows —
+    the reduce/divide/broadcast sequence of the partitioned transpose.
     ``shape`` is the static LOCAL shape of x, ``world`` the axis size.
     """
     axes = tuple(range(len(shape) - 1))
@@ -121,10 +122,15 @@ def _sync_batch_mean_bwd(shape, axis, world, res, ct):
     local = 1
     for a in axes:
         local *= shape[a]
-    ct = lax.psum(ct, axis) / (local * world)
+    ct = ct / (local * world)
     bshape = [1] * len(shape)
     bshape[-1] = shape[-1]
-    return (jnp.broadcast_to(ct.reshape(bshape), shape).astype(res.dtype),)
+    from ddlbench_tpu.compat import pcast_varying
+
+    # the primal rows vary over ``axis``; so must their cotangent
+    return (pcast_varying(
+        jnp.broadcast_to(ct.reshape(bshape), shape).astype(res.dtype),
+        (axis,)),)
 
 
 sync_batch_mean.defvjp(_sync_batch_mean_fwd, _sync_batch_mean_bwd)

@@ -473,6 +473,7 @@ def _flash_fwd_impl(q, k, v, q_offset, k_offset, prefix_len, block_q, block_k,
         ],
         scratch_shapes=scratch,
         interpret=interpret,
+        name="flash_attn_fwd",
         **_grid_params(interpret, streaming),
     )(qr, kr, vr)
     return o.reshape(B, H, Tq, dh), lse
@@ -554,6 +555,7 @@ def _flash_bwd_core(q_offset, k_offset, prefix_len, block_q, block_k,
         out_shape=_out_struct((BH, Tq, dh), q.dtype, qr, kr, vr, gr),
         scratch_shapes=dq_scratch,
         interpret=interpret,
+        name="flash_attn_dq",
         **_grid_params(interpret, dq_streaming),
     )(qr, kr, vr, gr, lse, delta_r)
 
@@ -605,6 +607,7 @@ def _flash_bwd_core(q_offset, k_offset, prefix_len, block_q, block_k,
         ],
         scratch_shapes=dkv_scratch,
         interpret=interpret,
+        name="flash_attn_dkv",
         **_grid_params(interpret, dkv_streaming),
     )(kr, vr, qr, gr, lse, delta_r)
 

@@ -458,6 +458,7 @@ def _fxent_fwd_pallas(h, w, labels, smoothing: float, interpret: bool):
         scratch_shapes=[pltpu.VMEM((br, 1), f32)] * 5
         + [pltpu.VMEM((br, 1), jnp.int32)],
         interpret=interpret,
+        name="fused_xent_fwd",
     )(hp, w, lab2)
 
     lse = lse[:N, 0]
@@ -576,6 +577,7 @@ def _fxent_bwd_pallas(h, w, labels, lses, go, gce, smoothing: float,
         out_shape=_pl_out((Np, D), h.dtype, hp, w, lab2, lse2, coef),
         scratch_shapes=[pltpu.VMEM((br, D), f32)],
         interpret=interpret,
+        name="fused_xent_dh",
     )(hp, w, lab2, lse2, coef)
 
     dw = pl.pallas_call(
@@ -592,6 +594,7 @@ def _fxent_bwd_pallas(h, w, labels, lses, go, gce, smoothing: float,
         out_shape=_pl_out((D, V), f32, hp, w, lab2, lse2, coef),
         scratch_shapes=[pltpu.VMEM((D, bv_dw), f32)],
         interpret=interpret,
+        name="fused_xent_dw",
     )(hp, w, lab2, lse2, coef)
 
     return dh[:N], dw

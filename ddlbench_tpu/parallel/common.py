@@ -240,12 +240,10 @@ def psum_keepgrad(x, axis):
     local backward with the replicated cotangent unchanged: every device
     already holds the same seed (e.g. 1/global_count), and the cross-device
     gradient sum happens once, explicitly, on the gradients themselves
-    (psum_scatter in the dp sharded engine). Stock pre-VMA jax transposes
-    psum-under-grad to another psum, which would scale such gradients by
-    the axis size. Use this for aggregates whose cotangent is replicated
-    (loss sums); aggregates with genuinely per-device partial cotangents
-    (sync-BN batch statistics) need the mirrored reduction in
-    models/layers.sync_batch_mean instead.
+    (psum_scatter in the dp sharded engine). Use this for aggregates whose
+    cotangent is replicated (loss sums); aggregates with genuinely
+    per-device partial cotangents (sync-BN batch statistics) need the
+    mirrored reduction in models/layers.sync_batch_mean instead.
     """
     from jax import lax
 
@@ -259,7 +257,10 @@ def _psum_keepgrad_fwd(x, axis):
 
 
 def _psum_keepgrad_bwd(axis, _res, ct):
-    return (ct,)
+    # the primal was varying over the psum'd axes and the cotangent of the
+    # (invariant) sum is not: custom_vjp wants the bwd output in the
+    # primal's VMA type, and the cast to varying is the identity per device
+    return (vary(ct, (axis,) if isinstance(axis, str) else tuple(axis)),)
 
 
 psum_keepgrad.defvjp(_psum_keepgrad_fwd, _psum_keepgrad_bwd)
@@ -665,8 +666,6 @@ def vary(v, axes):
 
     shard_map's VMA type system requires lax.switch branches and lax.scan
     carries to agree on varying-axes; constants (jnp.zeros) start invariant.
-    On pre-VMA jax (no ``jax.typeof``/``lax.pcast``) this is a no-op — see
-    ddlbench_tpu/compat.py.
     """
     from ddlbench_tpu.compat import pcast_varying
 

@@ -584,15 +584,20 @@ class DPStrategy:
                 # per-bucket all-gather rebuilds the pytree for the forward
                 pshard = params
                 params = gather_params(pshard)
+            # Differentiate w.r.t. a data-VARYING view of the params: the
+            # gradient of a varying loss w.r.t. a replicated (invariant)
+            # input is already its psum over 'data', and the engine's own
+            # wire-dtype collective below must be the one reduction.
+            gparams = jax.tree.map(lambda a: vary(a, ("data",)), params)
             if elastic:
                 # world-invariant canonical-tree path (no BN — validated
                 # at build, so no batch_parallel context is needed)
                 ce, correct, valid, new_state, gr = elastic_grads(
-                    params, state, x, y, smul)
+                    gparams, state, x, y, smul)
             else:
                 with batch_parallel("data", n):
                     ce, correct, valid, new_state, gr = local_grads(
-                        params, state, x, y, smul, qkey)
+                        gparams, state, x, y, smul, qkey)
             if guard is not None:
                 # unscale AFTER the (wire-dtype) collective — the scaled
                 # values are what rides the wire — then fuse the health

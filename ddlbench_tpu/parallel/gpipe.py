@@ -38,8 +38,6 @@ from jax import lax
 from jax.flatten_util import ravel_pytree
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# Version-adaptive shard_map (ddlbench_tpu/compat.py); every strategy
-# imports the one symbol so the policy cannot drift.
 from ddlbench_tpu.compat import shard_map as _shard_map
 
 from ddlbench_tpu.config import RunConfig
@@ -247,9 +245,9 @@ class GPipeStrategy:
         use_fused_eval = ((not train) and last and self.cfg.fused_head_loss
                           and head.fused_eval is not None)
 
-        def branch(param_row, state_row, x_buf, xs, ys, m):
+        def branch(param_row, state_row, x_buf, x_in, ys, m):
             if c == 0:
-                x = lax.dynamic_index_in_dim(xs, m, keepdims=False)
+                x = x_in
             else:
                 x = x_buf[: mb * math.prod(in_shape)].reshape(mb, *in_shape)
             params = cast_params(p_unravel(param_row[:p_len]), cdtype)
@@ -431,9 +429,15 @@ class GPipeStrategy:
                 param_row = lax.dynamic_index_in_dim(param_rows, v,
                                                      keepdims=False)
                 st_row = lax.dynamic_index_in_dim(st_rows, v, keepdims=False)
+                # This tick's input microbatch is picked OUT here, not
+                # inside branch 0: an operand of the switch is saved per
+                # tick for the backward, and the whole [M, mb, ...] input
+                # stacked T times does not fit a chip (resnet50/imagenet
+                # at the default 12 x 24: a 55 GB buffer on a 16 GB v5e).
+                x_in = lax.dynamic_index_in_dim(xs, m, keepdims=False)
                 (y_buf, new_st, loss_mb, ce_mb, aux_mb, corr_mb,
                  corr5_mb) = lax.switch(
-                    chunk, branches, param_row, st_row, x_buf, xs, ys, m
+                    chunk, branches, param_row, st_row, x_buf, x_in, ys, m
                 )
                 st_upd = lax.dynamic_update_index_in_dim(st_rows, new_st, v, 0)
                 st_rows = jnp.where(valid, st_upd, st_rows)

@@ -40,7 +40,7 @@ into the decisions layer:
   autoscaler consumes, the serving analog of overlap.py/bubble.py's
   one-number reductions.
 
-Works on any Chrome trace-event source with the engine's event taxonomy:
+Works on any Chrome trace-event source with the engine's event vocabulary:
 a ``--trace`` file from servebench, a dict, a bare event list, or a live
 :class:`~ddlbench_tpu.telemetry.tracer.Tracer`. SLOs default from the
 trace metadata servebench embeds (``serve.slo_ttft``/``slo_itl``).
@@ -331,7 +331,7 @@ def main(argv=None) -> int:
     p = argparse.ArgumentParser(prog="serveview", description=__doc__)
     p.add_argument("trace", help="Chrome trace-event JSON file written by "
                                  "servebench --trace (or any trace with "
-                                 "the engine's event taxonomy)")
+                                 "the engine's event vocabulary)")
     p.add_argument("--window", type=float, default=None,
                    help="emit the windowed SLO/goodput timeline with "
                         "buckets this many virtual units wide")

@@ -1,10 +1,10 @@
 """Check the auto-dispatch decision table against measured attnbench sweeps.
 
 Reads every ``perf_runs/attnsweep_*.json`` (and legacy attn_crossover.json)
-produced by scripts/tpu_round4.sh's median-of-N sweeps, computes the
-measured winner per (T, B, prefix) cell, and reports where
-``models.transformer.flash_pays_off`` disagrees — the refresh loop VERDICT
-r3 weak #2 asked for: policy from medians, re-checkable every round.
+produced by tools/attnbench.py median-of-N sweeps, computes the measured
+winner per (T, B, prefix) cell, and reports where
+``models.transformer.flash_pays_off`` disagrees: policy from medians,
+re-checkable whenever a sweep is re-run on the chip.
 
 One JSON document on stdout:
     {"cells": [...], "disagreements": [...], "agreement_pct": N}

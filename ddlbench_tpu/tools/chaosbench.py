@@ -312,6 +312,15 @@ def _run_attempt(argv: List[str], log_path: str) -> AttemptResult:
     return res
 
 
+def _result_device(lines: List[str]) -> Optional[Dict[str, Any]]:
+    """The ``device`` record ({platform, kind, count}) of a child's
+    ``result:`` line — what jax reported in the process that ran."""
+    for line in reversed(lines):
+        if line.startswith("result: "):
+            return json.loads(line[len("result: "):]).get("device")
+    return None
+
+
 def _parse_resumed_global(line: Optional[str], steps_per_epoch: int
                           ) -> Optional[int]:
     """'resumed from <dir> epoch E[ step S (mid-epoch)]' -> resumed global step."""
@@ -432,15 +441,14 @@ def run_chaos(args) -> Dict[str, Any]:
     budget = (args.restart_budget if args.restart_budget is not None
               else len(schedule) + 3)
 
-    # actual backend record (shared classification + loud cpu-fallback
-    # warning — distributed.record_provenance); the children run the
-    # compute but on the same machine, so the supervisor's backend is
-    # the fleet's backend
-    from ddlbench_tpu.distributed import record_provenance
+    # The supervisor never touches a jax backend: a chip belongs to one
+    # process at a time, and the children it spawns are the ones that need
+    # it. The device the run executed on is read from the completing
+    # child's ``result:`` line instead (report["device"], below).
+    from ddlbench_tpu.distributed import RECORD_SCHEMA_VERSION
 
-    prov = record_provenance(args.platform, "chaosbench")
     report: Dict[str, Any] = {
-        **prov,
+        "schema_version": RECORD_SCHEMA_VERSION,
         "metric": "chaosbench_recovery",
         "benchmark": args.benchmark, "arch": args.model,
         "framework": args.framework,
@@ -586,6 +594,7 @@ def run_chaos(args) -> Dict[str, Any]:
     chaos_wall = sum(a.wall_s for a in attempts)
     report.update({
         "completed": completed,
+        "device": _result_device(attempts[-1].lines) if completed else None,
         "attempts": len(attempts),
         "restarts": restarts,
         # fired counts, not args.kills: tiny runs collapse duplicate

@@ -22,14 +22,14 @@ import sys
 import time
 
 
-def _bench(fn, sync, repeats: int):
-    fn()  # compile
-    sync()
+def _bench(fn, repeats: int):
+    import jax
+
+    jax.block_until_ready(fn())  # compile
     best = float("inf")
     for _ in range(repeats):
         t0 = time.perf_counter()
-        fn()
-        sync()
+        jax.block_until_ready(fn())
         best = min(best, time.perf_counter() - t0)
     return best
 
@@ -49,11 +49,6 @@ def main(argv=None) -> int:
     p.add_argument("--cache-dtype", default="float32",
                    help="KV-cache storage dtype for the paged variants "
                         "(bfloat16 halves cache traffic; scores stay f32)")
-    p.add_argument("--paged-kernel", default="dots",
-                   choices=("dots", "elementwise"),
-                   help="paged-kernel math formulation (identical numerics; "
-                        "the elementwise form is the Mosaic compile-risk "
-                        "hedge — ops/paged_decode.py)")
     p.add_argument("--skip-uncached", action="store_true",
                    help="skip the slow full-forward reference path")
     p.add_argument("--chunk-prefill", action="store_true",
@@ -61,13 +56,15 @@ def main(argv=None) -> int:
                         "(ops/paged_decode.paged_chunk_attention): Pallas "
                         "kernel vs gathered-page XLA rows over "
                         "--chunk-sizes x --chunk-pages")
-    p.add_argument("--chunk-sizes", default="64,128",
-                   help="chunk-prefill query lengths C to sweep")
+    p.add_argument("--chunk-sizes", default="16,32",
+                   help="chunk-prefill query lengths C to sweep (the Pallas "
+                        "kernel refuses a C x page score product over its "
+                        "VMEM budget — ops/paged_decode.py)")
     p.add_argument("--chunk-pages", default="4,16",
                    help="live page counts to sweep for the chunk rows")
     p.add_argument("--chunk-heads", type=int, default=8)
     p.add_argument("--chunk-dh", type=int, default=64)
-    p.add_argument("--chunk-page-size", type=int, default=64,
+    p.add_argument("--chunk-page-size", type=int, default=32,
                    help="positions per page for the chunk rows")
     p.add_argument("--kv-dtype", default=None,
                    help="comma list among float32,bfloat16,int8: serving-"
@@ -85,16 +82,12 @@ def main(argv=None) -> int:
     import jax.numpy as jnp
 
     from ddlbench_tpu.distributed import (backend_provenance,
-                                          enable_compilation_cache,
-                                          warn_cpu_fallback)
+                                          enable_compilation_cache)
 
     enable_compilation_cache()
-    # actual-backend record on every row + loud cpu-fallback banner (shared
-    # classification — distributed.backend_provenance): without it a hung
-    # TPU init would silently report cpu decode numbers as if on-chip,
-    # exactly the poisoning bench.py/scalebench already guard against
-    prov = backend_provenance(args.platform)
-    warn_cpu_fallback(prov, "decodebench")
+    # actual-backend record on every row (distributed.backend_provenance,
+    # which refuses to measure on a CPU nobody asked for)
+    prov = backend_provenance(args.platform, "decodebench")
 
     from ddlbench_tpu.config import DATASETS
     from ddlbench_tpu.models import init_model
@@ -115,9 +108,6 @@ def main(argv=None) -> int:
     new_tokens = (T - S) * args.batch
 
     import ddlbench_tpu.models.decode as dec
-    from ddlbench_tpu.ops.paged_decode import set_paged_kernel_style
-
-    set_paged_kernel_style(args.paged_kernel)
 
     # "paged": copy-on-write page-table cache + live-page flash decode
     # (ops/paged_decode.py) — the round-4 fast path; "cached": dense KV
@@ -164,24 +154,7 @@ def main(argv=None) -> int:
             fn = jax.jit(lambda: s2s.beam_search_decode(
                 model, params, state, src, T, beam=args.beam,
                 use_cache=cached)[0])
-        out = [None]
-
-        def run():
-            out[0] = fn()
-
-        def sync():
-            jax.tree.map(lambda a: float(jnp.sum(a)), out[0])
-
-        try:
-            dt = _bench(run, sync, args.repeats)
-        except Exception as e:  # e.g. Mosaic rejects a kernel shape: record
-            # the row and keep the sweep alive (lmbench hbm-oom row pattern)
-            print(json.dumps({
-                "tool": "decodebench", "mode": mode, "variant": variant,
-                "error": f"{type(e).__name__}: {str(e).splitlines()[0][:200]}",
-                **prov,
-            }), flush=True)
-            continue
+        dt = _bench(fn, args.repeats)
         print(json.dumps({
             "tool": "decodebench",
             "platform": jax.devices()[0].platform,
@@ -216,7 +189,7 @@ def _chunk_prefill_rows(args, prov) -> None:
     engine produces). The kernel variant is the Pallas multi-query
     flash-decode analog and only compiles on TPU — elsewhere the row is
     recorded as skipped, with the same backend provenance as every other
-    row, so a cpu-fallback run can never masquerade as a chip number."""
+    row."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -257,25 +230,8 @@ def _chunk_prefill_rows(args, prov) -> None:
                 fn = jax.jit(lambda q=q, cache=cache, start=start,
                              uk=use_kernel: paged_chunk_attention(
                                  q, cache, start, npl, page=page,
-                                 use_kernel=uk,
-                                 kernel_style=args.paged_kernel))
-                out = [None]
-
-                def run():
-                    out[0] = fn()
-
-                def sync():
-                    float(jnp.sum(out[0]))
-
-                try:
-                    dt = _bench(run, sync, args.repeats)
-                except Exception as e:  # Mosaic shape rejection etc.
-                    print(json.dumps({
-                        **base,
-                        "error": f"{type(e).__name__}: "
-                                 f"{str(e).splitlines()[0][:200]}",
-                    }), flush=True)
-                    continue
+                                 use_kernel=uk))
+                dt = _bench(fn, args.repeats)
                 print(json.dumps({
                     **base,
                     "tokens_per_sec": round(C / dt, 2),
@@ -335,11 +291,9 @@ def _kv_dtype_rows(args, prov) -> None:
         start = jnp.asarray([(npl - 1) * page], jnp.int32)
         ops = [
             ("decode", 1, lambda uk: paged_attention(
-                q1, cache, pos, npl, page=page, use_kernel=uk,
-                kernel_style=args.paged_kernel)),
+                q1, cache, pos, npl, page=page, use_kernel=uk)),
             ("chunk", C, lambda uk: paged_chunk_attention(
-                qC, cache, start, npl, page=page, use_kernel=uk,
-                kernel_style=args.paged_kernel)),
+                qC, cache, start, npl, page=page, use_kernel=uk)),
         ]
         for op_name, toks, fn0 in ops:
             for variant, use_kernel in (("kernel", True), ("xla", False)):
@@ -355,23 +309,7 @@ def _kv_dtype_rows(args, prov) -> None:
                                    "path)"}), flush=True)
                     continue
                 fn = jax.jit(lambda uk=use_kernel, f=fn0: f(uk))
-                out = [None]
-
-                def run():
-                    out[0] = fn()
-
-                def sync():
-                    float(jnp.sum(out[0]))
-
-                try:
-                    dt_s = _bench(run, sync, args.repeats)
-                except Exception as e:  # Mosaic shape rejection etc.
-                    print(json.dumps({
-                        **base,
-                        "error": f"{type(e).__name__}: "
-                                 f"{str(e).splitlines()[0][:200]}",
-                    }), flush=True)
-                    continue
+                dt_s = _bench(fn, args.repeats)
                 print(json.dumps({
                     **base,
                     "tokens_per_sec": round(toks / dt_s, 2),

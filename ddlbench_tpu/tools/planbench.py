@@ -16,8 +16,7 @@ instead of a claim. On the CPU mesh the ABSOLUTE error is expected to be
 large with ``--profile-mode flops`` (the cost model prices a TPU v5e); use
 ``--profile-mode time`` (the default here) so per-layer costs are measured
 on the machine that executes them and the error mostly reflects the
-schedule/communication model. The on-chip rows land via
-scripts/tpu_round17.sh.
+schedule/communication model. On-chip rows: not measured.
 
 Usage:
     python -m ddlbench_tpu.tools.planbench \

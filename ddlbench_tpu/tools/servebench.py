@@ -573,9 +573,6 @@ def main(argv=None) -> int:
                    help="timeline bucket width in time units "
                         "(with --timeline)")
     p.add_argument("--seed", type=int, default=0)
-    p.add_argument("--paged-kernel", default="dots",
-                   choices=("dots", "elementwise"),
-                   help="paged-kernel math formulation (ops/paged_decode)")
     p.add_argument("--wall-clock", action="store_true",
                    help="also report real elapsed seconds (off by default "
                         "so the JSON stays bitwise-reproducible)")
@@ -605,7 +602,6 @@ def main(argv=None) -> int:
     from ddlbench_tpu.config import DATASETS, ServeConfig
     from ddlbench_tpu.models import init_model
     from ddlbench_tpu.models.zoo import get_model
-    from ddlbench_tpu.ops.paged_decode import set_paged_kernel_style
     from ddlbench_tpu.serve.engine import make_server, supports_serve
     from ddlbench_tpu.serve.workload import make_workload
     from ddlbench_tpu.telemetry.stats import serve_summary
@@ -618,7 +614,6 @@ def main(argv=None) -> int:
     model = get_model(args.model, spec)
     if not supports_serve(model):
         p.error(f"{args.model} has layers without serving support")
-    set_paged_kernel_style(args.paged_kernel)
     params, state, _ = init_model(model, jax.random.key(0))
 
     plo, ptyp, phi = (int(x) for x in args.prompt_lens.split(","))
@@ -968,9 +963,7 @@ def main(argv=None) -> int:
                 "final_replicas": len(server.engines),
                 "requests_lost": lost}
                if autoscale else {}),
-            # actual backend record (shared classification —
-            # distributed.backend_provenance); cpu-fallback rows must be
-            # identifiable as harness validation, not chip numbers
+            # actual backend record (distributed.backend_provenance)
             **prov,
         }
         if args.wall_clock:

@@ -651,12 +651,10 @@ def main(argv=None) -> int:
     import jax
 
     from ddlbench_tpu.distributed import (backend_provenance,
-                                          enable_compilation_cache,
-                                          warn_cpu_fallback)
+                                          enable_compilation_cache)
 
     enable_compilation_cache()
-    prov = backend_provenance(args.platform)
-    warn_cpu_fallback(prov, "servechaos")
+    prov = backend_provenance(args.platform, "servechaos")
 
     from ddlbench_tpu.config import DATASETS, ServeConfig
     from ddlbench_tpu.models import init_model

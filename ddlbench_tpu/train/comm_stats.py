@@ -186,7 +186,8 @@ def comm_stats(strategy) -> Dict[str, float]:
         T = M + S - 1
         out["physical_boundary_bytes"] = (
             2.0 * T * (S - 1) * dp * tp * strategy._act_size * itemsize)
-    else:  # pipeline strategies (gpipe / pipedream)
+    elif name in ("GPipeStrategy", "PipeDreamStrategy",
+                  "ScheduledPipelineStrategy"):
         itemsize = strategy.compute_dtype.itemsize
         M, mb, dp = strategy.num_microbatches, strategy.mb, strategy.dp
         bounds, shapes = strategy.bounds, strategy.shapes
@@ -241,6 +242,9 @@ def comm_stats(strategy) -> Dict[str, float]:
                     out["physical_allreduce_bytes"] = S * (
                         _ring_allreduce_bytes(4.0 * V * Lp, dp)
                         + _ring_allreduce_bytes(4.0 * V * Ls, dp))
+    else:
+        raise NotImplementedError(
+            f"comm_stats has no analytic model for {name}")
     out["total_bytes"] = (out["boundary_bytes"] + out["allreduce_bytes"]
                           + out["reduce_scatter_bytes"]
                           + out["all_gather_bytes"])

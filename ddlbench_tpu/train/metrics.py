@@ -52,11 +52,16 @@ class AverageMeter:
 
 
 def device_memory_gb(device: Optional[Any] = None) -> Dict[str, float]:
-    """Peak/in-use device memory in GB (TPU analog of torch.cuda.memory_stats)."""
-    try:
-        dev = device or jax.local_devices()[0]
-        stats = dev.memory_stats() or {}
-    except Exception:
+    """Peak/in-use device memory in GB (TPU analog of torch.cuda.memory_stats).
+
+    The CPU backend keeps no such statistics (``memory_stats()`` is None
+    there) and reports zeros; a TPU that returns none is an error, not a
+    0.00 GB log line."""
+    dev = device or jax.local_devices()[0]
+    stats = dev.memory_stats()
+    if stats is None:
+        if dev.platform == "tpu":
+            raise RuntimeError(f"{dev} reported no memory_stats()")
         stats = {}
     gb = 1024.0**3
     return {

@@ -3,9 +3,9 @@
 The empirical companion to TranslationData.bucketing_report's analytic
 pricing (VERDICT r3 next #9): bucketed batching is actually implemented —
 one seq2seq model variant per bucket shape sharing ONE parameter set — and
-both modes train the same corpus. On the 1-core CPU the timing ratio is
-noise; the test pins structure and token accounting, the on-chip number
-collects as watcher task bucketbench_r4.
+both modes train the same corpus. On the CPU the timing ratio is noise;
+the test pins structure and token accounting (on-chip number: not
+measured).
 """
 
 import json

@@ -529,48 +529,47 @@ def test_comm_bucket_config_gates():
                     comm_buckets=4).dp_overlap_engine()
 
 
-def test_comm_flags_helper():
-    """distributed.comm_flags: one authoritative flag string; apply is
-    idempotent and refuses cpu-pinned runs (a CPU-only XLA build rejects
-    unknown tpu flags)."""
+def test_comm_flags_helper(monkeypatch):
+    """distributed.comm_flags: one authoritative flag string of flags
+    libtpu 0.0.34 knows, carried in LIBTPU_INIT_ARGS (jaxlib's XLA_FLAGS
+    parser aborts on a tpu-prefixed flag); apply is idempotent and skips
+    runs pinned to another platform."""
     import os
+
+    from jax._src import xla_bridge
 
     from ddlbench_tpu.distributed import apply_comm_flags, comm_flags
 
     flags = comm_flags()
     assert "--xla_tpu_enable_async_collective_fusion=true" in flags
+    # not a libtpu 0.0.34 flag: fatal at backend init (PERF.md, PR 21)
+    assert "windowed_einsum" not in flags
     assert not apply_comm_flags("cpu")
-    saved = os.environ.get("XLA_FLAGS")
-    try:
-        os.environ["XLA_FLAGS"] = "--marker=1"
-        assert apply_comm_flags("tpu")
-        once = os.environ["XLA_FLAGS"]
-        assert "--marker=1" in once and "async_collective_fusion" in once
-        assert apply_comm_flags("tpu")  # idempotent
-        assert os.environ["XLA_FLAGS"] == once
-    finally:
-        if saved is None:
-            os.environ.pop("XLA_FLAGS", None)
-        else:
-            os.environ["XLA_FLAGS"] = saved
-
-
-def test_comm_flags_fail_closed_without_tpu_signal(monkeypatch):
-    """Unpinned + no libtpu plugin must NOT apply: the tpu-prefixed flags
-    are a fatal parse error at backend init on a CPU/GPU-only XLA build,
-    so failing open would crash exactly the machines that can't use them."""
-    import importlib.util
-    import os
-
-    from ddlbench_tpu.distributed import apply_comm_flags
-
-    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    monkeypatch.setenv("LIBTPU_INIT_ARGS", "--marker=1")
     monkeypatch.delenv("XLA_FLAGS", raising=False)
-    monkeypatch.setattr(importlib.util, "find_spec", lambda name: None)
-    assert not apply_comm_flags()
+    monkeypatch.setattr(xla_bridge, "backends_are_initialized",
+                        lambda: False)
+    assert apply_comm_flags("tpu")
+    once = os.environ["LIBTPU_INIT_ARGS"]
+    assert "--marker=1" in once and "async_collective_fusion" in once
     assert "XLA_FLAGS" not in os.environ
-    # with the plugin importable the unpinned path applies
-    monkeypatch.setattr(importlib.util, "find_spec",
-                        lambda name: object() if name == "libtpu" else None)
+    assert apply_comm_flags("tpu")  # idempotent
+    assert os.environ["LIBTPU_INIT_ARGS"] == once
+
+
+def test_comm_flags_refuse_after_backend_init(monkeypatch):
+    """libtpu reads LIBTPU_INIT_ARGS once, at backend init: a call that
+    would have to add a flag after that raises instead of returning with
+    the overlap flags silently not in effect; with every flag already in
+    the environment it is a no-op."""
+    import jax
+
+    from ddlbench_tpu.distributed import apply_comm_flags, comm_flags
+
+    jax.devices()  # the test session's backend is up
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    monkeypatch.delenv("LIBTPU_INIT_ARGS", raising=False)
+    with pytest.raises(RuntimeError, match="already initialized"):
+        apply_comm_flags()
+    monkeypatch.setenv("LIBTPU_INIT_ARGS", comm_flags())
     assert apply_comm_flags()
-    assert "async_collective_fusion" in os.environ.get("XLA_FLAGS", "")

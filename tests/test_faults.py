@@ -300,14 +300,15 @@ def test_distributed_init_retries_with_backoff(monkeypatch, capsys):
     out = capsys.readouterr().out
     assert "attempt 1/3 failed" in out and "retrying in 0.0s" in out
     monkeypatch.setattr(distributed, "_initialized", False)
-    # budget exhausted: the final error surfaces (non-fatally, as before)
+    # budget exhausted: the final error RAISES — a configured multi-host
+    # run that could not join its world must not carry on single-process
     calls.clear()
     monkeypatch.setattr(
         jax.distributed, "initialize",
         lambda **kw: (_ for _ in ()).throw(ConnectionError("still down")))
-    distributed.initialize()
-    assert "jax.distributed.initialize failed" in capsys.readouterr().out
-    monkeypatch.setattr(distributed, "_initialized", False)
+    with pytest.raises(ConnectionError, match="still down"):
+        distributed.initialize()
+    assert not distributed._initialized
 
 
 # ---- resume semantics through the real loop ------------------------------

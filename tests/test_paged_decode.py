@@ -120,24 +120,16 @@ def test_paged_attention_ref_matches_dense(pos, npl):
                                rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.parametrize("style", ["dots", "elementwise"])
 @pytest.mark.parametrize("pos,npl", [(3, 1), (10, 3), (15, 4)])
-def test_paged_attention_kernel_matches_ref(pos, npl, style):
-    """Both kernel math formulations (the batched-dot form and the
-    Mosaic-compile-risk elementwise hedge) match the jnp oracle."""
-    from ddlbench_tpu.ops.paged_decode import set_paged_kernel_style
-
+def test_paged_attention_kernel_matches_ref(pos, npl):
+    """The flash-decode kernel (interpret mode) matches the jnp oracle."""
     cache = paged_cache_init(ROWS, L, H, DH, jnp.float32, page=PAGE)
     cache = paged_prefill_write(cache, _rand(5, ROWS, L, H, DH),
                                 _rand(6, ROWS, L, H, DH), page=PAGE)
     q = _rand(7, ROWS, H, DH)
     ref = _paged_attention_ref(q, cache, pos, npl, page=PAGE)
-    set_paged_kernel_style(style)
-    try:
-        out = paged_attention(q, cache, pos, npl, page=PAGE, interpret=True,
-                              use_kernel=True)
-    finally:
-        set_paged_kernel_style("dots")
+    out = paged_attention(q, cache, pos, npl, page=PAGE, interpret=True,
+                          use_kernel=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
 
@@ -159,14 +151,11 @@ def _serve_chunk_cache(npages_pool, rows, npl, seed=30):
     return {**pool, "table": table}
 
 
-@pytest.mark.parametrize("start,npl,C,style", [
-    (0, 1, 4, "dots"), (8, 3, 4, "dots"), (4, 3, 8, "dots"),
-    (8, 3, 4, "elementwise"),  # the Mosaic hedge shares one shape's pin
-])
-def test_paged_chunk_attention_kernel_matches_ref(start, npl, C, style):
+@pytest.mark.parametrize("start,npl,C", [(0, 1, 4), (8, 3, 4), (4, 3, 8)])
+def test_paged_chunk_attention_kernel_matches_ref(start, npl, C):
     """The chunked-prefill kernel (multi-query flash-decode analog) matches
-    the gathered-page XLA reference through a shuffled serving table, for
-    both math formulations, within the flash-decode pin's tolerance."""
+    the gathered-page XLA reference through a shuffled serving table,
+    within the flash-decode pin's tolerance."""
     from ddlbench_tpu.ops.paged_decode import (_paged_chunk_attention_ref,
                                                paged_chunk_attention)
 
@@ -175,10 +164,31 @@ def test_paged_chunk_attention_kernel_matches_ref(start, npl, C, style):
     q = _rand(33, rows, H, C, DH)
     ref = _paged_chunk_attention_ref(q, cache, start, npl, page=PAGE)
     out = paged_chunk_attention(q, cache, start, npl, page=PAGE,
-                                interpret=True, use_kernel=True,
-                                kernel_style=style)
+                                interpret=True, use_kernel=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
+
+
+def test_paged_chunk_kernel_refuses_oversized_score_product():
+    """A compiled (non-interpret) chunk call whose [H, C, page, dh] score
+    product cannot fit scoped VMEM is refused by name at trace time — on
+    a TPU nothing may route to the jnp reference instead. The serving
+    default (chunk = page = 16 at transformer_m's H=12, dh=64) is far
+    inside the budget."""
+    from ddlbench_tpu.ops.paged_decode import (CHUNK_PRODUCT_MAX_BYTES,
+                                               _require_chunk_fits_vmem,
+                                               paged_chunk_attention)
+
+    _require_chunk_fits_vmem(12, 16, 16, 64)
+    assert 4 * 12 * 16 * 16 * 64 < CHUNK_PRODUCT_MAX_BYTES
+    with pytest.raises(ValueError, match="smaller prefill chunk"):
+        _require_chunk_fits_vmem(8, 64, 64, 64)  # ran out of VMEM on v5e
+    cache = _serve_chunk_cache(4, 1, 1)
+    cache = {k: (jnp.zeros((4, 64, 8, 64), jnp.float32)
+                 if k.startswith("pool") else v) for k, v in cache.items()}
+    q = jnp.zeros((1, 8, 64, 64), jnp.float32)
+    with pytest.raises(ValueError, match="paged_chunk_attention"):
+        paged_chunk_attention(q, cache, 0, 1, page=64, use_kernel=True)
 
 
 def test_paged_chunk_attention_per_row_start():
@@ -354,23 +364,21 @@ def test_span_write_f32_and_overflow_to_scratch():
     assert np.any(np.asarray(out["pool_k"])[0] != 0)
 
 
-@pytest.mark.parametrize("style", ["dots", "elementwise"])
-def test_quantized_flash_decode_kernel_matches_ref(style):
+def test_quantized_flash_decode_kernel_matches_ref():
     """Fused-dequant flash-decode kernel (interpret mode) vs the XLA
-    reference on an int8 pool, both math formulations, within the
-    existing flash-decode tolerance."""
+    reference on an int8 pool, within the existing flash-decode
+    tolerance."""
     cache, _, _ = _quant_cache(seed=85)
     q = _rand(86, 2, H, DH)
     pos = jnp.asarray([11, 7], jnp.int32)
     ref = _paged_attention_ref(q, cache, pos, 3, page=PAGE)
     out = paged_attention(q, cache, pos, 3, page=PAGE, interpret=True,
-                          use_kernel=True, kernel_style=style)
+                          use_kernel=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.parametrize("style", ["dots", "elementwise"])
-def test_quantized_chunk_kernel_matches_ref(style):
+def test_quantized_chunk_kernel_matches_ref():
     """Fused-dequant chunk-prefill kernel vs the XLA reference on an int8
     pool at per-row starts (the speculative verify read path)."""
     from ddlbench_tpu.ops.paged_decode import (_paged_chunk_attention_ref,
@@ -382,8 +390,7 @@ def test_quantized_chunk_kernel_matches_ref(style):
     starts = jnp.asarray([4, 7], jnp.int32)
     ref = _paged_chunk_attention_ref(q, cache, starts, 3, page=PAGE)
     out = paged_chunk_attention(q, cache, starts, 3, page=PAGE,
-                                interpret=True, use_kernel=True,
-                                kernel_style=style)
+                                interpret=True, use_kernel=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
 

@@ -183,7 +183,7 @@ def test_latency_summary_and_step_stats():
 
 def _tiny_cfg(**kw):
     # lenet, not resnet18: these tests pin TELEMETRY plumbing (span
-    # taxonomy, JSONL/scraper round-trip, tracing neutrality), which is
+    # vocabulary, JSONL/scraper round-trip, tracing neutrality), which is
     # arch-independent — the smallest conv net halves the compile bill of
     # the two heaviest tier-1 telemetry tests (ROADMAP item 5 budget)
     base = dict(benchmark="mnist", strategy="single", arch="lenet",

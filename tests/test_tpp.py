@@ -90,11 +90,9 @@ def test_tpp_matches_gpipe_loss_trajectory(monkeypatch):
     replicated-leaf gradient all-reduce — a missing LN-grad psum diverges
     the trajectory within a step or two).
 
-    Tier-1 since ISSUE 7 (no slow mark): tpp was dead at HEAD on jax
-    0.4.37 — the pre-VMA rep re-checks rejected mixed-rep `pad` args
-    (compat.py lenient standard check) — and now that it rides the
-    schedule runtime's timetable the integration must stay green in the
-    commit gate, not hidden behind --runslow. Runs on the suite's shared
+    Tier-1 since ISSUE 7 (no slow mark): now that tpp rides the schedule
+    runtime's timetable the integration must stay green in the commit
+    gate, not hidden behind --runslow. Runs on the suite's shared
     TINY_LM shapes (T=32, vocab 64): the sliced-matmul/psum math this
     pins is shape-independent, and the synthtext T=1024 variant cost
     ~95 s of the tier-1 wall (ROADMAP item 5) — the full-size shapes
