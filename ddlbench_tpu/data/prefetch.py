@@ -117,23 +117,16 @@ class EpochStream:
 
     def _fetch(self, step: int) -> Fetched:
         # Telemetry (telemetry/tracer.py): the producer's two phases —
-        # host-side batch production and shard/device_put — become separate
-        # spans on the producer thread's track, so an input-bound epoch
-        # shows WHERE the producer spends its time. Disabled: one flag
-        # check, no clock reads.
+        # host-side batch production and shard/device_put — are separate
+        # spans on the producer thread's track (ddl/batch_produce and
+        # ddl/shard_device_put in a profiler trace), so an input-bound
+        # epoch shows WHERE the producer spends its time.
         tr = get_tracer()
-        if not tr.enabled:
-            bx, by = self._data.batch(self._epoch, step, train=self._train)
-            batch = self._shard_fn(bx, by)
-            return Fetched(batch, (bx, by) if self._keep_raw else None)
         args = {"epoch": self._epoch, "step": step, "train": self._train}
-        t0 = time.perf_counter_ns()
-        bx, by = self._data.batch(self._epoch, step, train=self._train)
-        t1 = time.perf_counter_ns()
-        batch = self._shard_fn(bx, by)
-        t2 = time.perf_counter_ns()
-        tr.complete("batch_produce", t0, t1, args)
-        tr.complete("shard_device_put", t1, t2, args)
+        with tr.span("batch_produce", **args):
+            bx, by = self._data.batch(self._epoch, step, train=self._train)
+        with tr.span("shard_device_put", **args):
+            batch = self._shard_fn(bx, by)
         return Fetched(batch, (bx, by) if self._keep_raw else None)
 
     def _put(self, item) -> bool:
@@ -186,36 +179,29 @@ class EpochStream:
         if self._start + self._served >= self._steps:
             self.close()
             raise StopIteration
-        tr = get_tracer()
-        if self._queue is None:  # synchronous (depth 0): inline fetch is the stall
+        # the consumer-side blocking wait on the ring (or the inline fetch
+        # in synchronous mode) — the stall scalar, visible as a span on the
+        # consuming thread's timeline (ddl/ring_wait in a profiler trace)
+        with get_tracer().span("ring_wait", epoch=self._epoch,
+                               step=self._start + self._served,
+                               train=self._train):
             t0 = time.perf_counter_ns()
-            if self._ff_pending:
-                self._fast_forward()
-            item = self._fetch(self._start + self._served)
-            t1 = time.perf_counter_ns()
-            self.stall_s += (t1 - t0) / 1e9
-        else:
-            t0 = time.perf_counter_ns()
-            step, item = self._get_or_fail()
-            t1 = time.perf_counter_ns()
-            self.stall_s += (t1 - t0) / 1e9
-            if step == _ERROR:
-                self.close()
-                # TrainingFailure with the producer's exception CHAINED, so
-                # the consumer-side abort carries the original traceback
-                # (a dead producer must not surface only as a watchdog
-                # timeout or an anonymous hang)
-                raise TrainingFailure(
-                    f"prefetch producer failed in epoch {self._epoch}: "
-                    f"{item}") from item
-        if tr.enabled:
-            # the consumer-side blocking wait on the ring (or the inline
-            # fetch in synchronous mode) — today's stall scalar, visible
-            # as spans on the consuming thread's timeline
-            tr.complete("ring_wait", t0, t1,
-                        {"epoch": self._epoch,
-                         "step": self._start + self._served,
-                         "train": self._train})
+            if self._queue is None:  # synchronous (depth 0): inline fetch
+                if self._ff_pending:
+                    self._fast_forward()
+                step, item = None, self._fetch(self._start + self._served)
+            else:
+                step, item = self._get_or_fail()
+            self.stall_s += (time.perf_counter_ns() - t0) / 1e9
+        if step == _ERROR:
+            self.close()
+            # TrainingFailure with the producer's exception CHAINED, so the
+            # consumer-side abort carries the original traceback (a dead
+            # producer must not surface only as a watchdog timeout or an
+            # anonymous hang)
+            raise TrainingFailure(
+                f"prefetch producer failed in epoch {self._epoch}: "
+                f"{item}") from item
         self._served += 1
         if self._watchdog is not None:
             self._watchdog.kick()

@@ -33,6 +33,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ddlbench_tpu.telemetry import scopes
+
 Params = Any
 State = Any
 Shape = Tuple[int, ...]
@@ -291,11 +293,14 @@ def apply_slice(layers: Sequence[Layer], params, states, x, train: bool,
     the pipeline strategies' per-(microbatch, stage) cfg.remat_stages."""
     new_states = []
     for layer, p, s in zip(layers, params, states):
-        if remat:
-            x, s2 = jax.checkpoint(
-                functools.partial(layer.apply, train=train))(p, s, x)
-        else:
-            x, s2 = layer.apply(p, s, x, train)
+        # the layer instance's scope: every device op of this layer carries
+        # layer.name on its op_name path (telemetry/scopes.py)
+        with scopes.scope(layer.name):
+            if remat:
+                x, s2 = jax.checkpoint(
+                    functools.partial(layer.apply, train=train))(p, s, x)
+            else:
+                x, s2 = layer.apply(p, s, x, train)
         new_states.append(s2)
     return x, new_states
 
@@ -334,6 +339,7 @@ def _conv_out_hw(h, w, kh, kw, stride, padding):
 # Stateless primitive helpers used *inside* composite layers.
 # ---------------------------------------------------------------------------
 
+@scopes.scope(scopes.CONV)
 def conv2d(x, kernel, stride=1, padding="SAME", groups=1):
     return lax.conv_general_dilated(
         x,
@@ -345,6 +351,7 @@ def conv2d(x, kernel, stride=1, padding="SAME", groups=1):
     )
 
 
+@scopes.scope(scopes.BN)
 def batchnorm(p, s, x, train: bool):
     """Returns (y, new_state). p = {scale, bias}; s = {mean, var}.
 
@@ -425,6 +432,7 @@ def max_pool(name: str, window: int = 2, stride: int | None = None, padding: str
         oh, ow = _conv_out_hw(h, w, window, window, stride, padding)
         return {}, {}, (oh, ow, c)
 
+    @scopes.scope(scopes.POOL)
     def apply(p, s, x, train):
         y = lax.reduce_window(
             x, -jnp.inf, lax.max,
@@ -446,6 +454,7 @@ def avg_pool(name: str, window: int = 3, stride: int = 1,
         oh, ow = _conv_out_hw(h, w, window, window, stride, padding)
         return {}, {}, (oh, ow, c)
 
+    @scopes.scope(scopes.POOL)
     def apply(p, s, x, train):
         y = lax.reduce_window(
             x, 0.0, lax.add,
@@ -487,6 +496,7 @@ def global_avg_pool(name: str = "gap") -> Layer:
         h, w, c = in_shape
         return {}, {}, (c,)
 
+    @scopes.scope(scopes.POOL)
     def apply(p, s, x, train):
         return jnp.mean(x, axis=(1, 2)), s
 
@@ -514,6 +524,7 @@ def dense(name: str, out_features: int, relu: bool = False, dropout: float = 0.0
         w, b = _linear_init(key, cin, out_features)
         return {"w": w, "b": b}, {}, (out_features,)
 
+    @scopes.scope(scopes.FC)
     def apply(p, s, x, train):
         x = x.reshape(x.shape[0], -1)
         y = x @ p["w"].astype(x.dtype) + p["b"].astype(x.dtype)

@@ -47,6 +47,7 @@ from ddlbench_tpu.models.transformer import (
     layer_norm,
     lm_head,
 )
+from ddlbench_tpu.telemetry import scopes
 
 _VARIANTS = {
     # every other block is MoE (Switch/GShard convention)
@@ -128,6 +129,7 @@ def _expert_ffn(pe, x):
     return y + pe["b2"][:, None, :].astype(x.dtype)
 
 
+@scopes.scope(scopes.MLP)
 def moe_mlp(p, x, capacity_factor: float):
     """Switch MoE feed-forward over x [B, T, d]; returns [B, T, d].
 

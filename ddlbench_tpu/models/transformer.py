@@ -23,6 +23,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ddlbench_tpu.models.layers import Layer, LayerModel, axis_context
+from ddlbench_tpu.telemetry import scopes
 
 LN_EPS = 1e-5
 
@@ -109,6 +110,7 @@ def tp_split_layer_params(p, n: int):
     return shards, repl
 
 
+@scopes.scope(scopes.LN)
 def layer_norm(p, x):
     """f32-accumulated LayerNorm over the feature axis, compute-dtype out."""
     mean = jnp.mean(x, axis=-1, keepdims=True, dtype=jnp.float32)
@@ -150,6 +152,7 @@ def embed(name: str, vocab: int, d_model: int, max_len: int) -> Layer:
         }
         return p, {}, (T, d_model)
 
+    @scopes.scope(scopes.EMBED)
     def apply(p, s, x, train):
         # x: [B, T] int32 (T = local shard length under sequence parallelism)
         pos, _ = shard_positions(p["pos"], x.shape[1])
@@ -444,27 +447,30 @@ def attention_sublayer(p, x, n_heads: int, prefix_len: int = 0):
             f"tp_size={tp[1]}")
         n_local = n_heads // tp[1]
     h = layer_norm(p["ln1"], x)
-    qkv = h @ p["wqkv"].astype(x.dtype)
-    q, k, v = jnp.split(qkv, 3, axis=-1)
+    # qkv projection, attention core (the flash kernels on TPU), output
+    # projection and the residual add: one kind; its LayerNorm is under ln
+    with scopes.scope(scopes.ATTN):
+        qkv = h @ p["wqkv"].astype(x.dtype)
+        q, k, v = jnp.split(qkv, 3, axis=-1)
 
-    def heads(t):
-        return t.reshape(B, T, n_local, dh).transpose(0, 2, 1, 3)
+        def heads(t):
+            return t.reshape(B, T, n_local, dh).transpose(0, 2, 1, 3)
 
-    axis = _seq_axis()
-    if axis is None:
-        o = causal_attention(heads(q), heads(k), heads(v),
-                             prefix_len=prefix_len)
-    else:
-        assert tp is None, (
-            "ring (sequence-parallel) attention composed with tensor "
-            "parallelism is not supported")
-        o = ring_attention(heads(q), heads(k), heads(v), axis,
-                           prefix_len=prefix_len)
-    o = o.transpose(0, 2, 1, 3).reshape(B, T, n_local * dh)
-    proj = o @ p["wo"].astype(x.dtype)
-    if sliced:
-        proj = lax.psum(proj, tp[0])
-    return x + proj
+        axis = _seq_axis()
+        if axis is None:
+            o = causal_attention(heads(q), heads(k), heads(v),
+                                 prefix_len=prefix_len)
+        else:
+            assert tp is None, (
+                "ring (sequence-parallel) attention composed with tensor "
+                "parallelism is not supported")
+            o = ring_attention(heads(q), heads(k), heads(v), axis,
+                               prefix_len=prefix_len)
+        o = o.transpose(0, 2, 1, 3).reshape(B, T, n_local * dh)
+        proj = o @ p["wo"].astype(x.dtype)
+        if sliced:
+            proj = lax.psum(proj, tp[0])
+        return x + proj
 
 
 def transformer_block(name: str, d_model: int, n_heads: int, mlp_ratio: int = 4,
@@ -495,14 +501,17 @@ def transformer_block(name: str, d_model: int, n_heads: int, mlp_ratio: int = 4,
 
     def mlp(p, x):
         h = layer_norm(p["ln2"], x)
-        h = jax.nn.gelu(h @ p["w1"].astype(x.dtype) + p["b1"].astype(x.dtype))
-        proj = h @ p["w2"].astype(x.dtype)
-        tp = _tp_ctx()
-        # row-parallel psum ONLY when this shard holds a column slice (see
-        # attention_sublayer — replicated layers compute the full MLP)
-        if tp is not None and p["w1"].shape[1] < mlp_ratio * d_model:
-            proj = lax.psum(proj, tp[0])
-        return x + proj + p["b2"].astype(x.dtype)
+        with scopes.scope(scopes.MLP):
+            h = jax.nn.gelu(h @ p["w1"].astype(x.dtype)
+                            + p["b1"].astype(x.dtype))
+            proj = h @ p["w2"].astype(x.dtype)
+            tp = _tp_ctx()
+            # row-parallel psum ONLY when this shard holds a column slice
+            # (see attention_sublayer — replicated layers compute the full
+            # MLP)
+            if tp is not None and p["w1"].shape[1] < mlp_ratio * d_model:
+                proj = lax.psum(proj, tp[0])
+            return x + proj + p["b2"].astype(x.dtype)
 
     def prefill(p, s, cache, x, start):
         x, cache = attn_prefill_op(p, x, cache, n_heads, prefix_len, start)
@@ -779,7 +788,8 @@ def lm_head(name: str, vocab: int) -> Layer:
 
     def apply(p, s, x, train):
         h = layer_norm(p["ln_f"], x)
-        return h @ p["head"].astype(x.dtype), s
+        with scopes.scope(scopes.HEAD):
+            return h @ p["head"].astype(x.dtype), s
 
     def fused_loss(p, x, labels, smoothing):
         # Projection + CE fused per row chunk: the [B*T, vocab] logits never

@@ -15,7 +15,10 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from ddlbench_tpu.telemetry import scopes
 
+
+@scopes.scope(scopes.LOSS)
 def cross_entropy_loss(logits: jax.Array, labels: jax.Array,
                        smoothing: float = 0.0) -> jax.Array:
     """Mean CE over valid label positions; works for classification
@@ -38,6 +41,7 @@ def cross_entropy_loss(logits: jax.Array, labels: jax.Array,
     return jnp.sum(nll * mask) / jnp.maximum(1.0, jnp.sum(mask))
 
 
+@scopes.scope(scopes.LOSS)
 def correct_and_count(logits: jax.Array, labels: jax.Array):
     """(correct int32, valid-position count int32) for eval accumulation."""
     ok = (jnp.argmax(logits, axis=-1) == labels) & (labels >= 0)
@@ -187,6 +191,7 @@ def make_optimizer(cfg):
         def init(params, step_like=None):
             return {"m": zeros(params)}
 
+        @scopes.scope(scopes.OPTIMIZER)
         def update(params, grads, state, lr):
             def upd(p, g, m):
                 g = g.astype(p.dtype)
@@ -209,6 +214,7 @@ def make_optimizer(cfg):
                 else jnp.zeros(step_like, jnp.int32))
         return {"m": zeros(params), "v": zeros(params), "step": step}
 
+    @scopes.scope(scopes.OPTIMIZER)
     def update(params, grads, state, lr):
         step = state["step"] + 1
         stepf = step.astype(jnp.float32)
@@ -701,8 +707,12 @@ def fused_slice_loss_sums(layers, params_cast, states, x_cast, labels,
 
     h, new_states = apply_slice(layers[:-1], params_cast[:-1], states[:-1],
                                 x_cast, True, remat)
-    obj_sum, ce_sum, correct = layers[-1].fused_loss(
-        params_cast[-1], h, labels, smoothing)
+    # the head instance is applied here and not by apply_slice, so its
+    # scope is opened here: the fused-xent kernels sit under
+    # <head instance>/loss (projection and cross entropy are one kernel)
+    with scopes.scope(layers[-1].name), scopes.scope(scopes.LOSS):
+        obj_sum, ce_sum, correct = layers[-1].fused_loss(
+            params_cast[-1], h, labels, smoothing)
     return obj_sum, ce_sum, correct, new_states + [states[-1]]
 
 

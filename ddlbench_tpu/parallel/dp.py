@@ -102,6 +102,7 @@ from ddlbench_tpu.config import RunConfig
 from ddlbench_tpu.models.layers import LayerModel, init_model
 from ddlbench_tpu.parallel.common import make_optimizer
 from ddlbench_tpu.parallel.single import TrainState
+from ddlbench_tpu.telemetry import scopes
 
 
 def make_data_mesh(num_devices: int, devices: Optional[Sequence[jax.Device]] = None) -> Mesh:
@@ -253,19 +254,23 @@ class DPStrategy:
                 self.model, p, state, xc, y, smooth)
             return obj_sum, ce_sum, correct, valid, valid, new_state
         logits, new_state = apply_model(self.model, p, state, xc, True)
-        lf = logits.astype(jnp.float32)
-        logp = jax.nn.log_softmax(lf, axis=-1)
-        maskf = (y >= 0).astype(jnp.float32)
-        safe = jnp.maximum(y, 0)
-        nll = -jnp.take_along_axis(logp, safe[..., None], axis=-1)[..., 0]
-        ce_sum = jnp.sum(nll * maskf)
-        if smooth:
-            nll_s = (1.0 - smooth) * nll - smooth * jnp.mean(logp, axis=-1)
-            obj_sum = jnp.sum(nll_s * maskf)
-        else:
-            obj_sum = ce_sum
+        with scopes.scope(scopes.LOSS):
+            lf = logits.astype(jnp.float32)
+            logp = jax.nn.log_softmax(lf, axis=-1)
+            maskf = (y >= 0).astype(jnp.float32)
+            safe = jnp.maximum(y, 0)
+            nll = -jnp.take_along_axis(logp, safe[..., None],
+                                       axis=-1)[..., 0]
+            ce_sum = jnp.sum(nll * maskf)
+            if smooth:
+                nll_s = ((1.0 - smooth) * nll
+                         - smooth * jnp.mean(logp, axis=-1))
+                obj_sum = jnp.sum(nll_s * maskf)
+            else:
+                obj_sum = ce_sum
+            norm = jnp.sum(maskf)
         correct, valid = correct_and_count(logits, y)
-        return obj_sum, ce_sum, correct, valid, jnp.sum(maskf), new_state
+        return obj_sum, ce_sum, correct, valid, norm, new_state
 
     def _build_explicit_engine(self, smooth):
         """Build train_step as one jit whose body is an explicit shard_map
@@ -342,29 +347,34 @@ class DPStrategy:
             gf = pack_flat(g, meta)
             if meta.num_buckets == 1 and not int8_wire:
                 # the exact PR 3 monolithic program (--comm-buckets 1)
-                gw = gf.astype(wire)
-                if shard_update:
-                    return lax.psum_scatter(gw, "data",
-                                            tiled=True).astype(jnp.float32)
-                return lax.psum(gw, "data").astype(jnp.float32)
+                with scopes.scope(scopes.GRAD_SYNC), scopes.scope("bucket0"):
+                    gw = gf.astype(wire)
+                    if shard_update:
+                        return lax.psum_scatter(
+                            gw, "data", tiled=True).astype(jnp.float32)
+                    return lax.psum(gw, "data").astype(jnp.float32)
             parts = []
             for b in range(meta.num_buckets):
                 gb = bucket_slice(gf, meta, b)
-                if int8_wire:
-                    # one scale per bucket, shared across devices (pmax of
-                    # the local absmaxes) — a per-device scale could not be
-                    # summed on the wire
-                    absmax = lax.pmax(jnp.max(jnp.abs(gb)), "data")
-                    q, scale = quantize_int8(gb, jax.random.fold_in(qkey, b),
-                                             qmax=qmax, absmax=absmax)
-                    red = (lax.psum_scatter(q, "data", tiled=True)
-                           if shard_update else lax.psum(q, "data"))
-                    parts.append(red.astype(jnp.float32) * scale)
-                else:
-                    gw = gb.astype(wire)
-                    red = (lax.psum_scatter(gw, "data", tiled=True)
-                           if shard_update else lax.psum(gw, "data"))
-                    parts.append(red.astype(jnp.float32))
+                # grad_sync/bucket<b> on each bucket's collective: the device
+                # trace tells the buckets apart by their op_name
+                with scopes.scope(scopes.GRAD_SYNC), scopes.scope(f"bucket{b}"):
+                    if int8_wire:
+                        # one scale per bucket, shared across devices (pmax
+                        # of the local absmaxes) — a per-device scale could
+                        # not be summed on the wire
+                        absmax = lax.pmax(jnp.max(jnp.abs(gb)), "data")
+                        q, scale = quantize_int8(
+                            gb, jax.random.fold_in(qkey, b), qmax=qmax,
+                            absmax=absmax)
+                        red = (lax.psum_scatter(q, "data", tiled=True)
+                               if shard_update else lax.psum(q, "data"))
+                        parts.append(red.astype(jnp.float32) * scale)
+                    else:
+                        gw = gb.astype(wire)
+                        red = (lax.psum_scatter(gw, "data", tiled=True)
+                               if shard_update else lax.psum(gw, "data"))
+                        parts.append(red.astype(jnp.float32))
             return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
 
         guard = self._guard
@@ -706,17 +716,14 @@ class DPStrategy:
         self._jit_train_step = jit_step  # introspection (tests, tools)
         mode = ("overlapped" if overlap
                 else "sharded" if shard_update else "replicated")
-        span_args = {"mode": mode, "wire": str(jnp.dtype(wire)),
-                     "buckets": meta.num_buckets}
-        # Exact per-bucket wire-byte schedule for the rs_bucket/ag_bucket/
-        # ar_bucket marker spans: ring RS ships (n-1)/n of the (padded)
-        # bucket in the wire dtype, the param AG the same fraction in f32
-        # (master weights), and the replicated engine's ring ALLREDUCE
-        # ships 2(n-1)/n (RS + AG halves of the same ring — matching
-        # comm_stats._ring_allreduce_bytes). Host spans MARK the schedule
-        # with exact byte accounting — per-bucket device time lives in the
-        # --trace-dir XLA capture, where the async collectives are visible
-        # interleaved with compute.
+        # Exact per-bucket wire-byte schedule: ring RS ships (n-1)/n of the
+        # (padded) bucket in the wire dtype, the param AG the same fraction
+        # in f32 (master weights), and the replicated engine's ring
+        # ALLREDUCE ships 2(n-1)/n (RS + AG halves of the same ring —
+        # matching comm_stats._ring_allreduce_bytes). The one host span of
+        # the step, dp_explicit_update, carries the totals as arguments;
+        # per-bucket device time is the grad_sync/bucket<b> scope on the
+        # collectives themselves (reduce_grads), read from a device trace.
         wire_itemsize = 1 if int8_wire else jnp.dtype(wire).itemsize
         rs_scale = ((n - 1) / n if shard_update
                     else 2.0 * (n - 1) / n if n > 1 else 0.0)
@@ -729,30 +736,21 @@ class DPStrategy:
             for b in range(meta.num_buckets)
         ]
         self._bucket_schedule = bucket_sched
+        span_args = {
+            "mode": mode, "wire": str(jnp.dtype(wire)),
+            "buckets": meta.num_buckets,
+            "grad_wire_bytes": sum(sc["rs_wire_bytes"]
+                                   for sc in bucket_sched),
+            "param_wire_bytes": (sum(sc["ag_wire_bytes"]
+                                     for sc in bucket_sched)
+                                 if shard_update else 0.0)}
 
         def train_step(ts, x, y, lr):
             from ddlbench_tpu.telemetry import get_tracer
 
-            tracer = get_tracer()
-            if not tracer.enabled:
+            # the update phase's dispatch on the host timeline
+            with get_tracer().span("dp_explicit_update", **span_args):
                 return jit_step(ts, x, y, lr)
-            # marks the update phase's dispatch on the host timeline;
-            # device time lives in the --trace-dir XLA capture
-            with tracer.span("dp_explicit_update", **span_args):
-                out = jit_step(ts, x, y, lr)
-                for sc in bucket_sched:
-                    coll = "rs_bucket" if shard_update else "ar_bucket"
-                    with tracer.span(coll, bucket=sc["bucket"],
-                                     wire_bytes=sc["rs_wire_bytes"],
-                                     dtype=str(jnp.dtype(wire)),
-                                     offset=sc["offset"]):
-                        pass
-                    if shard_update:
-                        with tracer.span("ag_bucket", bucket=sc["bucket"],
-                                         wire_bytes=sc["ag_wire_bytes"],
-                                         dtype="float32", jit=overlap):
-                            pass
-            return out
 
         self.train_step = train_step
 

@@ -8,16 +8,13 @@ COMPUTE span::
 
     overlap_fraction = |union(comm) ∩ union(compute)| / |union(comm)|
 
-Works on any trace in the Chrome trace-event JSON format:
-
-* the ``--trace`` host span trace (telemetry/export.py) — comm spans are
-  the engine's ``rs_bucket``/``ag_bucket``/``ar_bucket`` markers (exact
-  wire-byte accounting, near-zero host duration: they mark the SCHEDULE,
-  so host-trace overlap is not a device measurement),
-* an XLA device trace exported from ``--trace-dir`` via Perfetto/TensorBoard
-  — comm spans are the async collective ops (``all-reduce``,
-  ``reduce-scatter``, ``all-gather``, ...), compute spans the fusions; the
-  overlap fraction THERE is the real measurement the round-9 A/B reports.
+Works on a trace in the Chrome trace-event JSON format, and means
+something on a DEVICE trace only: an XLA trace exported from ``--trace-dir``
+via Perfetto/TensorBoard, where comm spans are the collective ops
+(``all-reduce``, ``reduce-scatter``, ``all-gather``, ...; the dp engine's
+carry ``grad_sync/bucket<b>`` in their op_name) and compute spans the
+fusions. A ``--trace`` host span trace holds no communication span: the
+host dispatches one program and the collectives run on the device.
 
 Spans are classified by name prefix (case-insensitive), and intervals are
 unioned ACROSS tracks before intersecting — an async collective on a
@@ -29,7 +26,7 @@ default compute set by prefix denylist.
 CLI::
 
     python -m ddlbench_tpu.telemetry.overlap trace.json \
-        [--comm rs_bucket,ag_bucket] [--compute fusion,dot,conv]
+        [--comm all-reduce,reduce-scatter] [--compute fusion,dot,conv]
 """
 
 from __future__ import annotations
@@ -37,10 +34,9 @@ from __future__ import annotations
 import json
 from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
 
-# Default comm-span prefixes: the dp engine's bucket markers plus the op
-# names XLA device traces use for collectives.
+# Default comm-span prefixes: the op names XLA device traces use for
+# collectives.
 COMM_PREFIXES = (
-    "rs_bucket", "ag_bucket", "ar_bucket",
     "all-reduce", "all-gather", "reduce-scatter", "all-to-all",
     "collective-permute", "psum", "ppermute", "send", "recv",
 )
@@ -114,7 +110,7 @@ def overlap_fraction(trace: Any,
     complete span that is neither comm nor a container". Returns a dict
     with total/overlapped comm seconds, the overlap fraction (0 when no
     comm spans exist), span counts, and summed ``wire_bytes`` args per
-    comm span name (the engine's markers carry exact byte accounting).
+    comm span name (where a trace's events carry that argument).
     """
     comm_iv: List[Tuple[float, float]] = []
     compute_iv: List[Tuple[float, float]] = []

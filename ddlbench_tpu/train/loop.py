@@ -671,110 +671,108 @@ def _run_benchmark(cfg: RunConfig, strategy, data, logger: MetricLogger,
                 # separately as stall (data/prefetch.py), so the two
                 # decompose the epoch instead of double-counting it.
                 t_step = time.perf_counter_ns()
-                # fault hook: `kill` SIGKILLs / `preempt` SIGTERMs at this
-                # step boundary — before the dispatch, so the last committed
-                # checkpoint is what a resume must recover from
-                faults.step_boundary(epoch, step)
-                if preempt is not None and preempt.requested:
-                    # graceful preemption: commit the state as of the LAST
-                    # COMPLETED step through the atomic protocol, then exit
-                    # with the distinct code (cli.py). The guard flushes
-                    # first so an anomalous pending step cannot be the
-                    # state that gets committed.
-                    guard.flush(epoch, step)
-                    _commit_preemption(cfg, ts, epoch, step, global_step,
-                                       logger, tracer, wd, ckpt_pin,
-                                       ckpt_logical)
-                if faults.poison_grad(epoch, step):
-                    # `nan-grad`: a NaN lr rides into the backward through
-                    # the guard-armed engines' objective multiplier
-                    # (lr*0+1), poisoning the device-side gradients — the
-                    # on-device detection/skip path is what gets exercised.
-                    # Disarmed engines have no multiplier: the NaN scales
-                    # the update directly and params stay NaN, which is
-                    # exactly what a real NaN gradient does without a guard
-                    # (nan_policy then sees it at the next loss sync)
-                    step_lr = float("nan")
-                xla_window.step(global_step, lambda: (
-                    float(metrics["loss"]) if metrics is not None else None))
-                ann = (jax.profiler.StepTraceAnnotation(
-                    "train", step_num=global_step)
-                    if annotate_steps else _NULL_CTX)
-                with ann:
-                    ts, metrics = strategy.train_step(ts, *fetched.batch,
-                                                      jnp.float32(step_lr))
-                if faults.poison_loss(epoch, step):
-                    # `nan-loss`: poison this step's HOST-side loss (device
-                    # state untouched) — drives the --nan-policy path
-                    metrics = dict(metrics)
-                    metrics["loss"] = jnp.float32(float("nan"))
-                global_step += 1
-                interval_samples += global_batch
-                interval_steps += 1
-                # With the watchdog armed, sync every step so the deadline
-                # really is per-step (a small pipelining cost, only when
-                # opted in); otherwise the loop transfers one accumulated
-                # scalar per log interval.
-                log_step = (step + 1) % cfg.log_interval == 0 or step == steps - 1
-                if wd:
-                    with tracer.span("step_sync"):
-                        step_loss = float(metrics["loss"])  # transfer = sync
-                    # per-step health first: a dropped/rewound update is the
-                    # step's primary event, the loss value its symptom
-                    guard.step_health(epoch, step + 1, metrics)
-                    guard.check_loss(step_loss, epoch, step + 1)
-                    wd.kick()
-                    host_loss_sum += step_loss
-                else:
-                    loss_sum = (metrics["loss"] if loss_sum is None
-                                else loss_sum + metrics["loss"])
-                    # guard: chain (finite, grad_norm) lazily on device —
-                    # synced with the same interval transfer below
-                    guard.accumulate(metrics)
-                if log_step:
+                # the whole loop body as one host span: ddl/train_step on the
+                # profiler's clock, train_step in the ring when --trace is on
+                with tracer.span("train_step", epoch=epoch, step=step,
+                                 global_step=global_step):
+                    # fault hook: `kill` SIGKILLs / `preempt` SIGTERMs at this
+                    # step boundary — before the dispatch, so the last committed
+                    # checkpoint is what a resume must recover from
+                    faults.step_boundary(epoch, step)
+                    if preempt is not None and preempt.requested:
+                        # graceful preemption: commit the state as of the LAST
+                        # COMPLETED step through the atomic protocol, then exit
+                        # with the distinct code (cli.py). The guard flushes
+                        # first so an anomalous pending step cannot be the
+                        # state that gets committed.
+                        guard.flush(epoch, step)
+                        _commit_preemption(cfg, ts, epoch, step, global_step,
+                                           logger, tracer, wd, ckpt_pin,
+                                           ckpt_logical)
+                    if faults.poison_grad(epoch, step):
+                        # `nan-grad`: a NaN lr rides into the backward through
+                        # the guard-armed engines' objective multiplier
+                        # (lr*0+1), poisoning the device-side gradients — the
+                        # on-device detection/skip path is what gets exercised.
+                        # Disarmed engines have no multiplier: the NaN scales
+                        # the update directly and params stay NaN, which is
+                        # exactly what a real NaN gradient does without a guard
+                        # (nan_policy then sees it at the next loss sync)
+                        step_lr = float("nan")
+                    xla_window.step(global_step, lambda: (
+                        float(metrics["loss"]) if metrics is not None else None))
+                    ann = (jax.profiler.StepTraceAnnotation(
+                        "train", step_num=global_step)
+                        if annotate_steps else _NULL_CTX)
+                    with ann:
+                        ts, metrics = strategy.train_step(ts, *fetched.batch,
+                                                          jnp.float32(step_lr))
+                    if faults.poison_loss(epoch, step):
+                        # `nan-loss`: poison this step's HOST-side loss (device
+                        # state untouched) — drives the --nan-policy path
+                        metrics = dict(metrics)
+                        metrics["loss"] = jnp.float32(float("nan"))
+                    global_step += 1
+                    interval_samples += global_batch
+                    interval_steps += 1
+                    # With the watchdog armed, sync every step so the deadline
+                    # really is per-step (a small pipelining cost, only when
+                    # opted in); otherwise the loop transfers one accumulated
+                    # scalar per log interval.
+                    log_step = (step + 1) % cfg.log_interval == 0 or step == steps - 1
                     if wd:
-                        # per-step syncs already landed (and checked) every
-                        # loss; the interval mean is free host math
-                        loss = host_loss_sum / interval_steps
+                        with tracer.span("step_sync"):
+                            step_loss = float(metrics["loss"])  # transfer = sync
+                        # per-step health first: a dropped/rewound update is the
+                        # step's primary event, the loss value its symptom
+                        guard.step_health(epoch, step + 1, metrics)
+                        guard.check_loss(step_loss, epoch, step + 1)
+                        wd.kick()
+                        host_loss_sum += step_loss
                     else:
-                        # one transfer = sync; the sum chains every step in
-                        # the interval, so non-finite losses propagate into
-                        # it (the interval mean cannot pin the offending
-                        # step — only the watchdog's per-step sync can)
-                        with tracer.span("interval_sync"):
-                            loss = float(loss_sum) / interval_steps
-                        guard.check_loss(loss, epoch, step + 1,
-                                         where=f"in epoch {epoch} interval "
-                                               f"ending step {step + 1}")
-                        guard.flush(epoch, step + 1)
-                    loss_sum, host_loss_sum, interval_steps = None, 0.0, 0
-                    now = time.perf_counter()
-                    logger.train_interval(
-                        epoch,
-                        100.0 * (step + 1) / steps,
-                        interval_samples / max(1e-9, now - interval_tick),
-                        loss,
-                    )
-                    interval_tick, interval_samples = now, 0
+                        loss_sum = (metrics["loss"] if loss_sum is None
+                                    else loss_sum + metrics["loss"])
+                        # guard: chain (finite, grad_norm) lazily on device —
+                        # synced with the same interval transfer below
+                        guard.accumulate(metrics)
+                    if log_step:
+                        if wd:
+                            # per-step syncs already landed (and checked) every
+                            # loss; the interval mean is free host math
+                            loss = host_loss_sum / interval_steps
+                        else:
+                            # one transfer = sync; the sum chains every step in
+                            # the interval, so non-finite losses propagate into
+                            # it (the interval mean cannot pin the offending
+                            # step — only the watchdog's per-step sync can)
+                            with tracer.span("interval_sync"):
+                                loss = float(loss_sum) / interval_steps
+                            guard.check_loss(loss, epoch, step + 1,
+                                             where=f"in epoch {epoch} interval "
+                                                   f"ending step {step + 1}")
+                            guard.flush(epoch, step + 1)
+                        loss_sum, host_loss_sum, interval_steps = None, 0.0, 0
+                        now = time.perf_counter()
+                        logger.train_interval(
+                            epoch,
+                            100.0 * (step + 1) / steps,
+                            interval_samples / max(1e-9, now - interval_tick),
+                            loss,
+                        )
+                        interval_tick, interval_samples = now, 0
                 t_step_end = time.perf_counter_ns()
                 stats.record_step(epoch, (t_step_end - t_step) / 1e9)
-                if tracer.enabled:
-                    tracer.complete("train_step", t_step, t_step_end,
-                                    {"epoch": epoch, "step": step,
-                                     "global_step": global_step - 1})
-                    if step == ep_start and \
-                            getattr(strategy, "timetable", None) is not None:
-                        # pipeline runtimes: project the schedule timetable
-                        # onto this step's window as per-stage pipe_tick
-                        # marker spans — telemetry/bubble.py's food. Once
-                        # per epoch: the projection is identical every step
-                        # (the schedule is static), so more would only fill
-                        # the ring.
-                        from ddlbench_tpu.telemetry.bubble import (
-                            emit_tick_spans)
+                if tracer.enabled and step == ep_start and \
+                        getattr(strategy, "timetable", None) is not None:
+                    # pipeline runtimes: project the schedule timetable
+                    # onto this step's window as per-stage pipe_tick marker
+                    # spans — telemetry/bubble.py's food. Once per epoch:
+                    # the projection is identical every step (the schedule
+                    # is static), so more would only fill the ring.
+                    from ddlbench_tpu.telemetry.bubble import emit_tick_spans
 
-                        emit_tick_spans(tracer, strategy.timetable, t_step,
-                                        t_step_end, step=global_step - 1)
+                    emit_tick_spans(tracer, strategy.timetable, t_step,
+                                    t_step_end, step=global_step - 1)
                 if (cfg.checkpoint_every_steps
                         and (step + 1) % cfg.checkpoint_every_steps == 0
                         and step != steps - 1):  # epoch-end save covers last

@@ -9,9 +9,10 @@ Acceptance pins, all tier-1-fast on the 8-virtual-device CPU mesh:
   leaves, never a reduction order within a bucket, so this is exact by
   construction and pinned here against regression;
 * --comm-buckets 1 reproduces the pre-bucketing FlatMeta layout exactly;
-* per-bucket rs_bucket/ag_bucket marker spans land in the host trace with
-  EXACT wire-byte accounting (int8 = 1/4 the f32 gradient bytes, also
-  pinned through comm_stats);
+* every bucket's gradient collective carries the grad_sync/bucket<b>
+  named scope in the compiled step, and the step's one host span
+  (dp_explicit_update) carries the EXACT wire-byte accounting (int8 = 1/4
+  the f32 gradient bytes, also pinned through comm_stats);
 * the int8 wire's stochastic rounding is unbiased, seed-deterministic
   (bitwise run replay), and absmax round-trip exact;
 * the overlapped engine's flat sharded params survive eval, checkpoint
@@ -299,35 +300,54 @@ def test_overlapped_checkpoint_roundtrip(devices, train_factory, tmp_path):
 # ---- per-bucket spans + wire-byte accounting -------------------------------
 
 
-def test_bucket_spans_and_exact_wire_bytes(devices, train_factory):
-    """rs_bucket/ag_bucket spans appear under --trace with wire-byte args
-    that sum EXACTLY to comm_stats' physical accounting, per dtype."""
+def _traced_run(train_factory, cfg, steps):
     from ddlbench_tpu.telemetry import Tracer, get_tracer, set_tracer
 
     prev = get_tracer()
     tracer = set_tracer(Tracer())
     tracer.enable()
     try:
-        _, _, strat = _run(train_factory, _cfg(dp_shard_update=True, comm_buckets=4),
-                           steps=2)
+        _, ts, strat = _run(train_factory, cfg, steps=steps)
     finally:
         tracer.disable()
         set_tracer(prev)
-    events = tracer.events()
-    rs = [e for e in events if e[1] == "rs_bucket"]
-    ag = [e for e in events if e[1] == "ag_bucket"]
+    return tracer, ts, strat
+
+
+def test_bucket_scopes_and_exact_wire_bytes(devices, train_factory):
+    """Each bucket's gradient collective sits under grad_sync/bucket<b> in
+    the compiled step (what a device trace times), and the one host span of
+    the step carries wire-byte args that sum EXACTLY to comm_stats'
+    physical accounting."""
+    import re
+
+    cfg = _cfg(dp_shard_update=True, comm_buckets=4)
+    tracer, ts, strat = _traced_run(train_factory, cfg, steps=2)
     K = strat._flat_meta.num_buckets
     assert K > 1
-    assert len(rs) == 2 * K and len(ag) == 2 * K  # 2 steps x K buckets
+    spans = [e for e in tracer.events() if e[1] == "dp_explicit_update"]
+    assert len(spans) == 2  # one a step, no zero-work marker beside it
+    assert not [e for e in tracer.events() if e[1].endswith("_bucket")]
     cs = comm_stats(strat)
-    per_step_rs = sum(e[6]["wire_bytes"] for e in rs) / 2
-    per_step_ag = sum(e[6]["wire_bytes"] for e in ag) / 2
-    np.testing.assert_allclose(per_step_rs,
-                               cs["physical_reduce_scatter_bytes"],
-                               rtol=1e-12)
-    np.testing.assert_allclose(per_step_ag, cs["physical_all_gather_bytes"],
-                               rtol=1e-12)
-    assert {e[6]["bucket"] for e in rs} == set(range(K))
+    for e in spans:
+        assert e[6]["buckets"] == K and e[6]["mode"] == "overlapped"
+        np.testing.assert_allclose(e[6]["grad_wire_bytes"],
+                                   cs["physical_reduce_scatter_bytes"],
+                                   rtol=1e-12)
+        np.testing.assert_allclose(e[6]["param_wire_bytes"],
+                                   cs["physical_all_gather_bytes"],
+                                   rtol=1e-12)
+    x, y = _batch(cfg.global_batch(), 0, strat.model.num_classes,
+                  strat.model.in_shape)
+    text = strat._jit_train_step.lower(
+        ts, *strat.shard_batch(x, y), jnp.float32(0.2)).compile().as_text()
+    found = set()
+    for line in text.splitlines():
+        if re.search(r"= \S+ (reduce-scatter|all-reduce)(-start)?\(", line):
+            m = re.search(r'op_name="[^"]*grad_sync/bucket(\d+)/', line)
+            if m:
+                found.add(int(m.group(1)))
+    assert found == set(range(K))
 
 
 def _dp_stats(**kw):
@@ -460,21 +480,21 @@ def test_overlap_fraction_interval_math():
     from ddlbench_tpu.telemetry.overlap import overlap_fraction
 
     ev = [
-        {"ph": "X", "name": "rs_bucket", "ts": 0, "dur": 10,
+        {"ph": "X", "name": "reduce-scatter.1", "ts": 0, "dur": 10,
          "args": {"wire_bytes": 100.0}},
-        {"ph": "X", "name": "rs_bucket", "ts": 20, "dur": 10,
+        {"ph": "X", "name": "reduce-scatter.1", "ts": 20, "dur": 10,
          "args": {"wire_bytes": 50.0}},
         {"ph": "X", "name": "fusion.7", "ts": 5, "dur": 20},
         # containers must not count as compute-under-comm
         {"ph": "X", "name": "dp_explicit_update", "ts": 0, "dur": 1000},
         {"ph": "X", "name": "train_step", "ts": 0, "dur": 1000},
         # non-complete events are ignored
-        {"ph": "i", "name": "rs_bucket", "ts": 3},
+        {"ph": "i", "name": "reduce-scatter.1", "ts": 3},
     ]
     r = overlap_fraction(ev)
     assert r["comm_spans"] == 2 and r["compute_spans"] == 1
     np.testing.assert_allclose(r["overlap_fraction"], 0.5)
-    assert r["wire_bytes"] == {"rs_bucket": 150.0}
+    assert r["wire_bytes"] == {"reduce-scatter.1": 150.0}
     # no comm spans -> fraction 0, not a division error
     assert overlap_fraction([])["overlap_fraction"] == 0.0
     # explicit compute prefixes override the default complement rule
@@ -483,22 +503,23 @@ def test_overlap_fraction_interval_math():
 
 
 def test_overlap_cli_on_exported_trace(devices, train_factory, tmp_path):
-    """--trace output -> export -> CLI reducer: the engine's marker spans
-    are found and their wire bytes aggregated."""
-    from ddlbench_tpu.telemetry import Tracer, export_chrome_trace, \
-        get_tracer, set_tracer
+    """--trace output -> export -> CLI reducer: a host trace holds no
+    communication span any more (the engine's one span is a container, its
+    bytes are arguments), so the reducer reports none — overlap is read
+    from a device trace, where the collectives are."""
+    from ddlbench_tpu.telemetry import export_chrome_trace
     from ddlbench_tpu.telemetry.overlap import main as overlap_main
 
-    prev = get_tracer()
-    tracer = set_tracer(Tracer())
-    tracer.enable()
-    try:
-        _run(train_factory, _cfg(dp_shard_update=True, comm_buckets=2), steps=1)
-    finally:
-        tracer.disable()
-        set_tracer(prev)
+    tracer, _, _ = _traced_run(
+        train_factory, _cfg(dp_shard_update=True, comm_buckets=2), steps=1)
     path = str(tmp_path / "trace.json")
     export_chrome_trace(tracer, path)
+    doc = json.load(open(path))
+    (span,) = [e for e in doc["traceEvents"]
+               if e.get("name") == "dp_explicit_update"]
+    assert span["args"]["buckets"] == 2
+    assert span["args"]["grad_wire_bytes"] > 0
+    assert span["args"]["param_wire_bytes"] > 0
     import io
     from contextlib import redirect_stdout
 
@@ -506,8 +527,8 @@ def test_overlap_cli_on_exported_trace(devices, train_factory, tmp_path):
     with redirect_stdout(buf):
         assert overlap_main([path]) == 0
     out = json.loads(buf.getvalue())
-    assert out["comm_spans"] >= 4  # 2 buckets x (rs + ag)
-    assert set(out["wire_bytes"]) == {"rs_bucket", "ag_bucket"}
+    assert out["comm_spans"] == 0 and out["wire_bytes"] == {}
+    assert out["overlap_fraction"] == 0.0
 
 
 # ---- config gates ----------------------------------------------------------

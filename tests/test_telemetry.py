@@ -42,9 +42,55 @@ def test_disabled_tracer_records_nothing():
     tr.instant("i")
     assert len(tr) == 0
 
-    # the disabled span fast-path returns one cached singleton — the no-op
-    # check contract (no allocation per call site)
-    assert tr.span("a") is tr.span("b")
+
+def test_ring_off_span_is_a_cheap_profiler_annotation():
+    """Ring off, no profiler session: a span is one inactive
+    ``TraceAnnotation("ddl/<name>")`` — under 2 us (the prefetcher opens
+    three per batch; the benchmark's cells run 7-10 batches a second)."""
+    import time
+
+    from jax.profiler import TraceAnnotation
+
+    tr = Tracer()
+    assert isinstance(tr.span("a"), TraceAnnotation)
+    n = 20_000
+    best = float("inf")
+    for _ in range(5):  # the best of five: a loaded box must not fail this
+        t0 = time.perf_counter_ns()
+        for _ in range(n):
+            with tr.span("batch_produce", epoch=1, step=2, train=True):
+                pass
+        best = min(best, (time.perf_counter_ns() - t0) / n)
+    assert best < 2000, f"{best:.0f} ns a span"
+    assert len(tr) == 0
+
+
+def test_spans_land_in_the_profiler_trace(tmp_path):
+    """Under a profiler session the program's host spans are ``ddl/<name>``
+    events of the same ``.xplane.pb`` as the device ops, ring on or off;
+    ``complete`` (a region already over) stays ring-only."""
+    import glob
+
+    import jax
+
+    tr = Tracer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tr.span("ring_wait", step=1):
+            pass
+        tr.enable()
+        with tr.span("step_sync"):
+            pass
+        tr.complete("warmup_compile", 0, 10)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(path)
+    names = {e.name for plane in data.planes if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events
+             if e.name.startswith("ddl/")}
+    assert names == {"ddl/ring_wait", "ddl/step_sync"}
+    assert [e[1] for e in tr.events()] == ["step_sync", "warmup_compile"]
 
 
 def test_span_records_name_duration_and_args():

@@ -1,0 +1,120 @@
+"""The named scopes must not hide the Pallas kernels from the benchmark.
+
+A trace event of a Pallas kernel is named after its HLO instruction, and the
+instruction's name is built from the name stack it was traced under: inside
+the program's scopes ``jvp_flash_attn_fwd_.12`` became ``flash_attn_fwd.12``.
+``benchmarks/kernels/*.py`` find a kernel by a substring of that name, so the
+six substrings have to survive wherever a scope is put. Checked here by
+compiling the program's own attention sublayer and fused head loss, forward
+and backward, inside their scopes, for a described v5e chip at gpt2-small's
+widths (about two seconds each; nothing runs, no number is a device's).
+
+The topology is described inside a fixture, never at import: one process at
+a time may load the TPU's library (the on-chip-measurement guide, section 2).
+"""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+B, T, D, H, V = 16, 1024, 768, 12, 50304
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here: nothing to check
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def as_on_tpu(monkeypatch):
+    """The kernels' dispatch asks the default backend, which is the CPU
+    here, and two process-wide settings an earlier test of the same worker
+    may have left set: steer all three in the test, quiet the compile cache
+    (an executable for a described chip cannot be read back)."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from ddlbench_tpu import distributed
+    from ddlbench_tpu.models import transformer
+    from ddlbench_tpu.ops import util
+
+    monkeypatch.setattr(distributed, "is_tpu_backend", lambda: True)
+    monkeypatch.setattr(transformer, "_ATTENTION_BACKEND", ["auto"])
+    monkeypatch.setattr(util, "_IN_SHARDED_JIT", [False])
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    cc.reset_cache()
+
+
+def _mosaic_calls(fn, chip, *shapes):
+    """{instruction name: op_name} of the Mosaic calls ``fn`` compiles to."""
+    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    out = {}
+    for line in text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line:
+            name = re.match(r"\s*(?:ROOT\s+)?%?([\w.\-]+) = ", line).group(1)
+            out[name] = re.search(r'op_name="([^"]*)"', line).group(1)
+    return out
+
+
+def _assert_kernels(found, instance, kind, kernels):
+    for kernel, wrapper in kernels:
+        names = [n for n in found if kernel in n]
+        assert len(names) == 1, (kernel, sorted(found))
+        assert (f"{wrapper.format(instance)}/{kind}/{kernel}/pallas_call"
+                in found[names[0]])
+
+
+def test_flash_attention_keeps_its_names_inside_the_scopes(one_chip,
+                                                           as_on_tpu):
+    from ddlbench_tpu.models.layers import apply_slice
+    from ddlbench_tpu.models.transformer import transformer_block
+
+    block = transformer_block("block3", D, H)
+    params = jax.eval_shape(lambda k: block.init(k, (T, D))[0],
+                            jax.random.key(0))
+    leaves, tree = jax.tree.flatten(params)
+
+    def loss(x, *flat):
+        p = jax.tree.unflatten(tree, flat)
+        y, _ = apply_slice([block], [p], [{}], x, True)
+        return y.astype(jnp.float32).sum()
+
+    found = _mosaic_calls(
+        jax.grad(loss), one_chip, ((B, T, D), jnp.bfloat16),
+        *[(a.shape, jnp.bfloat16) for a in leaves])
+    _assert_kernels(found, "block3", "attn", (
+        ("flash_attn_fwd", "jvp({})"), ("flash_attn_dq", "transpose(jvp({}))"),
+        ("flash_attn_dkv", "transpose(jvp({}))")))
+
+
+def test_fused_xent_keeps_its_names_inside_the_scopes(one_chip, as_on_tpu):
+    from ddlbench_tpu.models.transformer import lm_head
+    from ddlbench_tpu.parallel.common import fused_slice_loss_sums
+
+    head = lm_head("lm_head", V)
+
+    def loss(h, w, scale, bias, labels):
+        p = {"ln_f": {"scale": scale, "bias": bias}, "head": w}
+        return fused_slice_loss_sums([head], [p], [{}], h, labels, 0.0)[0]
+
+    found = _mosaic_calls(
+        jax.grad(loss, argnums=(0, 1)), one_chip,
+        ((B, T, D), jnp.bfloat16), ((D, V), jnp.bfloat16),
+        ((D,), jnp.bfloat16), ((D,), jnp.bfloat16), ((B, T), jnp.int32))
+    _assert_kernels(found, "lm_head", "loss", (
+        ("fused_xent_fwd", "jvp({})"), ("fused_xent_dh", "transpose(jvp({}))"),
+        ("fused_xent_dw", "transpose(jvp({}))")))
