@@ -46,7 +46,7 @@ import time
 HERE = os.path.dirname(os.path.abspath(__file__))
 OUT_DIR = os.path.join(HERE, "chiprun_out", "chip_smoke")
 
-FLASH_KERNELS = {"flash_attn_fwd", "flash_attn_dq", "flash_attn_dkv"}
+FLASH_KERNELS = {"flash_attn_fwd", "flash_attn_dq_dkv"}
 XENT_KERNELS = {"fused_xent_fwd", "fused_xent_dh", "fused_xent_dw"}
 
 
@@ -153,9 +153,9 @@ def phase_kernels():
                     q, c, a, npl, page, use_kernel=False))(q, cache, at)
             notes.append(_close(f"{label}/{name}", got, ref, tol))
 
-    # flash attention fwd+bwd: resident at T=1024; at T=8192 both the
-    # resident pick and the forced streaming grid. Reference: the XLA
-    # einsum path on the same bf16 values upcast to f32.
+    # flash attention fwd+bwd: resident (one-pass backward) at T=1024; at
+    # T=8192 both the resident pick and the forced streaming grid.
+    # Reference: the XLA einsum path on the same bf16 values upcast to f32.
     def fwd_bwd(fn):
         def f(q, k, v, w):
             o, vjp = jax.vjp(fn, q, k, v)

@@ -10,26 +10,38 @@ online-softmax accumulator, so HBM traffic drops from O(T^2) to O(T * d)
 and the block matmuls run on the MXU.
 
 Forward saves only O and the row logsumexp (LSE); backward recomputes the
-probabilities blockwise in two more kernels (dQ; dK/dV together), the
-standard FlashAttention-2 recipe, wired up with jax.custom_vjp.
+probabilities blockwise, the standard FlashAttention-2 recipe, wired up with
+jax.custom_vjp.
 
-Two grid designs share one set of block-step functions (round 3):
+Two grid designs:
 
-* **resident** (the fast path): grid (batch*head, outer block), the whole
-  inner sequence lives in VMEM and a fori_loop sweeps it with causal
-  bounds. Minimal grid overhead and no re-fetching, but scoped-VMEM use
-  grows with T — Mosaic rejects it past ~8-16k (measured: 16.8 MiB at
-  T=8192 with 1024-wide blocks vs the 16 MiB v5e limit).
+* **resident** (the fast path): the whole inner sequence lives in VMEM and
+  a fori_loop sweeps it with causal bounds. Forward: grid (batch*head, Q
+  block) over resident K/V. Backward: ONE kernel, grid (batch*head, K
+  block) over resident Q/dO — per tile S, P, dP, dS are computed once and
+  give dV, dK and dQ (5 matmuls, 1 exp; a dq kernel plus a dkv kernel did 7
+  and 2). dK/dV ride the fori carry; dQ of the whole row block accumulates
+  in f32 scratch while the K-block axis sweeps. The resident kernels keep
+  their score tile TRANSPOSED, [bk, bq]: the per-query statistics (running
+  max and sum, lse, delta) are then rows [1, bq] — 4 vregs at bq=512 where
+  a [bq, 1] column takes 64 — reductions run down the sublanes, and no
+  [bq, bk] tile is ever transposed for a matmul.
 * **streaming**: grid (batch*head, outer block, inner block), the inner
   dimension arrives blockwise via BlockSpec with accumulators in VMEM
   scratch — every block shape is T-independent, so any sequence length
-  compiles (T=32k measured on one chip). ~15-30% slower at short T than
-  resident (dead causal cells still pay their fetch), hence the hybrid.
+  compiles (T=32k on one chip). Forward, dq and dkv kernels, row-major
+  tiles. Dead causal cells still pay their fetch.
 
-_use_streaming picks per kernel: resident while the inner-side operands fit
-a conservative budget, streaming beyond (or under oversized block
-requests). Block-level causal skipping in both: resident bounds its fori,
-streaming skips dead cells' compute under @pl.when.
+_use_streaming picks: resident while what the kernel keeps resident fits a
+conservative budget, streaming beyond (or under oversized block requests).
+Block-level causal skipping in both: resident bounds its fori, streaming
+skips dead cells' compute under @pl.when. Measured on one v5e at B*H=192,
+dh=64, bf16 (PERF.md, PR 25), device ms per call, forward / backward:
+T=1024 0.78 / 1.34 (row-major tiles and a dq + dkv pair, before: 0.97 /
+2.35); T=8192 (B*H=24) 3.9 / 6.0 against 6.8 / 15.5 streaming. 512x512
+tiles won every sweep of {128, 256, 512}^2 at T=1024..8192: the sweeps are
+1-2 iterations long and do not pipeline, so a finer causal tiling loses more
+per tile than it saves in area.
 
 ``q_offset``/``k_offset`` give each block its absolute position — the same
 convention as causal_attention — so the kernel also serves blocks of a
@@ -53,35 +65,44 @@ NEG_INF = -1e30
 
 # Inner-side resident bytes (both streamed operands, raw) past which the
 # streaming design is used. 3 MiB keeps every benchmarked shape on the fast
-# resident path (T=8192, dh=64, bf16 -> 2 MiB measured compiling with
-# 512-blocks) while dh=128 or f32 at 8k+ stream. Oversized blocks
-# (max > 512) also stream once the inner side is nontrivial: the resident
-# dkv kernel measured 16.8 MiB scoped VMEM at (bq=256, bk=1024, T=8192).
+# resident path (T=8192, dh=64, bf16 -> 2 MiB) while dh=128 or f32 at 8k+
+# stream. Oversized blocks (max > 512) also stream once the inner side is
+# nontrivial: a resident kernel measured 16.8 MiB scoped VMEM at
+# (bq=256, bk=1024, T=8192).
 RESIDENT_MAX_BYTES = 3 * 1024 * 1024
+# The one-pass backward also keeps dQ of the whole row block resident, in f32
+# (Tq * dh * 4 bytes): 256 KiB at T=1024 dh=64, 2 MiB at T=8192. Past this the
+# backward is the streaming two-kernel pair.
+RESIDENT_DQ_MAX_BYTES = 2 * 1024 * 1024
 
 
 def _use_streaming(t_inner: int, dh: int, itemsize: int, bq: int, bk: int,
-                   stream) -> bool:
+                   stream, dq_rows: int = 0, interpret: bool = False) -> bool:
+    """``dq_rows``: rows of the f32 dQ block the kernel keeps resident (the
+    one-pass backward: Tq; 0 for the forward)."""
     if stream is not None:
         return bool(stream)
+    if not interpret and bq % 128:
+        # the resident kernels put the queries on the lanes: [bk, bq] tiles,
+        # [1, bq] slices of the lse row
+        return True
     resident = 2 * t_inner * dh * itemsize
-    return resident > RESIDENT_MAX_BYTES or (
-        max(bq, bk) > 512 and resident > 1024 * 1024)
+    return (resident > RESIDENT_MAX_BYTES
+            or dq_rows * dh * 4 > RESIDENT_DQ_MAX_BYTES
+            or (max(bq, bk) > 512 and resident > 1024 * 1024))
 
 
-def _grid_params(interpret: bool, streaming: bool):
-    """Mosaic grid hints. Streaming: batch*head and the outer block are
-    parallel, the inner streamed dimension is "arbitrary" (sequential — it
-    carries the scratch accumulator). Resident: both dims parallel. No-op
-    under interpret (CPU tests)."""
+def _grid_params(interpret: bool, *semantics: str, vmem_limit_bytes=None):
+    """Mosaic grid hints: "parallel" grid axes are independent, an
+    "arbitrary" one is sequential — it carries a scratch accumulator (the
+    streamed inner dimension; the one-pass backward's K-block axis, across
+    which dQ accumulates). No-op under interpret (CPU tests)."""
     if interpret:
         return {}
     from jax.experimental.pallas import tpu as pltpu
 
-    sem = (("parallel", "parallel", "arbitrary") if streaming
-           else ("parallel", "parallel"))
     return {"compiler_params": pltpu.CompilerParams(
-        dimension_semantics=sem)}
+        dimension_semantics=semantics, vmem_limit_bytes=vmem_limit_bytes)}
 
 
 def _pick_block(t: int, preferred: int, interpret: bool = False) -> int:
@@ -115,9 +136,15 @@ def _causal_kv_bound(q_hi_pos, k_offset: int, block_k: int, num_k: int,
     return jnp.clip(nb, 0, num_k)
 
 
-# ---------------------------------------------------------------------------
-# Block-step math, shared by the resident and streaming kernels.
-# ---------------------------------------------------------------------------
+def _first_q_block(k_lo, q_offset: int, block_q: int, num_q: int,
+                   prefix_len: int = 0):
+    """First Q block whose last position can see the K block starting at
+    absolute position k_lo; a K block overlapping the prefix is visible to
+    every Q block."""
+    start = jnp.clip((k_lo - q_offset) // block_q, 0, num_q)
+    if prefix_len:
+        start = jnp.where(k_lo < prefix_len, 0, start)
+    return start
 
 
 def _block_mask(q_pos, k_pos, prefix_len: int):
@@ -125,6 +152,11 @@ def _block_mask(q_pos, k_pos, prefix_len: int):
     if prefix_len:
         mask = mask | (k_pos < prefix_len)
     return mask
+
+
+# ---------------------------------------------------------------------------
+# Block-step math of the streaming kernels: row-major [bq, bk] tiles.
+# ---------------------------------------------------------------------------
 
 
 def _fwd_block_step(q, k_blk, v_blk, m, l, acc, q_pos, k_pos, scale,
@@ -197,9 +229,18 @@ def _dkv_block_step(k, v, q_blk, do_blk, lse_blk, delta_blk, q_pos, k_pos,
 
 
 # ---------------------------------------------------------------------------
-# Resident kernels: grid (BH, outer), whole inner sequence in VMEM, fori
-# sweep with causal bounds. Fast path for shapes that fit.
+# Resident kernels: whole inner sequence in VMEM, fori sweep with causal
+# bounds, score tile transposed ([bk, bq]: keys down the sublanes, queries
+# along the lanes). Fast path for shapes that fit.
 # ---------------------------------------------------------------------------
+
+_NT = (((1,), (1,)), ((), ()))  # a @ b.T
+_NN = (((1,), (0,)), ((), ()))  # a @ b
+_TN = (((0,), (0,)), ((), ()))  # a.T @ b
+
+
+def _dot(a, b, dims):
+    return jax.lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
 
 
 def _fwd_kernel_res(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, block_k,
@@ -208,91 +249,87 @@ def _fwd_kernel_res(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, block_k,
     dh = q_ref.shape[2]
     q = q_ref[0]  # [bq, dh] native dtype; MXU accumulates f32 below
     qi = pl.program_id(1)
-    q_pos = q_offset + qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
+    q_pos = q_offset + qi * bq + jax.lax.broadcasted_iota(jnp.int32, (1, bq), 1)
     bound = _causal_kv_bound(q_offset + (qi + 1) * bq - 1, k_offset, block_k,
                              num_k, prefix_len)
 
     def body(j, carry):
-        k_blk = k_ref[0, pl.ds(j * block_k, block_k), :]
-        v_blk = v_ref[0, pl.ds(j * block_k, block_k), :]
+        m, l, acc = carry  # [1, bq], [1, bq], [dh, bq]
+        rows = pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
+        k_blk = k_ref[0, rows, :]
+        v_blk = v_ref[0, rows, :]
         k_pos = (k_offset + j * block_k
-                 + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1))
-        return _fwd_block_step(q, k_blk, v_blk, *carry, q_pos, k_pos, scale,
-                               prefix_len)
+                 + jax.lax.broadcasted_iota(jnp.int32, (block_k, 1), 0))
+        s = _dot(k_blk, q, _NT) * scale  # [bk, bq]
+        mask = _block_mask(q_pos, k_pos, prefix_len)
+        s = jnp.where(mask, s, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
+        p = jnp.exp(s - m_new) * mask.astype(jnp.float32)
+        corr = jnp.exp(m - m_new)
+        l_new = l * corr + jnp.sum(p, axis=0, keepdims=True)
+        # p cast to the input dtype so the PV matmul takes the fast MXU path
+        acc_new = acc * corr + _dot(v_blk, p.astype(v_blk.dtype), _TN)
+        return m_new, l_new, acc_new
 
-    m0 = jnp.full((bq, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((bq, 1), jnp.float32)
-    acc0 = jnp.zeros((bq, dh), jnp.float32)
+    m0 = jnp.full((1, bq), NEG_INF, jnp.float32)
+    l0 = jnp.zeros((1, bq), jnp.float32)
+    acc0 = jnp.zeros((dh, bq), jnp.float32)
     m, l, acc = jax.lax.fori_loop(0, bound, body, (m0, l0, acc0))
     l_safe = jnp.maximum(l, 1e-20)
-    o_ref[0] = (acc / l_safe).astype(o_ref.dtype)
+    o_ref[0] = (acc / l_safe).T.astype(o_ref.dtype)
     # LSE of fully-masked rows stays NEG_INF-ish; backward p=exp(s-lse) uses
-    # the same masking so those rows contribute nothing either way. Kept as
-    # [T, 1] (not [T]) to satisfy TPU block-tiling constraints.
+    # the same masking so those rows contribute nothing either way.
     lse_ref[0] = m + jnp.log(l_safe)
 
 
-def _dq_kernel_res(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
-                   scale, block_k, q_offset, k_offset, num_k, prefix_len):
-    bq = q_ref.shape[1]
-    q = q_ref[0]
-    do = do_ref[0]
-    lse = lse_ref[0]      # [bq, 1]
-    delta = delta_ref[0]  # [bq, 1]
-    qi = pl.program_id(1)
-    q_pos = q_offset + qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
-    bound = _causal_kv_bound(q_offset + (qi + 1) * bq - 1, k_offset, block_k,
-                             num_k, prefix_len)
-
-    def body(j, dq):
-        k_blk = k_ref[0, pl.ds(j * block_k, block_k), :]
-        v_blk = v_ref[0, pl.ds(j * block_k, block_k), :]
-        k_pos = (k_offset + j * block_k
-                 + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1))
-        return dq + _dq_block_step(q, do, lse, delta, k_blk, v_blk, q_pos,
-                                   k_pos, scale, prefix_len)
-
-    dq = jax.lax.fori_loop(
-        0, bound, body, jnp.zeros((bq, q.shape[1]), jnp.float32)
-    )
-    dq_ref[0] = dq.astype(dq_ref.dtype)
-
-
-def _dkv_kernel_res(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, *, scale, block_q, q_offset, k_offset,
-                    num_q, prefix_len):
+def _dq_dkv_kernel_res(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
+                       dq_ref, dk_ref, dv_ref, dq_sc, *, scale, block_q,
+                       q_offset, k_offset, num_q, num_k, prefix_len):
+    """One-pass backward: per (K block, Q block) S, P, dP, dS once, and from
+    them dV += P dO, dK += dS Q (the fori carry) and dQ^T += K^T dS, in the
+    [dh, Tq] f32 scratch that stays put while the K-block grid axis sweeps
+    and is written out, transposed back, on its last block."""
     bk = k_ref.shape[1]
     k = k_ref[0]
     v = v_ref[0]
+    k_t = k.T  # [dh, bk], once per K block: dQ^T's tiles come out [dh, bq]
     kj = pl.program_id(1)
-    k_pos = (k_offset + kj * bk
-             + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1))
-    # first q block whose last position can see this k block's first position;
-    # a k block overlapping the prefix is visible to every q block
     k_lo = k_offset + kj * bk
-    start = jnp.clip((k_lo - q_offset) // block_q, 0, num_q)
-    if prefix_len:
-        start = jnp.where(k_lo < prefix_len, 0, start)
+    k_pos = k_lo + jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0)
+    start = _first_q_block(k_lo, q_offset, block_q, num_q, prefix_len)
+
+    @pl.when(kj == 0)
+    def _init():
+        dq_sc[:] = jnp.zeros(dq_sc.shape, jnp.float32)
 
     def body(i, carry):
         dk, dv = carry
-        q_blk = q_ref[0, pl.ds(i * block_q, block_q), :]
-        do_blk = do_ref[0, pl.ds(i * block_q, block_q), :]
-        lse_blk = lse_ref[0, pl.ds(i * block_q, block_q), :]      # [bq, 1]
-        delta_blk = delta_ref[0, pl.ds(i * block_q, block_q), :]
+        rows = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
+        q_blk = q_ref[0, rows, :]
+        do_blk = do_ref[0, rows, :]
         q_pos = (q_offset + i * block_q
-                 + jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0))
-        dk_add, dv_add = _dkv_block_step(k, v, q_blk, do_blk, lse_blk,
-                                         delta_blk, q_pos, k_pos, scale,
-                                         prefix_len)
-        return dk + dk_add, dv + dv_add
+                 + jax.lax.broadcasted_iota(jnp.int32, (1, block_q), 1))
+        s = _dot(k, q_blk, _NT) * scale  # [bk, bq]
+        # where() on the exp, not a multiply: fully-masked rows have lse ~
+        # -1e30, exp(s - lse) overflows to inf and inf * 0 would poison the
+        # gradients with NaN
+        p = jnp.where(_block_mask(q_pos, k_pos, prefix_len),
+                      jnp.exp(s - lse_ref[0, :, rows]), 0.0)
+        dp = _dot(v, do_blk, _NT)
+        ds = (p * (dp - delta_ref[0, :, rows]) * scale).astype(k.dtype)
+        dq_sc[:, rows] += _dot(k_t, ds, _NN)
+        return (dk + _dot(ds, q_blk, _NN),
+                dv + _dot(p.astype(do_blk.dtype), do_blk, _NN))
 
     dk, dv = jax.lax.fori_loop(
         start, num_q, body,
-        (jnp.zeros(k.shape, jnp.float32), jnp.zeros(v.shape, jnp.float32)),
-    )
+        (jnp.zeros(k.shape, jnp.float32), jnp.zeros(v.shape, jnp.float32)))
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
+
+    @pl.when(kj == num_k - 1)
+    def _fini():
+        dq_ref[0] = dq_sc[:].T.astype(dq_ref.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -365,10 +402,8 @@ def _dkv_kernel_stream(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
     kj, i = pl.program_id(1), pl.program_id(2)
     k_pos = (k_offset + kj * bk
              + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1))
-    k_lo = k_offset + kj * bk
-    start = jnp.clip((k_lo - q_offset) // block_q, 0, num_q)
-    if prefix_len:
-        start = jnp.where(k_lo < prefix_len, 0, start)
+    start = _first_q_block(k_offset + kj * bk, q_offset, block_q, num_q,
+                           prefix_len)
 
     @pl.when(i == 0)
     def _init():
@@ -406,10 +441,11 @@ def flash_attention(q, k, v, q_offset=0, k_offset=0, prefix_len=0,
     Semantics match models/transformer.py causal_attention (including the
     q_offset/k_offset absolute-position convention and the prefix-LM rule:
     absolute key positions < prefix_len are visible to every query — the
-    seq2seq source segment); fully-masked rows return 0. Block sizes shrink
-    automatically to divide the sequence. Default 512x512 blocks measured
-    fastest on v5e (2.3-2.5x over the XLA attention at T=1024-4096 forward,
-    1.2-1.9x forward+backward). ``stream`` forces the streaming (True) or
+    seq2seq source segment); fully-masked rows return 0. ``block_q`` /
+    ``block_k`` are upper bounds: blocks shrink to divide the sequence. The
+    default 512x512 was the fastest of {128, 256, 512}^2 for the forward and
+    for the one-pass backward at T=1024, 2048, 4096 and 8192 (dh=64, bf16,
+    one v5e; PERF.md, PR 25). ``stream`` forces the streaming (True) or
     resident (False) grid design; None picks per kernel (module docstring).
     """
     o, _ = _flash_fwd_impl(q, k, v, q_offset, k_offset, prefix_len, block_q,
@@ -419,6 +455,9 @@ def flash_attention(q, k, v, q_offset=0, k_offset=0, prefix_len=0,
 
 def _flash_fwd_impl(q, k, v, q_offset, k_offset, prefix_len, block_q, block_k,
                     interpret, stream):
+    """-> (o [B, H, Tq, dh], lse [B*H, 1, Tq] f32). The lse is kept as ROWS:
+    dense in HBM (a [.., Tq, 1] f32 array is tiled (8, 128) on its last two
+    dimensions, 128 times its size) and what the resident kernels read."""
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, Tq, dh = q.shape
@@ -429,7 +468,8 @@ def _flash_fwd_impl(q, k, v, q_offset, k_offset, prefix_len, block_q, block_k,
     scale = 1.0 / math.sqrt(dh)
     qr, kr, vr = _bh(q), _bh(k), _bh(v)
     BH = B * H
-    streaming = _use_streaming(Tk, dh, q.dtype.itemsize, bq, bk, stream)
+    streaming = _use_streaming(Tk, dh, q.dtype.itemsize, bq, bk, stream,
+                               interpret=interpret)
     f32 = jnp.float32
 
     kw = dict(scale=scale, block_k=bk, q_offset=q_offset, k_offset=k_offset,
@@ -444,10 +484,13 @@ def _flash_fwd_impl(q, k, v, q_offset, k_offset, prefix_len, block_q, block_k,
         ]
         out_specs = [
             pl.BlockSpec((1, bq, dh), lambda b, i, j: (b, i, 0)),
+            # [T, 1] (not [T]): TPU block tiling wants two trailing dims
             pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
         ]
+        lse_shape = (BH, Tq, 1)
         scratch = [pltpu.VMEM((bq, 1), f32), pltpu.VMEM((bq, 1), f32),
                    pltpu.VMEM((bq, dh), f32)]
+        semantics = ("parallel", "parallel", "arbitrary")
     else:
         kern = functools.partial(_fwd_kernel_res, **kw)
         grid = (BH, num_q)
@@ -458,9 +501,11 @@ def _flash_fwd_impl(q, k, v, q_offset, k_offset, prefix_len, block_q, block_k,
         ]
         out_specs = [
             pl.BlockSpec((1, bq, dh), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, bq, 1), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, 1, bq), lambda b, i: (b, 0, i)),
         ]
+        lse_shape = (BH, 1, Tq)
         scratch = []
+        semantics = ("parallel", "parallel")
 
     o, lse = pl.pallas_call(
         kern,
@@ -469,14 +514,14 @@ def _flash_fwd_impl(q, k, v, q_offset, k_offset, prefix_len, block_q, block_k,
         out_specs=out_specs,
         out_shape=[
             _out_struct((BH, Tq, dh), q.dtype, q, k, v),
-            _out_struct((BH, Tq, 1), jnp.float32, q, k, v),
+            _out_struct(lse_shape, jnp.float32, q, k, v),
         ],
         scratch_shapes=scratch,
         interpret=interpret,
         name="flash_attn_fwd",
-        **_grid_params(interpret, streaming),
+        **_grid_params(interpret, *semantics),
     )(qr, kr, vr)
-    return o.reshape(B, H, Tq, dh), lse
+    return o.reshape(B, H, Tq, dh), lse.reshape(BH, 1, Tq)
 
 
 def _flash_fwd(q, k, v, q_offset, k_offset, prefix_len, block_q, block_k,
@@ -502,7 +547,6 @@ def _flash_bwd_core(q_offset, k_offset, prefix_len, block_q, block_k,
     bq = _pick_block(Tq, block_q, interpret)
     bk = _pick_block(Tk, block_k, interpret)
     num_q, num_k = Tq // bq, Tk // bk
-    scale = 1.0 / math.sqrt(dh)
     BH = B * H
     isz = q.dtype.itemsize
 
@@ -514,104 +558,69 @@ def _flash_bwd_core(q_offset, k_offset, prefix_len, block_q, block_k,
     if g_lse is not None:
         delta = delta - g_lse.astype(jnp.float32)
     qr, kr, vr, gr = _bh(q), _bh(k), _bh(v), _bh(g)
-    delta_r = delta.reshape(BH, Tq, 1)
     f32 = jnp.float32
+    shape4 = lambda x, T: x.reshape(B, H, T, dh)
+    grad_of = lambda x: _out_struct(x.shape, x.dtype, qr, kr, vr, gr)
+    kw = dict(scale=1.0 / math.sqrt(dh), q_offset=q_offset,
+              k_offset=k_offset, prefix_len=prefix_len)
 
-    dq_kw = dict(scale=scale, block_k=bk, q_offset=q_offset,
-                 k_offset=k_offset, num_k=num_k, prefix_len=prefix_len)
-    dq_streaming = _use_streaming(Tk, dh, isz, bq, bk, stream)
-    if dq_streaming:
-        dq_kern = functools.partial(_dq_kernel_stream, **dq_kw)
-        dq_grid = (BH, num_q, num_k)
-        dq_in = [
-            pl.BlockSpec((1, bq, dh), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bk, dh), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bk, dh), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bq, dh), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
-        ]
-        dq_out = pl.BlockSpec((1, bq, dh), lambda b, i, j: (b, i, 0))
-        dq_scratch = [pltpu.VMEM((bq, dh), f32)]
-    else:
-        dq_kern = functools.partial(_dq_kernel_res, **dq_kw)
-        dq_grid = (BH, num_q)
-        dq_in = [
-            pl.BlockSpec((1, bq, dh), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, Tk, dh), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, Tk, dh), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, bq, dh), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, bq, 1), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, bq, 1), lambda b, i: (b, i, 0)),
-        ]
-        dq_out = pl.BlockSpec((1, bq, dh), lambda b, i: (b, i, 0))
-        dq_scratch = []
+    # the one-pass kernel keeps the Q side resident: Q, dO, lse, delta, dQ
+    if not _use_streaming(Tq, dh, isz, bq, bk, stream, dq_rows=Tq,
+                          interpret=interpret):
+        k_blk = pl.BlockSpec((1, bk, dh), lambda b, j: (b, j, 0))
+        q_all = pl.BlockSpec((1, Tq, dh), lambda b, j: (b, 0, 0))
+        row = pl.BlockSpec((1, 1, Tq), lambda b, j: (b, 0, 0))
+        dq, dk, dv = pl.pallas_call(
+            functools.partial(_dq_dkv_kernel_res, block_q=bq, num_q=num_q,
+                              num_k=num_k, **kw),
+            grid=(BH, num_k),
+            in_specs=[k_blk, k_blk, q_all, q_all, row, row],
+            out_specs=[q_all, k_blk, k_blk],
+            out_shape=[grad_of(qr), grad_of(kr), grad_of(vr)],
+            scratch_shapes=[pltpu.VMEM((dh, Tq), f32)],
+            interpret=interpret,
+            # benchmarks/kernels/flash_attn.py finds the flash kernels'
+            # trace events by substring: "flash_attn_dq" has to be in it
+            name="flash_attn_dq_dkv",
+            # 25 MiB at T=8192 (dh=64, bf16), over the 16 MiB default
+            **_grid_params(interpret, "parallel", "arbitrary",
+                           vmem_limit_bytes=(16 << 20) + 3072 * Tq),
+        )(kr, vr, qr, gr, lse, delta.reshape(BH, 1, Tq))
+        return shape4(dq, Tq), shape4(dk, Tk), shape4(dv, Tk)
 
+    # the streaming pair reads lse and delta as columns, blockwise
+    lse_c, delta_c = lse.reshape(BH, Tq, 1), delta.reshape(BH, Tq, 1)
+    semantics = ("parallel", "parallel", "arbitrary")
+    q_blk = pl.BlockSpec((1, bq, dh), lambda b, i, j: (b, i, 0))
+    q_col = pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0))
+    k_blk = pl.BlockSpec((1, bk, dh), lambda b, i, j: (b, j, 0))
     dq = pl.pallas_call(
-        dq_kern,
-        grid=dq_grid,
-        in_specs=dq_in,
-        out_specs=dq_out,
-        out_shape=_out_struct((BH, Tq, dh), q.dtype, qr, kr, vr, gr),
-        scratch_shapes=dq_scratch,
+        functools.partial(_dq_kernel_stream, block_k=bk, num_k=num_k, **kw),
+        grid=(BH, num_q, num_k),
+        in_specs=[q_blk, k_blk, k_blk, q_blk, q_col, q_col],
+        out_specs=q_blk,
+        out_shape=grad_of(qr),
+        scratch_shapes=[pltpu.VMEM((bq, dh), f32)],
         interpret=interpret,
         name="flash_attn_dq",
-        **_grid_params(interpret, dq_streaming),
-    )(qr, kr, vr, gr, lse, delta_r)
+        **_grid_params(interpret, *semantics),
+    )(qr, kr, vr, gr, lse_c, delta_c)
 
     # the dkv kernel streams Q-side operands: Q, dO, lse, delta
-    dkv_kw = dict(scale=scale, block_q=bq, q_offset=q_offset,
-                  k_offset=k_offset, num_q=num_q, prefix_len=prefix_len)
-    dkv_streaming = _use_streaming(Tq, dh, isz, bq, bk, stream)
-    if dkv_streaming:
-        dkv_kern = functools.partial(_dkv_kernel_stream, **dkv_kw)
-        dkv_grid = (BH, num_k, num_q)
-        dkv_in = [
-            pl.BlockSpec((1, bk, dh), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, bk, dh), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, bq, dh), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, bq, dh), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, bq, 1), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, bq, 1), lambda b, j, i: (b, i, 0)),
-        ]
-        dkv_out = [
-            pl.BlockSpec((1, bk, dh), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, bk, dh), lambda b, j, i: (b, j, 0)),
-        ]
-        dkv_scratch = [pltpu.VMEM((bk, dh), f32), pltpu.VMEM((bk, dh), f32)]
-    else:
-        dkv_kern = functools.partial(_dkv_kernel_res, **dkv_kw)
-        dkv_grid = (BH, num_k)
-        dkv_in = [
-            pl.BlockSpec((1, bk, dh), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, bk, dh), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, Tq, dh), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, Tq, dh), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, Tq, 1), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, Tq, 1), lambda b, j: (b, 0, 0)),
-        ]
-        dkv_out = [
-            pl.BlockSpec((1, bk, dh), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, bk, dh), lambda b, j: (b, j, 0)),
-        ]
-        dkv_scratch = []
-
+    k_blk = pl.BlockSpec((1, bk, dh), lambda b, j, i: (b, j, 0))
+    q_blk = pl.BlockSpec((1, bq, dh), lambda b, j, i: (b, i, 0))
+    q_col = pl.BlockSpec((1, bq, 1), lambda b, j, i: (b, i, 0))
     dk, dv = pl.pallas_call(
-        dkv_kern,
-        grid=dkv_grid,
-        in_specs=dkv_in,
-        out_specs=dkv_out,
-        out_shape=[
-            _out_struct((BH, Tk, dh), k.dtype, qr, kr, vr, gr),
-            _out_struct((BH, Tk, dh), v.dtype, qr, kr, vr, gr),
-        ],
-        scratch_shapes=dkv_scratch,
+        functools.partial(_dkv_kernel_stream, block_q=bq, num_q=num_q, **kw),
+        grid=(BH, num_k, num_q),
+        in_specs=[k_blk, k_blk, q_blk, q_blk, q_col, q_col],
+        out_specs=[k_blk, k_blk],
+        out_shape=[grad_of(kr), grad_of(vr)],
+        scratch_shapes=[pltpu.VMEM((bk, dh), f32), pltpu.VMEM((bk, dh), f32)],
         interpret=interpret,
         name="flash_attn_dkv",
-        **_grid_params(interpret, dkv_streaming),
-    )(kr, vr, qr, gr, lse, delta_r)
-
-    shape4 = lambda x, T: x.reshape(B, H, T, dh)
+        **_grid_params(interpret, *semantics),
+    )(kr, vr, qr, gr, lse_c, delta_c)
     return shape4(dq, Tq), shape4(dk, Tk), shape4(dv, Tk)
 
 
@@ -631,7 +640,7 @@ def flash_attention_lse(q, k, v, q_offset=0, k_offset=0, prefix_len=0,
     lse_tot = logaddexp_i(lse_i) (models/transformer.py ring_attention).
     Both outputs are differentiable: d lse/d scores = p, which folds into the
     existing backward kernels as a delta shift (ds = p∘(dp - (delta - lse_bar))),
-    so the dq/dkv kernels are reused unchanged.
+    so the backward kernels are reused unchanged.
     """
     out, _ = _flash_lse_fwd(q, k, v, q_offset, k_offset, prefix_len, block_q,
                             block_k, interpret, stream)

@@ -296,8 +296,8 @@ def fused_linear_xent_eval(h, w, labels, k: int = 5, row_chunk: int = 512):
 # carried across the inner v sweep; per-row (lse, gold, zsum, argmax) written
 # on the last v block and reduced to the three sums with trivial XLA ops.
 # Backward: dh kernel accumulates dz @ W_j^T over the inner v sweep; dW kernel
-# flips the grid and accumulates h_i^T @ dz over the inner row sweep — the
-# same two-kernel split as ops/flash_attention.py's dq / dkv.
+# flips the grid and accumulates h_i^T @ dz over the inner row sweep (the
+# two-kernel split of ops/flash_attention.py's streaming dq / dkv pair).
 # ---------------------------------------------------------------------------
 
 ROW_BLOCK = 256

@@ -12,9 +12,18 @@ One JSON line per T:
 Sync discipline follows tools/timing.py: chain nothing (the primitive is
 stateless) and close each timed region on jax.block_until_ready.
 
+``--tiles 256x256,512x512`` sweeps the flash kernels' (block_q x block_k)
+instead — the evidence behind flash_attention's default blocks. It reads
+DEVICE time per kernel from a profiler trace (a host clock around a 1 ms
+kernel times the dispatch), one JSON line per (T, tile):
+
+    {"T": 1024, ..., "block_q": 512, "block_k": 512,
+     "kernel_ms": {"flash_attn_fwd": N, "flash_attn_dq_dkv": N}}
+
 Usage:
     python -m ddlbench_tpu.tools.attnbench [--seq-lens 128,256,512,1024]
         [--batch 16] [--heads 8] [--head-dim 64] [--prefix 0] [--steps 50]
+        [--tiles 128x128,256x256,512x512]
 """
 
 from __future__ import annotations
@@ -23,6 +32,41 @@ import argparse
 import json
 import sys
 import time
+
+
+def flash_kernel_ms(fn, xs, steps: int) -> dict:
+    """{flash kernel name: device ms per call of fn} from a profiler trace."""
+    import collections
+    import glob
+    import re
+    import shutil
+    import tempfile
+
+    import jax
+
+    jax.block_until_ready(fn(*xs))
+    trace_dir = tempfile.mkdtemp(prefix="attnbench_")
+    try:
+        jax.profiler.start_trace(trace_dir)
+        for _ in range(steps):
+            out = fn(*xs)
+        jax.block_until_ready(out)
+        jax.profiler.stop_trace()
+        (path,) = glob.glob(f"{trace_dir}/plugins/profile/*/*.xplane.pb")
+        ns = collections.defaultdict(int)
+        for plane in jax.profiler.ProfileData.from_file(path).planes:
+            if plane.name != "/device:TPU:0":
+                continue
+            for line in plane.lines:
+                if line.name != "XLA Ops":
+                    continue
+                for e in line.events:
+                    m = re.search(r"flash_attn_(fwd|dq_dkv|dq|dkv)", e.name)
+                    if m:
+                        ns[m.group(0)] += e.duration_ns
+    finally:
+        shutil.rmtree(trace_dir, ignore_errors=True)
+    return {k: round(v / steps * 1e-6, 4) for k, v in sorted(ns.items())}
 
 
 def main(argv=None) -> int:
@@ -37,6 +81,9 @@ def main(argv=None) -> int:
     p.add_argument("--repeats", type=int, default=1,
                    help="timed loops per cell; the reported ms is the median")
     p.add_argument("--dtype", default="bfloat16")
+    p.add_argument("--tiles", default="",
+                   help="BQxBK,...: sweep the flash kernels' blocks (TPU "
+                        "only), device ms per kernel from a trace")
     from ddlbench_tpu.distributed import add_platform_arg, apply_platform
 
     add_platform_arg(p)
@@ -53,6 +100,10 @@ def main(argv=None) -> int:
     enable_compilation_cache()
     backends = ("flash", "xla") if is_tpu_backend() else ("xla",)
     dtype = jnp.dtype(args.dtype)
+    tiles = [tuple(int(b) for b in t.split("x"))
+             for t in args.tiles.split(",") if t]
+    if tiles and not is_tpu_backend():
+        p.error("--tiles times the compiled kernels: needs a TPU backend")
 
     def timed_once(f, *xs):
         jax.block_until_ready(f(*xs))
@@ -79,6 +130,17 @@ def main(argv=None) -> int:
         row = {"T": T, "B": args.batch, "H": args.heads,
                "dh": args.head_dim, "prefix": args.prefix,
                "dtype": args.dtype, "repeats": args.repeats}
+        for bq, bk in tiles:
+            from ddlbench_tpu.ops.flash_attention import flash_attention
+
+            g = jax.jit(jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+                q, k, v, 0, 0, args.prefix, bq, bk).astype(jnp.float32)),
+                argnums=(0, 1, 2)))
+            print(json.dumps({**row, "block_q": bq, "block_k": bk,
+                              "kernel_ms": flash_kernel_ms(
+                                  g, (q, k, v), args.steps)}), flush=True)
+        if tiles:
+            continue
         for mode in backends:
             set_attention_backend(mode)
             try:

@@ -353,17 +353,23 @@ def test_paged_kernel_flag_is_gone(tool):
     assert not hasattr(paged_decode, "set_paged_kernel_style")
 
 
-def test_pallas_kernels_read_from_compiled_hlo():
+@pytest.mark.parametrize("backward,count", [
+    ("flash_attn_dq", {"flash_attn_dq": 1}),
+    # the one-pass backward's name holds the two-kernel pair's as substrings;
+    # the manifest has to count it under its own
+    ("flash_attn_dq_dkv", {"flash_attn_dq_dkv": 1}),
+])
+def test_pallas_kernels_read_from_compiled_hlo(backward, count):
     from ddlbench_tpu.telemetry.audit import pallas_kernels
 
     hlo = '''
-  %a = bf16[2] custom-call(%x), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/transpose(jvp(flash_attn_dq))/pallas_call" stack_frame_id=3}
+  %a = bf16[2] custom-call(%x), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/transpose(jvp(BACKWARD))/pallas_call" stack_frame_id=3}
   %b = bf16[2] custom-call(%x), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/jvp(flash_attn_fwd)/pallas_call"}
   %c = f32[2] custom-call(%y), custom_call_target="tpu_custom_call", metadata={op_name="jit(decode_fn)/paged_decode_attn/pallas_call"}
   %d = f32[2] custom-call(%y), custom_call_target="tpu_custom_call", metadata={op_name="jit(decode_fn)/paged_decode_attn/pallas_call"}
   %e = f32[2] custom-call(%y), custom_call_target="Sharding", metadata={op_name="jit(f)/x"}
-'''
-    assert pallas_kernels(hlo) == {"flash_attn_dq": 1, "flash_attn_fwd": 1,
+'''.replace("BACKWARD", backward)
+    assert pallas_kernels(hlo) == {**count, "flash_attn_fwd": 1,
                                    "paged_decode_attn": 2}
     # a CPU program (reference / interpret paths) holds none
     assert pallas_kernels(jax.jit(lambda x: x * 2).lower(1.0).compile()

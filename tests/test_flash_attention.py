@@ -5,18 +5,22 @@ comparisons force highest matmul precision; tolerances then reflect only the
 kernel's own (f32-accumulated) arithmetic.
 """
 
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-pytestmark = pytest.mark.slow  # compile-heavy (see conftest --runslow)
-
 from ddlbench_tpu.models.transformer import (
     causal_attention,
     set_attention_backend,
 )
-from ddlbench_tpu.ops.flash_attention import _pick_block, flash_attention
+from ddlbench_tpu.ops.flash_attention import (_pick_block, flash_attention,
+                                              flash_attention_lse)
+
+# the module itself: ddlbench_tpu.ops re-exports the function under its name
+fa = importlib.import_module("ddlbench_tpu.ops.flash_attention")
 
 
 def _rand(shape, key):
@@ -57,21 +61,52 @@ def test_forward_matches_reference():
                                rtol=1e-4, atol=1e-5)
 
 
-def test_grads_match_reference():
-    B, H, T, dh = 1, 2, 64, 16
+# (Tq, Tk, q_offset, k_offset, prefix_len, block_q, block_k): the backward
+# (one-pass resident kernel, stream=None at these sizes) against the
+# gradients of the jnp reference
+GRAD_CASES = {
+    "causal": (64, 64, 0, 0, 0, 32, 32),
+    "causal_fine_tiles": (64, 64, 0, 0, 0, 8, 8),
+    "prefix": (64, 64, 0, 0, 24, 16, 16),
+    "prefix_cuts_a_tile": (96, 96, 0, 0, 40, 32, 32),
+    "prefix_covers_k_tiles": (64, 64, 0, 0, 48, 16, 16),
+    # queries 0..63 vs keys at absolute 10..73: rows 0-9 fully masked
+    # (lse ~ -1e30) must give zero — not NaN — gradients
+    "k_offset_masks_rows": (64, 64, 0, 10, 0, 32, 32),
+    "q_offset_ring_block": (64, 128, 500, 0, 0, 32, 32),
+    "q_offset_mid_tile": (48, 48, 8, 0, 0, 16, 16),
+    "both_offsets": (64, 64, 40, 24, 0, 16, 16),
+    "fully_masked_block": (32, 32, 0, 1000, 0, 16, 16),
+    "offsets_and_prefix": (64, 64, 8, 0, 20, 16, 16),
+    "bq_lt_bk": (64, 64, 0, 0, 0, 16, 32),
+    "bq_gt_bk": (64, 64, 0, 0, 0, 32, 16),
+    "bq_gt_bk_prefix": (64, 64, 0, 0, 24, 32, 8),
+    "uneven_tiling": (96, 96, 0, 0, 0, 64, 64),   # tiles shrink to 48
+    "uneven_tq_ne_tk": (48, 96, 48, 0, 0, 32, 64),
+    "one_tile": (32, 32, 0, 0, 0, 64, 64),
+}
+
+
+def _grads(fn, q, k, v, g):
+    return jax.grad(lambda *a: jnp.sum(fn(*a) * g), argnums=(0, 1, 2))(q, k, v)
+
+
+@pytest.mark.parametrize("case", GRAD_CASES)
+def test_grads_match_reference(case):
+    Tq, Tk, qo, ko, pfx, bq, bk = GRAD_CASES[case]
+    B, H, dh = 1, 2, 16
     ks = jax.random.split(jax.random.key(1), 4)
-    q, k, v, g = (_rand((B, H, T, dh), kk) for kk in ks)
+    q, g = _rand((B, H, Tq, dh), ks[0]), _rand((B, H, Tq, dh), ks[3])
+    k, v = _rand((B, H, Tk, dh), ks[1]), _rand((B, H, Tk, dh), ks[2])
     with jax.default_matmul_precision("highest"):
-        ref_g = jax.grad(
-            lambda *a: jnp.sum(causal_attention(*a) * g), argnums=(0, 1, 2)
-        )(q, k, v)
-        fa_g = jax.grad(
-            lambda *a: jnp.sum(flash_attention(*a, 0, 0, 0, 32, 32, True) * g),
-            argnums=(0, 1, 2),
-        )(q, k, v)
+        ref_g = _grads(lambda *a: causal_attention(
+            *a, q_offset=qo, k_offset=ko, prefix_len=pfx), q, k, v, g)
+        fa_g = _grads(lambda *a: flash_attention(
+            *a, qo, ko, pfx, bq, bk, True), q, k, v, g)
     for a, b in zip(ref_g, fa_g):
+        assert np.all(np.isfinite(np.asarray(b)))
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-3, atol=1e-3)
+                                   rtol=1e-4, atol=1e-4)
 
 
 def test_offsets_match_reference():
@@ -86,31 +121,6 @@ def test_offsets_match_reference():
         got = flash_attention(q, k, v, 500, 0, 0, 32, 32, True)
     np.testing.assert_allclose(np.asarray(ref), np.asarray(got),
                                rtol=1e-4, atol=1e-5)
-
-
-def test_offset_grads_no_nan():
-    """Regression: rows fully masked by k_offset (lse ~ -1e30) must produce
-    zero — not NaN — gradients through the backward kernels."""
-    B, H, dh = 1, 2, 16
-    ks = jax.random.split(jax.random.key(6), 4)
-    q = _rand((B, H, 64, dh), ks[0])
-    k = _rand((B, H, 64, dh), ks[1])
-    v = _rand((B, H, 64, dh), ks[2])
-    g = _rand((B, H, 64, dh), ks[3])
-    with jax.default_matmul_precision("highest"):
-        # queries 0..63 vs keys at absolute 10..73: rows 0-9 fully masked
-        fa_g = jax.grad(
-            lambda *a: jnp.sum(flash_attention(*a, 0, 10, 0, 32, 32, True) * g),
-            argnums=(0, 1, 2),
-        )(q, k, v)
-        ref_g = jax.grad(
-            lambda *a: jnp.sum(causal_attention(*a, q_offset=0, k_offset=10) * g),
-            argnums=(0, 1, 2),
-        )(q, k, v)
-    for a, b in zip(ref_g, fa_g):
-        assert np.all(np.isfinite(np.asarray(b)))
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-3, atol=1e-3)
 
 
 def test_fully_masked_is_zero():
@@ -173,26 +183,6 @@ def test_prefix_forward_matches_reference():
     assert not np.allclose(np.asarray(got), np.asarray(causal))
 
 
-def test_prefix_grads_match_reference():
-    B, H, T, dh = 1, 2, 64, 16
-    S = 24
-    ks = jax.random.split(jax.random.key(8), 4)
-    q, k, v = (_rand((B, H, T, dh), kk) for kk in ks[:3])
-    g = _rand((B, H, T, dh), ks[3])
-    with jax.default_matmul_precision("highest"):
-        ref_grads = jax.grad(
-            lambda *a: jnp.sum(causal_attention(*a, prefix_len=S) * g),
-            argnums=(0, 1, 2),
-        )(q, k, v)
-        got_grads = jax.grad(
-            lambda *a: jnp.sum(flash_attention(*a, 0, 0, S, 16, 16, True) * g),
-            argnums=(0, 1, 2),
-        )(q, k, v)
-    for r, got in zip(ref_grads, got_grads):
-        np.testing.assert_allclose(np.asarray(got), np.asarray(r),
-                                   rtol=5e-5, atol=5e-5)
-
-
 def _ref_with_lse(q, k, v, q_offset=0, k_offset=0):
     """(o, lse) from the plain jnp path, matching flash_attention_lse."""
     import math as _math
@@ -212,8 +202,6 @@ def _ref_with_lse(q, k, v, q_offset=0, k_offset=0):
 
 
 def test_lse_output_matches_reference():
-    from ddlbench_tpu.ops.flash_attention import flash_attention_lse
-
     B, H, T, dh = 2, 2, 64, 16
     ks = jax.random.split(jax.random.key(7), 3)
     q, k, v = (_rand((B, H, T, dh), kk) for kk in ks)
@@ -226,20 +214,26 @@ def test_lse_output_matches_reference():
                                rtol=1e-5, atol=1e-5)
 
 
-def test_lse_cotangent_flows():
-    """Gradients through BOTH outputs (the ring-combination use case)."""
-    from ddlbench_tpu.ops.flash_attention import flash_attention_lse
-
+# (q_offset, block_q, block_k, stream): ring attention's diagonal block
+# (offset 0) and its "whole block visible" case (q_offset = Tl)
+@pytest.mark.parametrize("qoff,bq,bk,stream", [
+    (0, 8, 8, None), (32, 8, 8, None), (0, 16, 8, None), (32, 8, 16, None),
+    (0, 8, 8, True)])
+def test_lse_cotangent_flows(qoff, bq, bk, stream):
+    """Gradients through BOTH outputs (the ring-combination use case): the
+    lse cotangent is a delta shift, so the one-pass kernel (and the
+    streaming pair) carry it unchanged."""
     B, H, T, dh = 1, 2, 32, 8
     ks = jax.random.split(jax.random.key(8), 3)
     q, k, v = (_rand((B, H, T, dh), kk) for kk in ks)
 
     def f_flash(q, k, v):
-        o, lse = flash_attention_lse(q, k, v, 0, 0, 0, 8, 8, True)
+        o, lse = flash_attention_lse(q, k, v, qoff, 0, 0, bq, bk, True,
+                                     stream)
         return jnp.sum(o * 0.3) + jnp.sum(jnp.sin(lse))
 
     def f_ref(q, k, v):
-        o, lse = _ref_with_lse(q, k, v)
+        o, lse = _ref_with_lse(q, k, v, q_offset=qoff)
         return jnp.sum(o * 0.3) + jnp.sum(jnp.sin(lse))
 
     with jax.default_matmul_precision("highest"):
@@ -250,33 +244,102 @@ def test_lse_cotangent_flows():
                                    rtol=2e-4, atol=2e-5)
 
 
-def test_streaming_design_matches_resident():
-    """The two grid designs (resident fori vs streaming 3D scratch) share
-    their block math and must agree bit-for-bit-close; the hybrid picks per
-    shape on TPU (flash_attention.py _use_streaming), so both paths need
-    coverage off-chip. Covers causal, offsets, and prefix-LM."""
+def _kernel_names(fn, *xs):
+    import re
+
+    return set(re.findall(r"flash_attn_\w+", str(jax.make_jaxpr(fn)(*xs))))
+
+
+@pytest.mark.parametrize("pfx,qoff,bq,bk", [
+    (0, 0, 16, 16), (16, 0, 16, 16), (0, 8, 16, 16), (20, 0, 8, 16)])
+def test_streaming_design_matches_resident(pfx, qoff, bq, bk):
+    """The two grid designs share their block math and must agree closely:
+    resident forward + ONE-PASS backward (dq, dk, dv from one kernel)
+    against the streaming forward + two-kernel backward, on one input. The
+    rule picks per shape on TPU (_use_streaming), so both need coverage
+    off-chip."""
     B, H, T, dh = 1, 2, 48, 8
     ks = jax.random.split(jax.random.key(11), 3)
     q, k, v = (_rand((B, H, T, dh), kk) for kk in ks)
 
-    for pfx, qoff in ((0, 0), (16, 0), (0, 8)):
-        def f(q, k, v, stream):
-            o = flash_attention(q, k, v, qoff, 0, pfx, 16, 16, True, stream)
-            return jnp.sum(o ** 2)
+    def f(q, k, v, stream):
+        o = flash_attention(q, k, v, qoff, 0, pfx, bq, bk, True, stream)
+        return jnp.sum(o ** 2)
 
-        with jax.default_matmul_precision("highest"):
-            vr, gr = jax.value_and_grad(
-                lambda *xs: f(*xs, False), argnums=(0, 1, 2))(q, k, v)
-            vs, gs = jax.value_and_grad(
-                lambda *xs: f(*xs, True), argnums=(0, 1, 2))(q, k, v)
-        np.testing.assert_allclose(float(vs), float(vr), rtol=1e-6)
-        for a, b in zip(gs, gr):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       rtol=1e-5, atol=1e-6)
+    res = jax.value_and_grad(lambda *xs: f(*xs, False), argnums=(0, 1, 2))
+    stream = jax.value_and_grad(lambda *xs: f(*xs, True), argnums=(0, 1, 2))
+    assert _kernel_names(res, q, k, v) == {"flash_attn_fwd",
+                                           "flash_attn_dq_dkv"}
+    assert _kernel_names(stream, q, k, v) == {
+        "flash_attn_fwd", "flash_attn_dq", "flash_attn_dkv"}
+    with jax.default_matmul_precision("highest"):
+        vr, gr = res(q, k, v)
+        vs, gs = stream(q, k, v)
+    np.testing.assert_allclose(float(vs), float(vr), rtol=1e-6)
+    for a, b in zip(gs, gr):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("stream", [False, True])
+def test_lse_is_kept_as_rows(stream):
+    """Both designs hand the backward (and flash_attention_lse) the lse as
+    [B*H, 1, Tq]: dense in HBM, where [B*H, Tq, 1] f32 is tiled (8, 128) on
+    its last two dimensions — 128 times the bytes, 100 MB a layer at the
+    benchmark's shape."""
+    B, H, T, dh = 2, 2, 64, 16
+    ks = jax.random.split(jax.random.key(13), 3)
+    q, k, v = (_rand((B, H, T, dh), kk) for kk in ks)
+    with jax.default_matmul_precision("highest"):
+        o, lse = fa._flash_fwd_impl(q, k, v, 0, 0, 0, 16, 32, True, stream)
+        o_r, lse_r = _ref_with_lse(q, k, v)
+    assert lse.shape == (B * H, 1, T) and lse.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(o), np.asarray(o_r),
+                               rtol=1e-4, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(lse).reshape(B, H, T),
+                               np.asarray(lse_r), rtol=1e-5, atol=1e-5)
+
+
+def _visible(q_lo, bq, k_lo, bk, pfx):
+    qp = q_lo + np.arange(bq)[:, None]
+    kp = k_lo + np.arange(bk)[None, :]
+    return (qp >= kp) | (kp < pfx)
+
+
+@pytest.mark.parametrize("T,bq,bk,qoff,koff,pfx", [
+    (1024, 256, 256, 0, 0, 0), (1024, 512, 512, 0, 0, 0),
+    (1024, 128, 256, 0, 0, 0), (1024, 256, 128, 0, 0, 0),
+    (96, 32, 32, 0, 0, 40), (64, 16, 16, 0, 10, 0), (64, 16, 16, 40, 24, 0),
+    (64, 32, 8, 8, 0, 20), (64, 8, 32, 0, 0, 33), (128, 32, 32, 500, 0, 0),
+    (32, 16, 16, 0, 1000, 0), (128, 128, 128, 128, 0, 0)])
+def test_sweep_bounds_cover_every_live_tile(T, bq, bk, qoff, koff, pfx):
+    """The forward sweeps K blocks [0, bound) of a Q block, the one-pass
+    backward Q blocks [start, num_q) of a K block: no tile with a visible
+    element may be left out, and under pure causal masking none without one
+    is computed — against the elementwise mask, tile by tile."""
+    nq, nk = T // bq, T // bk
+    live = {(i, j): _visible(qoff + i * bq, bq, koff + j * bk, bk, pfx).any()
+            for i in range(nq) for j in range(nk)}
+    for i in range(nq):
+        bound = int(fa._causal_kv_bound(qoff + (i + 1) * bq - 1, koff, bk,
+                                        nk, pfx))
+        want = [j for j in range(nk) if live[i, j]]
+        assert 0 <= bound <= nk and set(range(bound)) >= set(want)
+        if not pfx:
+            assert list(range(bound)) == want
+    for j in range(nk):
+        start = int(fa._first_q_block(koff + j * bk, qoff, bq, nq, pfx))
+        want = [i for i in range(nq) if live[i, j]]
+        assert 0 <= start <= nq and set(range(start, nq)) >= set(want)
+        if not pfx:
+            assert list(range(start, nq)) == want
+    if (T, bq, bk, qoff, koff, pfx) == (1024, 512, 512, 0, 0, 0):
+        assert sum(live.values()) == 3  # the benchmark cell: 3 of 4 tiles
 
 
 def test_use_streaming_rule():
-    from ddlbench_tpu.ops.flash_attention import (RESIDENT_MAX_BYTES,
+    from ddlbench_tpu.ops.flash_attention import (RESIDENT_DQ_MAX_BYTES,
+                                                  RESIDENT_MAX_BYTES,
                                                   _use_streaming)
 
     # benchmarked shapes stay resident: T=8192, dh=64, bf16 = 2 MiB
@@ -294,3 +357,44 @@ def test_use_streaming_rule():
     # explicit override wins both ways
     assert _use_streaming(64, 8, 2, 8, 8, True)
     assert not _use_streaming(1 << 20, 64, 2, 512, 512, False)
+    assert RESIDENT_MAX_BYTES == 3 << 20 and RESIDENT_DQ_MAX_BYTES == 2 << 20
+    # the resident kernels put the queries on the lanes: a compiled q block
+    # that is no multiple of 128 (T=648 -> 216) streams; the interpreter
+    # takes any
+    assert _use_streaming(648, 64, 2, 216, 216, None)
+    assert not _use_streaming(648, 64, 2, 216, 216, None, interpret=True)
+    assert not _use_streaming(768, 64, 2, 384, 384, None)
+
+
+@pytest.mark.parametrize("T,dh,itemsize,fused", [
+    (1024, 64, 2, True),     # the benchmark cell: dQ block 256 KiB
+    (8192, 64, 2, True),     # longctx: 2 MiB, the most that stays resident
+    (16384, 64, 2, False),   # 4 MiB of f32 dQ (and 4 MiB of Q + dO)
+    (32768, 64, 2, False),
+    (8192, 128, 2, False),   # wide heads: 4 MiB of dQ at 8k
+    (4096, 128, 2, True),
+    (8192, 64, 4, False),    # f32 operands: Q + dO alone pass the budget
+])
+def test_one_pass_backward_where_its_dq_block_fits(T, dh, itemsize, fused):
+    """The backward's one more term in the rule: the one-pass kernel keeps
+    the f32 dQ of the whole row block resident, so it serves a shape only
+    while that fits; the streaming pair takes the rest. Tiles: 512x512 at
+    every length (the chip sweep's winner at T=1024..8192)."""
+    from ddlbench_tpu.ops.flash_attention import _use_streaming
+
+    bq = bk = _pick_block(T, 512)
+    assert bq == 512
+    assert _use_streaming(T, dh, itemsize, bq, bk, None, dq_rows=T) \
+        == (not fused)
+    assert _use_streaming(T, dh, itemsize, bq, bk, True, dq_rows=T)
+    assert not _use_streaming(T, dh, itemsize, bq, bk, False, dq_rows=T)
+
+
+def test_attnbench_tile_sweep_needs_the_compiled_kernels():
+    """`attnbench --tiles` reads device time per kernel from a trace: off
+    the TPU there is nothing to time, and it says so instead of timing the
+    interpreter."""
+    from ddlbench_tpu.tools.attnbench import main
+
+    with pytest.raises(SystemExit):
+        main(["--seq-lens", "64", "--tiles", "32x32", "--platform", "cpu"])

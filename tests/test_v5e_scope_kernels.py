@@ -3,11 +3,13 @@
 A trace event of a Pallas kernel is named after its HLO instruction, and the
 instruction's name is built from the name stack it was traced under: inside
 the program's scopes ``jvp_flash_attn_fwd_.12`` became ``flash_attn_fwd.12``.
-``benchmarks/kernels/*.py`` find a kernel by a substring of that name, so the
-six substrings have to survive wherever a scope is put. Checked here by
-compiling the program's own attention sublayer and fused head loss, forward
-and backward, inside their scopes, for a described v5e chip at gpt2-small's
-widths (about two seconds each; nothing runs, no number is a device's).
+``benchmarks/kernels/*.py`` find a kernel by a substring of that name, so a
+kernel's name has to hold one of the six substrings wherever a scope is put
+(the one-pass backward ``flash_attn_dq_dkv`` holds ``flash_attn_dq``).
+Checked here by compiling the program's own attention sublayer and fused head
+loss, forward and backward, inside their scopes, for a described v5e chip at
+gpt2-small's widths (about two seconds each; nothing runs, no number is a
+device's).
 
 The topology is described inside a fixture, never at import: one process at
 a time may load the TPU's library (the on-chip-measurement guide, section 2).
@@ -96,9 +98,40 @@ def test_flash_attention_keeps_its_names_inside_the_scopes(one_chip,
     found = _mosaic_calls(
         jax.grad(loss), one_chip, ((B, T, D), jnp.bfloat16),
         *[(a.shape, jnp.bfloat16) for a in leaves])
+    # the sublayer holds exactly the forward and the one-pass backward
+    assert sorted(n.split(".")[0] for n in found) == [
+        "flash_attn_dq_dkv", "flash_attn_fwd"], sorted(found)
     _assert_kernels(found, "block3", "attn", (
-        ("flash_attn_fwd", "jvp({})"), ("flash_attn_dq", "transpose(jvp({}))"),
-        ("flash_attn_dkv", "transpose(jvp({}))")))
+        ("flash_attn_fwd", "jvp({})"),
+        ("flash_attn_dq_dkv", "transpose(jvp({}))")))
+    # benchmarks/kernels/flash_attn.py finds a kernel's events by substring:
+    # each name has to hold one of its three, or its time leaves the roofline
+    from benchmarks.kernels.flash_attn import EVENTS
+
+    assert all(any(e in n for e in EVENTS) for n in found)
+
+
+# (B, H, T, dh): what the shape rule picks has to pass Mosaic (scoped VMEM
+# above all) at a realistic batch*heads — a refusal found without a chip call
+@pytest.mark.parametrize("shape,kernels", [
+    ((2, 12, 8192, 64), ["flash_attn_dq_dkv", "flash_attn_fwd"]),
+    ((4, 12, 4096, 64), ["flash_attn_dq_dkv", "flash_attn_fwd"]),
+    ((1, 12, 32768, 64), ["flash_attn_dkv", "flash_attn_dq",
+                          "flash_attn_fwd"]),
+])
+def test_long_sequences_compile_with_the_kernels_the_rule_picks(
+        one_chip, as_on_tpu, shape, kernels):
+    from ddlbench_tpu.ops.flash_attention import flash_attention
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v).astype(jnp.float32).sum()
+
+    found = _mosaic_calls(jax.grad(loss, argnums=(0, 1, 2)), one_chip,
+                          *[(shape, jnp.bfloat16)] * 3)
+    # outside the program's scopes the instruction is jvp_<name>_.N; the
+    # op_name ends [transpose(]jvp(<name>)[)]/pallas_call
+    assert sorted(re.search(r"(\w+)\)*/pallas_call$", op).group(1)
+                  for op in found.values()) == kernels
 
 
 def test_fused_xent_keeps_its_names_inside_the_scopes(one_chip, as_on_tpu):
