@@ -13,22 +13,36 @@ own, found by the name in the index:
                                             batch or lengths and clients
     benchmarks/harness/<kind>_driver.py     run(ctx): the window driver of
                                             the mixes of that kind
-    benchmarks/metrics/<metric>.json        layer, unit, moves, workloads,
-                                            reader (+ its arguments)
+    benchmarks/metrics/<metric>.json        what the metric is: layer, unit,
+                                            moves, reader (+ its arguments)
     benchmarks/metrics/readers/<reader>.py  read(ctx, **args) -> float | None
-    benchmarks/kernels/<kernel>.py          work from shapes, trace names
+    benchmarks/metrics/scope_kinds*.json    the scope kinds, in order; a new
+                                            kind comes in a file of its own
+    benchmarks/kernels/<kernel>.py          work from shapes, trace names;
+                                            the call shapes come from the
+                                            reference's kernel_calls(kernel,
+                                            config, traffic)
 
-``check`` holds the rules a driver refuses a manifest on, so that a refusal
-is found here and not on submission.
+WHERE a metric is reported stands in the index alone (the ``workloads`` list
+of its entry), so a cell joins a metric by gaining a name there: adding a
+configuration with its cell is new files and new entries, and not one byte
+changed in a file that was there.
+
+``check`` holds the rules a driver refuses a manifest on, and ``against``
+those a later PR that may only add is refused on, so that a refusal is found
+here and not on submission.
 """
 
 from __future__ import annotations
 
+import hashlib
 import importlib.util
 import json
 import os
 import re
 from typing import Dict, List, Optional
+
+from benchmarks.harness import scopes
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -296,13 +310,14 @@ def check(m: Manifest) -> List[str]:
             err.append(f"workload {w} reports no per-layer metric")
 
     # the index agrees with the files it points at
+    vocabulary = scopes.vocabulary(os.path.join(m.dir, "metrics"))
     for mt in pl:
         try:
             f = m.metric_file(mt["name"])
         except (OSError, ValueError) as e:
             err.append(f"per_layer {mt.get('name')}: no metric file ({e})")
             continue
-        for k in ("unit", "better", "source", "layer", "moves", "workloads"):
+        for k in ("unit", "better", "source", "layer", "moves"):
             if f.get(k) != mt.get(k):
                 err.append(f"per_layer {mt['name']}: {k} is {mt.get(k)!r} in "
                            f"BENCHMARK.json and {f.get(k)!r} in its file")
@@ -310,8 +325,69 @@ def check(m: Manifest) -> List[str]:
                                            f"{f.get('reader')}.py")):
             err.append(f"per_layer {mt['name']}: no reader "
                        f"{f.get('reader')!r}")
+        if "workloads" in f:
+            err.append(f"per_layer {mt['name']}: its file lists workloads; "
+                       f"the index alone says where a metric is reported")
+        for kind in f.get("args", {}).get("kinds", ()):
+            if kind not in vocabulary:
+                err.append(f"per_layer {mt['name']}: its file reads the kind "
+                           f"{kind!r}, which no scope_kinds*.json lists")
     if len(json.dumps(ix)) > 64 * 1024:
         err.append("BENCHMARK.json is over 64 KiB")
+    return err
+
+
+def tree_hashes(root: str, paths) -> Dict[str, str]:
+    """``{path relative to root: sha256}`` of every file under ``paths``
+    that git would keep (no ``__pycache__``, no ``.pyc``)."""
+    out = {}
+    for p in paths:
+        for d, dirs, names in os.walk(os.path.join(root, p)):
+            dirs[:] = [x for x in dirs if x != "__pycache__"]
+            for n in names:
+                if n.endswith(".pyc"):
+                    continue
+                full = os.path.join(d, n)
+                with open(full, "rb") as f:
+                    out[os.path.relpath(full, root)] = hashlib.sha256(
+                        f.read()).hexdigest()
+    return out
+
+
+def against(m: Manifest, parent_root: str) -> List[str]:
+    """What a PR that may only ADD to the benchmark is refused on, as
+    sentences: every file under the parent's ``paths`` that this tree
+    changed or deleted, and every entry of the parent's ``BENCHMARK.json``
+    that changed otherwise than by gaining names in ``workloads``."""
+    parent = _read(os.path.join(parent_root, "BENCHMARK.json"))
+    err = []
+    was = tree_hashes(parent_root, parent["paths"])
+    now = tree_hashes(m.root, parent["paths"])
+    for path in sorted(was):
+        if path not in now:
+            err.append(f"{path} is in the parent and was deleted")
+        elif now[path] != was[path]:
+            err.append(f"{path} is in the parent and differs")
+    for key in ("command", "paths", "run_seconds"):
+        if m.index.get(key) != parent[key]:
+            err.append(f"BENCHMARK.json {key} changed from {parent[key]!r} "
+                       f"to {m.index.get(key)!r}")
+    rest = lambda e: {k: v for k, v in e.items() if k != "workloads"}
+    for key in ("configs", "workloads", "end_to_end", "per_layer"):
+        mine = {e.get("name"): e for e in m.index.get(key, [])}
+        for old in parent[key]:
+            new = mine.get(old["name"])
+            if new is None:
+                err.append(f"BENCHMARK.json {key} entry {old['name']!r} of "
+                           f"the parent was removed")
+                continue
+            lost = set(old.get("workloads", [])) - set(new.get("workloads",
+                                                               []))
+            if rest(old) != rest(new) or lost \
+                    or ("workloads" in old) != ("workloads" in new):
+                err.append(f"BENCHMARK.json {key} entry {old['name']!r} "
+                           f"changed otherwise than by gaining a name in "
+                           f"workloads: {old} -> {new}")
     return err
 
 

@@ -38,24 +38,45 @@ one), but so does its Mosaic payload, which is no debug info to jax's key:
 a step with kernels gets an entry of its own when a scope around them
 changes (gpt2s-train's did), and its window runs under the table's names.
 
-The vocabulary is the benchmark's own copy (``tests/benchmark`` holds it to
-the program's), so these files also run against a program that has none.
+The vocabulary is the benchmark's own copy (``tests/`` holds it to the
+program's), so these files also run against a program that has none. It is
+DATA: the ``kinds`` lists of the ``scope_kinds*.json`` files under
+``benchmarks/metrics/``, in the order of the files' names. A later PR whose
+model opens a scope of a new kind adds a ``scope_kinds.<its name>.json`` of
+its own beside the metric file that reads the kind; nothing that exists is
+edited.
 """
 
 from __future__ import annotations
 
 import contextlib
 import dataclasses
+import glob
 import gzip
 import hashlib
+import json
 import os
 import re
 import sys
 import time
 from typing import Dict, List, Optional, Tuple
 
-KINDS = ("conv", "bn", "pool", "fc", "embed", "ln", "attn", "mlp", "head",
-         "loss")
+METRICS_DIR = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "metrics")
+
+
+def vocabulary(metrics_dir: str = METRICS_DIR) -> Tuple[str, ...]:
+    """The kinds that the ``scope_kinds*.json`` files of ``metrics_dir``
+    list, each once, in the order of the files' names."""
+    kinds: List[str] = []
+    for path in sorted(glob.glob(os.path.join(metrics_dir,
+                                              "scope_kinds*.json"))):
+        with open(path) as f:
+            kinds += [k for k in json.load(f)["kinds"] if k not in kinds]
+    return tuple(kinds)
+
+
+KINDS = vocabulary()
 STEP_PHASES = ("optimizer", "grad_sync")  # scopes that name a step phase
 FORWARD, BACKWARD, UNSCOPED = "forward", "backward", "unscoped"
 # wrappers that name a function and not a scope: jit(relu), pjit(..)
@@ -165,8 +186,9 @@ def step_hlo(rc) -> str:
 
     Rebuilds the strategy from the cell's two data files and lowers its
     step on shapes alone (``jax.eval_shape``: no weights are drawn, no
-    array is put on the device). Reached from the scope readers only, never
-    from an untraced run.
+    array is put on the device); across chips with the shardings of the
+    window's own arguments (``rc.step_shardings``). Reached from the scope
+    readers only, never from an untraced run.
 
     The text must come from a compile of THIS program's metadata, which
     jax's compile cache cannot promise (its key strips debug info, so the
@@ -192,20 +214,27 @@ def step_hlo(rc) -> str:
         cfg, strategy = train_driver.build(rc.config, rc.traffic)
         ds = rc.config["dataset"]
         state = jax.eval_shape(strategy.init, jax.random.key(0))
-        if rc.chips == 1:
-            # train_driver.seeded_state commits the weights it lays in to
-            # the chip, and a committed argument is annotated in the
-            # lowered module: the same here, so that this is the module the
-            # window ran. (Across chips the state's shardings are the
-            # strategy's own and are not rebuilt here.)
-            chip = jax.sharding.SingleDeviceSharding(rc.devices[0])
-            state = state._replace(params=jax.tree.map(
-                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                               sharding=chip), state.params))
         x, y = jax.eval_shape(lambda: SeededBatches(
             0, ds["kind"], tuple(ds["sample_shape"]),
             rc.config.get("vocab_size", ds["num_classes"]),
             cfg.global_batch()).batch(0, 0))
+        if rc.chips == 1:
+            # train_driver.seeded_state commits the weights it lays in to
+            # the chip, and a committed argument is annotated in the
+            # lowered module: the same here, so that this is the module the
+            # window ran.
+            chip = jax.sharding.SingleDeviceSharding(rc.devices[0])
+            state = state._replace(params=jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=chip), state.params))
+        else:
+            # across chips the step is partitioned by its arguments'
+            # shardings, which are the strategy's own: the window's driver
+            # keeps them (train_driver.run), nothing rebuilds them here
+            state, x, y = jax.tree.map(
+                lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                                   sharding=sh),
+                (state, x, y), rc.step_shardings)
         step = strategy.train_step
         if not hasattr(step, "lower"):  # dp's explicit engines wrap theirs
             step = strategy._jit_train_step

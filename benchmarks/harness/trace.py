@@ -100,8 +100,8 @@ class Summary:
     busy_s: float  # mean over devices of the union of device-op intervals
     n_devices: int
     op_seconds: Dict[str, float]  # by event name, mean over devices
-    collective_exposed_s: float  # mean over devices
-    collective_s: float
+    collective_exposed_s: float  # of the device that shows most of it
+    collective_s: float  # mean over devices
     idle_gaps: List[Tuple[str, float]]  # by host span, device 0, sorted
 
     def kernel_seconds(self, names) -> float:
@@ -140,7 +140,7 @@ def reduce(events: Dict) -> Summary:
             (coll if COLLECTIVE.search(op) else comp).append((s, e))
         busy += iv.total(spans)
         coll_total += iv.total(coll)
-        exposed += iv.total(iv.subtract(coll, comp))
+        exposed = max(exposed, iv.total(iv.subtract(coll, comp)))
         if di == 0:
             gaps0 = iv.gaps(spans, lo, hi)
     n = len(devices)
@@ -161,5 +161,5 @@ def reduce(events: Dict) -> Summary:
     return Summary(
         window_s=hi - lo, busy_s=busy / n, n_devices=n,
         op_seconds={k: v / n for k, v in op_seconds.items()},
-        collective_exposed_s=exposed / n, collective_s=coll_total / n,
+        collective_exposed_s=exposed, collective_s=coll_total / n,
         idle_gaps=sorted(by_host.items(), key=lambda x: -x[1]))

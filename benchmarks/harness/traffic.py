@@ -102,7 +102,21 @@ class SeededBatches:
             y = jax.random.randint(ky, (batch,), 0, num_classes, jnp.int32)
             return x, y
 
-        self._gen = jax.jit(gen)
+        self._fn, self._gen = gen, jax.jit(gen)
+
+    def lay_out_like(self, shard_fn) -> None:
+        """Make every batch where ``shard_fn`` (a strategy's ``shard_batch``)
+        would put it: each chip draws its own rows, ``shard_fn`` then moves
+        nothing, and no chip makes, holds and sends out the global batch
+        for the others. The values do not change (jax's threefry is
+        partitionable: the bits of a position do not depend on the layout)."""
+        import jax
+        import jax.numpy as jnp
+
+        shapes = jax.eval_shape(self._fn, np.int32(0))
+        placed = shard_fn(*(jnp.zeros(s.shape, s.dtype) for s in shapes))
+        self._gen = jax.jit(self._fn, out_shardings=tuple(
+            a.sharding for a in placed))
 
     def steps_per_epoch(self, train: bool = True) -> int:
         return 1 << 30

@@ -192,6 +192,8 @@ def run(rc) -> Dict:
     data = SeededBatches(rc.seed, ds["kind"], tuple(ds["sample_shape"]),
                          config.get("vocab_size", ds["num_classes"]),
                          global_batch)
+    if rc.chips > 1:
+        data.lay_out_like(strategy.shard_batch)
     ts, specs, names = seeded_state(strategy, rc.seed, config["weights"])
     rc.mark("weights from the seed")
     lr = jnp.float32(hp["lr"])
@@ -234,6 +236,10 @@ def run(rc) -> Dict:
     stream.close()
     last_loss = float(m["loss"])
     memory_peak = rc.read_memory_peak()
+    # how the window's own arguments lay over the chips: across chips
+    # scopes.step_hlo partitions the step it lowers by these
+    rc.step_shardings = jax.tree.map(lambda a: a.sharding,
+                                     (ts, *fetched.batch))
 
     # -- free the program, then the reference ----------------------------
     del ts, m, pending, fetched, stream, strategy, step_fn

@@ -18,9 +18,12 @@ def work(B: int, H: int, T: int, dh: int, elem_bytes: int = 2):
 
 
 def calls(ctx):
-    """(flops, bytes) of the traced window: one fwd+bwd per layer per step."""
-    c, t = ctx.config, ctx.traffic
-    B = t["run_config"]["batch_size"]
-    f, b = work(B, c["n_head"], c["n_positions"], c["n_embd"] // c["n_head"])
-    n = ctx.counters["steps"] * c["n_layer"]
-    return f * n, b * n
+    """(flops, bytes) of the traced window. The call shapes are the
+    configuration's: its reference module gives ``[(calls a step, keyword
+    arguments of work)]`` for this cell's traffic."""
+    f = b = 0.0
+    for per_step, shape in ctx.reference.kernel_calls(
+            "flash_attn", ctx.config, ctx.traffic):
+        df, db = work(**shape)
+        f, b = f + per_step * df, b + per_step * db
+    return f * ctx.counters["steps"], b * ctx.counters["steps"]

@@ -16,7 +16,11 @@ def work(N: int, D: int, V: int, elem_bytes: int = 2):
 
 
 def calls(ctx):
-    c, t = ctx.config, ctx.traffic
-    N = t["run_config"]["batch_size"] * c["n_positions"]
-    f, b = work(N, c["n_embd"], c["padded_vocab_size"])
+    """(flops, bytes) of the traced window, at the call shapes the
+    configuration's reference module gives for this cell's traffic."""
+    f = b = 0.0
+    for per_step, shape in ctx.reference.kernel_calls(
+            "fused_xent", ctx.config, ctx.traffic):
+        df, db = work(**shape)
+        f, b = f + per_step * df, b + per_step * db
     return f * ctx.counters["steps"], b * ctx.counters["steps"]

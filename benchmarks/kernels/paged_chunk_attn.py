@@ -19,13 +19,14 @@ def work(start: int, n_real: int, H: int, dh: int, page: int,
 
 
 def calls(ctx):
-    c = ctx.config
-    sc = ctx.traffic["serve_config"]
-    kvb = {"float32": 4, "bfloat16": 2, "int8": 1}[sc.get("kv_dtype",
-                                                          "float32")]
+    """(flops, bytes) of the traced window: the chunks the driver counted
+    (``prefill_calls``: start, real tokens), each at the static shapes the
+    configuration's reference module gives, ``[(calls a chunk, keyword
+    arguments of work)]``."""
     f = b = 0.0
-    for start, n_real in ctx.counters["prefill_calls"]:
-        df, db = work(start, n_real, c["n_head"], c["n_embd"] // c["n_head"],
-                      sc["page"], kvb)
-        f, b = f + df, b + db
-    return f * c["n_layer"], b * c["n_layer"]
+    for per_chunk, shape in ctx.reference.kernel_calls(
+            "paged_chunk_attn", ctx.config, ctx.traffic):
+        for start, n_real in ctx.counters["prefill_calls"]:
+            df, db = work(start, n_real, **shape)
+            f, b = f + per_chunk * df, b + per_chunk * db
+    return f, b

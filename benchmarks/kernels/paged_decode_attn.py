@@ -18,13 +18,14 @@ def work(live_positions, H: int, dh: int, page: int, kv_bytes: int = 4):
 
 
 def calls(ctx):
-    c = ctx.config
-    sc = ctx.traffic["serve_config"]
-    kvb = {"float32": 4, "bfloat16": 2, "int8": 1}[sc.get("kv_dtype",
-                                                          "float32")]
+    """(flops, bytes) of the traced window: the decode passes the driver
+    counted (``decode_calls``: the live rows' depths), each at the static
+    shapes the configuration's reference module gives, ``[(calls a pass,
+    keyword arguments of work)]``."""
     f = b = 0.0
-    for depths in ctx.counters["decode_calls"]:
-        df, db = work([d + 1 for d in depths], c["n_head"],
-                      c["n_embd"] // c["n_head"], sc["page"], kvb)
-        f, b = f + df, b + db
-    return f * c["n_layer"], b * c["n_layer"]
+    for per_pass, shape in ctx.reference.kernel_calls(
+            "paged_decode_attn", ctx.config, ctx.traffic):
+        for depths in ctx.counters["decode_calls"]:
+            df, db = work([d + 1 for d in depths], **shape)
+            f, b = f + per_pass * df, b + per_pass * db
+    return f, b

@@ -112,6 +112,30 @@ def loss_and_grads(P, tokens, labels, config, rnd=exact):
     return loss, grads, {}  # no normalization statistics are kept
 
 
+KV_BYTES = {"float32": 4, "bfloat16": 2, "int8": 1}
+
+
+def kernel_calls(kernel: str, config, traffic):
+    """The shapes at which this configuration calls a kernel under a mix:
+    ``[(calls, keyword arguments of benchmarks/kernels/<kernel>.work)]``.
+    ``calls`` is per train step for the training kernels (one flash
+    forward + backward a block, one fused head + loss a step) and per
+    prefill chunk or decode pass for the paged ones (one a block), whose
+    ``work`` takes the rest of its arguments from the driver's counters."""
+    d, H, L = config["n_embd"], config["n_head"], config["n_layer"]
+    if kernel == "flash_attn":
+        return [(L, dict(B=traffic["run_config"]["batch_size"], H=H,
+                         T=config["n_positions"], dh=d // H))]
+    if kernel == "fused_xent":
+        N = traffic["run_config"]["batch_size"] * config["n_positions"]
+        return [(1, dict(N=N, D=d, V=config["padded_vocab_size"]))]
+    if kernel in ("paged_decode_attn", "paged_chunk_attn"):
+        sc = traffic["serve_config"]
+        return [(L, dict(H=H, dh=d // H, page=sc["page"],
+                         kv_bytes=KV_BYTES[sc.get("kv_dtype", "float32")]))]
+    raise KeyError(f"the gpt2 reference has no call shapes of {kernel!r}")
+
+
 def matmul_params(config, with_head: bool = True) -> float:
     """Parameters that sit in a matmul: per block wqkv 3d^2, wo d^2, MLP
     2 * ratio * d^2; plus the output head d x padded vocab."""

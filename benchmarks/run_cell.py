@@ -14,7 +14,11 @@ gives the cell's end-to-end metrics. ``--trace 1`` is a run of its own under
 by ``harness/trace.py`` into the cell's per-layer metrics and the breakdown.
 
 ``--check-manifest`` checks BENCHMARK.json and the files it indexes against
-the rules a driver refuses on, and touches no device.
+the rules a driver refuses on, and touches no device. With ``--against <a
+checkout of the parent commit>`` it also lists what a PR that may only ADD to
+the benchmark is refused on: every file under ``paths`` that the parent has
+and this tree changed, and every entry of the parent's BENCHMARK.json that
+changed otherwise than by gaining a name in ``workloads``.
 """
 
 from __future__ import annotations
@@ -164,11 +168,14 @@ def main(argv=None) -> int:
     ap.add_argument("--seconds", type=float, default=None)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     ap.add_argument("--check-manifest", action="store_true")
+    ap.add_argument("--against", metavar="PARENT_CHECKOUT", default=None)
     args = ap.parse_args(argv)
 
     man = manifest.Manifest()
     if args.check_manifest:
         errors = manifest.check(man)
+        if args.against:
+            errors += manifest.against(man, args.against)
         for e in errors:
             print(f"manifest: {e}", file=sys.stderr)
         print(f"manifest: {len(errors)} fault(s) in BENCHMARK.json and the "

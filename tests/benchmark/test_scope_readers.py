@@ -8,6 +8,7 @@ import gzip
 import json
 import os
 import re
+import shutil
 import types
 
 import jax
@@ -180,10 +181,13 @@ def test_a_program_without_scopes_reads_nothing(capsys):
 
 
 def test_manifest_holds_the_nine_scope_metrics():
+    """The rule, not the roster: the cells each metric was accepted on are
+    AMONG its roster (a later cell joins by gaining a name there), and a
+    kind a model lacks is not printed for it."""
     man = manifest.Manifest()
     assert manifest.check(man) == []
     both = {"resnet50-single", "gpt2s-train"}
-    want = {
+    accepted = {
         "step_forward_ms.train": both, "step_backward_ms.train": both,
         "step_optimizer_ms.train": both, "norm_ms.train": both,
         "head_loss_ms.train": both, "unscoped_device_share.train": both,
@@ -191,14 +195,60 @@ def test_manifest_holds_the_nine_scope_metrics():
         "attn_ms.train": {"gpt2s-train"}, "mlp_ms.train": {"gpt2s-train"}}
     got = {m["name"]: set(m["workloads"]) for m in man.index["per_layer"]
            if m["source"] == "program_span"}
-    assert got == want
-    for name in want:
+    assert set(accepted) <= set(got)
+    for name, cells in accepted.items():
+        assert cells <= got[name], name
         spec = man.metric_file(name)
         assert spec["moves"] == "train_samples_per_s_per_chip"
         assert spec["better"] == "lower"
         assert callable(man.reader(spec).read)
+    for name in got:  # every scope metric reads the one reader
+        assert man.metric_file(name)["reader"] in ("scope_ms",
+                                                   "unscoped_share"), name
     printed = {m["name"] for m in man.per_layer_of("gpt2s-train")}
     assert "conv_ms.train" not in printed and "attn_ms.train" in printed
+
+
+# ---- the vocabulary is data -------------------------------------------------
+
+
+def test_the_vocabulary_is_what_the_scope_kinds_files_list(tmp_path):
+    """The kinds are the ``kinds`` lists of the ``scope_kinds*.json`` files
+    beside the metric files, in the order of the files' names; the committed
+    file lists the ten kinds the accepted cells were read with, so what
+    counts as scoped (``unscoped_device_share.train``) is what it was, and
+    every kind a metric file reads is one of them."""
+    ten = ("conv", "bn", "pool", "fc", "embed", "ln", "attn", "mlp", "head",
+           "loss")
+    assert scopes.KINDS == ten == scopes.vocabulary()
+    man = manifest.Manifest()
+    read = {k for m in man.index["per_layer"]
+            for k in man.metric_file(m["name"]).get("args", {}).get(
+                "kinds", ())}
+    assert read == set(ten) - {"pool", "embed"}  # read by no metric yet
+    # a later PR's file comes after, adds what is new, repeats nothing
+    shutil.copy(os.path.join(scopes.METRICS_DIR, "scope_kinds.json"),
+                tmp_path / "scope_kinds.json")
+    (tmp_path / "scope_kinds.moe.json").write_text(json.dumps(
+        {"kinds": ["router", "mlp", "expert"]}))
+    (tmp_path / "router_ms.train.json").write_text(json.dumps(
+        {"name": "router_ms.train", "reader": "scope_ms",
+         "args": {"kinds": ["gate"]}}))  # a metric file makes no kind
+    assert scopes.vocabulary(str(tmp_path)) == ten + ("router", "expert")
+    op = "jit(train_step)/jvp(block3)/mlp/router/dot_general"
+    assert scopes.classify(op) == ("forward", "mlp")
+    times = scopes.reduce_scopes({"fusion.1": 0.5}, {"fusion.1": op})
+    assert times.by_kind == {"mlp": [0.5, 1]}
+
+
+def test_the_benchmark_s_vocabulary_is_the_program_s():
+    """The benchmark's copy against the program's own tuple, order and all
+    (``tests/test_scopes.py`` holds the same from the program's side)."""
+    from ddlbench_tpu.telemetry import scopes as program
+
+    assert scopes.KINDS == program.KINDS
+    assert isinstance(scopes.KINDS, tuple)
+    assert scopes.STEP_PHASES == program.PHASES
 
 
 # ---- jax's compile cache: the two halves of the stale-executable hazard ----
@@ -397,3 +447,46 @@ def test_keep_scopes_cuts_whole_steps(recorded):
     assert one["host"][0][2] == pytest.approx(max(e[2] for e in kept))
     assert trace.reduce(one).busy_s == pytest.approx(
         trace.reduce(events).busy_s / 2, rel=0.02)
+
+
+# ---- across chips ------------------------------------------------------------
+
+
+def test_step_hlo_across_chips_takes_the_window_s_own_shardings(tmp_path):
+    """A two-chip data-parallel cell: the driver keeps how the window's own
+    arguments lay over the chips, ``step_hlo`` partitions the step it lowers
+    by them, and the partitioner's all-reduces carry the path of the ops
+    they were made from (GSPMD opens no ``grad_sync`` scope)."""
+    from test_rehearsal import context
+
+    from benchmarks.harness import train_driver
+
+    rc = context("train-tiny-dp2", chips=2)
+    with program_cache(tmp_path):
+        train_driver.run(rc)
+        state, x, y = rc.step_shardings
+        assert len(x.device_set) == 2 and not x.is_fully_replicated
+        assert all(s.is_fully_replicated for s in jax.tree.leaves(state))
+        text = scopes.step_hlo(rc)
+    table = scopes.scope_table(text)
+    reduces = [op for name, op in table.items()
+               if name.startswith("all-reduce")]
+    assert reduces, "the partitioned step holds no all-reduce"
+    phases = {scopes.classify(op)[0] for op in reduces}
+    assert "backward" in phases and "grad_sync" not in phases
+    kinds = {scopes.classify(op)[1] for op in table.values()}
+    assert {"embed", "ln", "attn", "mlp", "loss"} <= kinds
+    # a trace of that step: nothing reads a grad_sync phase, the rest reads
+    ops = {name: 0.001 for name in table}
+    ctx = types.SimpleNamespace(
+        _step_hlo=text, counters={"steps": 1},
+        trace_summary=types.SimpleNamespace(op_seconds=ops))
+    assert reader("scope_ms").read(ctx, phase="grad_sync") is None
+    assert reader("scope_ms").read(ctx, phase="backward") > 0
+    # an explicit engine names its collectives, and the same reader reads
+    # them (the metric comes with the cell that runs such an engine)
+    ctx = fake_context(table=dict(TABLE, **{
+        "all-reduce.3": "jit(step)/shard_map/grad_sync/bucket0/psum"}),
+        op_seconds=dict(OP_SECONDS, **{"all-reduce.3": 0.006}))
+    assert reader("scope_ms").read(ctx, phase="grad_sync") == pytest.approx(
+        3.0)

@@ -87,8 +87,8 @@ def test_reduce_synthetic():
     assert s.window_s == 10 and s.n_devices == 2
     # device 0 busy [0,6) + [7,9) = 8; device 1 busy 10
     assert s.busy_s == pytest.approx(9.0)
-    # exposed: [4,6) on device 0, none on device 1 -> mean 1.0
-    assert s.collective_exposed_s == pytest.approx(1.0)
+    # exposed: [4,6) on device 0, none on device 1 -> device 0's counts
+    assert s.collective_exposed_s == pytest.approx(2.0)
     assert s.collective_s == pytest.approx(1.5)
     assert s.kernel_seconds(("flash_attn_fwd",)) == pytest.approx(1.0)
     assert s.op_seconds["fusion.1"] == pytest.approx((4 + 10) / 2)
@@ -105,7 +105,22 @@ def test_async_collectives_are_collective_time_not_busy_time():
         [trace.ASYNC_PREFIX + "all-reduce-start.2", 6, 7])
     s = trace.reduce(ev)
     assert s.busy_s == pytest.approx(9.0)
-    assert s.collective_exposed_s == pytest.approx((2.0 + 1.0) / 2)
+    assert s.collective_exposed_s == pytest.approx(2.0 + 1.0)
+
+
+def test_collective_exposed_share_is_of_the_chip_that_shows_most():
+    from types import SimpleNamespace
+
+    from benchmarks.harness import manifest
+
+    man = manifest.Manifest()
+    read = man.reader(man.metric_file("collective_exposed_share.train")).read
+    # device 0 waits [4,6) of a 10 s window on its all-reduce; device 1 none
+    assert read(SimpleNamespace(trace_summary=trace.reduce(synthetic()))) \
+        == pytest.approx(20.0)
+    one_chip = synthetic()
+    one_chip["devices"]["/device:TPU:0"] = [["fusion.1", 0, 4]]
+    assert read(SimpleNamespace(trace_summary=trace.reduce(one_chip))) is None
 
 
 def test_reduce_needs_a_window_and_a_device():

@@ -49,3 +49,29 @@ def test_every_seed_gets_the_same_sizes_in_the_same_order():
     n = MIX["pool_size"] + 30  # into the second cycle
     assert sizes(a, n) == sizes(b, n)
     assert sizes(RequestSource(MIX, 50257, 3), 96) == length_pool(MIX)[:96]
+
+
+@pytest.mark.parametrize("kind,shape,classes", [("image", (8, 8, 3), 10),
+                                                ("tokens", (16,), 50)])
+def test_batches_laid_out_over_the_chips_are_the_same_batches(kind, shape,
+                                                              classes):
+    """A cell on several chips draws each chip's rows on that chip
+    (``lay_out_like``): where a batch lies changes, what it holds does not,
+    so the reference, which draws the global batch whole, sees the same
+    rows."""
+    import jax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from benchmarks.harness.traffic import SeededBatches
+
+    rows = NamedSharding(Mesh(jax.devices()[:4], ("data",)), P("data"))
+    whole = SeededBatches(2600000001, kind, shape, classes, 8)
+    spread = SeededBatches(2600000001, kind, shape, classes, 8)
+    spread.lay_out_like(lambda x, y: (jax.device_put(x, rows),
+                                      jax.device_put(y, rows)))
+    for step in (0, 3):
+        (xa, ya), (xb, yb) = whole.batch(0, step), spread.batch(0, step)
+        assert xb.sharding == rows and yb.sharding == rows
+        assert len(xa.sharding.device_set) == 1
+        assert np.array_equal(np.asarray(xa), np.asarray(xb))
+        assert np.array_equal(np.asarray(ya), np.asarray(yb))

@@ -95,7 +95,7 @@ def test_paged_chunk_work_counts_visible_keys():
 
 def test_kernel_calls_over_a_window():
     class Ctx:
-        config = GPT2
+        config, reference = GPT2, gpt2
         traffic = {"run_config": {"batch_size": 16},
                    "serve_config": {"page": 16, "kv_dtype": "float32"}}
         counters = {"steps": 3, "decode_calls": [[0, 15], [16]],
@@ -110,6 +110,39 @@ def test_kernel_calls_over_a_window():
     assert bd == 12 * 2 * (16 + 16 + 32) * 12 * 64 * 4
     fc, _ = paged_chunk_attn.calls(Ctx)
     assert fc == 12 * 4 * 12 * 64 * (sum(range(1, 65)) + sum(range(65, 75)))
+
+
+def test_call_shapes_come_from_the_configuration_s_reference():
+    """The kernel files hold the yardstick (work from shapes) and no
+    configuration's key names; gpt2's shapes at the cells' traffic are the
+    parent's: flash B 16, H 12, T 1024, dh 64, 12 a step; xent N 16384,
+    d 768, V 50304, once a step."""
+    train = MAN.traffic("train-b16")
+    assert gpt2.kernel_calls("flash_attn", GPT2, train) == [
+        (12, dict(B=16, H=12, T=1024, dh=64))]
+    assert gpt2.kernel_calls("fused_xent", GPT2, train) == [
+        (1, dict(N=16384, D=768, V=50304))]
+    serve = MAN.traffic("serve-closed96")
+    paged = [(12, dict(H=12, dh=64, page=16, kv_bytes=4))]
+    assert gpt2.kernel_calls("paged_decode_attn", GPT2, serve) == paged
+    assert gpt2.kernel_calls("paged_chunk_attn", GPT2, serve) == paged
+    with pytest.raises(KeyError):
+        gpt2.kernel_calls("conv_winograd", GPT2, train)
+    # a second configuration at its own shapes, through the same file
+    wide = dict(GPT2, n_head=6, n_layer=2, padded_vocab_size=6400)
+
+    class Ctx:
+        config, reference, traffic = wide, gpt2, train
+        counters = {"steps": 1}
+
+    assert flash_attn.calls(Ctx) == tuple(
+        2 * v for v in flash_attn.work(16, 6, 1024, 128))
+    assert fused_xent.calls(Ctx) == fused_xent.work(16384, 768, 6400)
+    import inspect
+    import re
+    for mod in (flash_attn, fused_xent, paged_chunk_attn, paged_decode_attn):
+        text = inspect.getsource(mod)
+        assert not re.search(r"config\[|\bc\[|traffic\[", text), mod.__name__
 
 
 def test_peaks_table_raises_on_an_unknown_device():
