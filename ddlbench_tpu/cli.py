@@ -23,7 +23,7 @@ from ddlbench_tpu.config import (
     RunConfig,
     STRATEGIES,
 )
-from ddlbench_tpu.models.zoo import MODEL_NAMES
+from ddlbench_tpu.models.zoo import ARCH_HELP, arch_name
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -33,7 +33,8 @@ def build_parser() -> argparse.ArgumentParser:
                    help="parallelization strategy (reference: pytorch|horovod|gpipe|pipedream)")
     p.add_argument("-g", "--devices", type=int, default=1,
                    help="total number of chips (reference: gpus x nodes)")
-    p.add_argument("-m", "--model", default="resnet18", choices=MODEL_NAMES)
+    p.add_argument("-m", "--model", "--arch", default="resnet18",
+                   type=arch_name, metavar="ARCH", help=ARCH_HELP)
     p.add_argument("-p", "--log-interval", type=int, default=25)
     p.add_argument("-s", "--real-data", action="store_true",
                    help="use on-disk data via the native loader (reference -s flag, inverted)")

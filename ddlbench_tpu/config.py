@@ -392,10 +392,10 @@ class RunConfig:
     # capping live activations at one layer's working set. Off by default
     # (XLA's fusion usually wins); required for XLA-attention long-context
     # training on one chip, where each layer otherwise keeps a [B, H, T, T]
-    # score matrix alive into the backward. Incompatible with MoE archs: the
-    # router aux losses are collected through a trace-time side channel
-    # (models/moe.py collect_aux_losses) that cannot escape a checkpointed
-    # trace.
+    # score matrix alive into the backward. Incompatible with the Switch
+    # MoE archs (collects_aux_loss): their router aux losses are collected
+    # through a trace-time side channel (models/moe.py collect_aux_losses)
+    # that cannot escape a checkpointed trace.
     remat_layers: bool = False
     seed: int = 1  # reference seeds torch.manual_seed(1) (imagenet_pytorch.py:58-66)
 
@@ -509,6 +509,25 @@ class RunConfig:
 
     def dataset(self) -> DatasetSpec:
         return DATASETS[self.benchmark]
+
+    def collects_aux_loss(self) -> bool:
+        """The arch's router collects an auxiliary loss over the routed
+        batch (models/zoo.collects_aux_loss)."""
+        from ddlbench_tpu.models.zoo import collects_aux_loss
+
+        return collects_aux_loss(self.arch)
+
+    def model_strategies(self):
+        """The strategies the arch's model is brought up on
+        (LayerModel.strategies), None for no such list — and for an arch
+        the benchmark's dataset cannot build: make_strategy raises that
+        where the model is made, as it always did."""
+        from ddlbench_tpu.models.zoo import get_model
+
+        try:
+            return get_model(self.arch, self.dataset()).strategies
+        except (KeyError, ValueError):  # unknown arch, or not this dataset's
+            return None
 
     def resolved_optimizer(self) -> str:
         if self.optimizer is not None:
@@ -787,13 +806,23 @@ class RunConfig:
         if self.strategy == "ep":
             if self.dataset().kind != "tokens":
                 raise ValueError("ep (expert parallelism) requires a token benchmark")
-            if "moe" not in self.arch:
-                raise ValueError("ep (expert parallelism) requires an MoE arch")
-        if self.remat_layers and "moe" in self.arch:
+            if not self.collects_aux_loss():
+                raise ValueError(
+                    "ep (expert parallelism) requires a Switch-routed MoE "
+                    "arch (transformer_moe_*): parallel/ep.py shards that "
+                    "layer's expert axis")
+        brought_up = self.model_strategies()
+        if brought_up is not None and self.strategy not in brought_up:
             raise ValueError(
-                "remat_layers is incompatible with MoE archs (router aux "
-                "losses cannot escape a checkpointed trace); use "
-                "remat_stages via a pipeline strategy instead")
+                f"{self.arch} is brought up on {', '.join(brought_up)} only "
+                f"(its model says which strategies: LayerModel.strategies); "
+                f"got {self.strategy!r}")
+        if self.remat_layers and self.collects_aux_loss():
+            raise ValueError(
+                "remat_layers is incompatible with archs whose router "
+                "collects an auxiliary loss (transformer_moe_*: it cannot "
+                "escape a checkpointed trace); use remat_stages via a "
+                "pipeline strategy instead")
         if self.remat_layers and self.strategy not in ("single", "dp", "tp",
                                                        "fsdp"):
             raise ValueError(
@@ -1057,7 +1086,7 @@ class RunConfig:
                 "allreduce_dtype applies to the dp strategy's gradient "
                 "collectives")
         if self.dp_explicit_collectives():
-            if "moe" in self.arch:
+            if self.collects_aux_loss():
                 raise ValueError(
                     "dp_shard_update / compressed allreduce run the train "
                     "step under shard_map, where MoE router statistics "

@@ -196,6 +196,11 @@ class Layer:
     # shared free-list page pool. Pointwise layers participate through
     # ``apply``; everything else needs a ServeOps to be servable.
     serve: Any = None
+    # Top-level keys of this layer's params that it computes with in float32
+    # and that the step's cast to the compute dtype therefore leaves as they
+    # are (parallel/common.cast_params, given the layers), e.g. a router's
+    # weights.
+    f32_params: Tuple[str, ...] = ()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -262,6 +267,9 @@ class LayerModel:
     # seq2seq models only: the prefix-LM source-segment length baked into the
     # attention masks (decode entry points validate against it).
     src_len: int | None = None
+    # the strategies the model is brought up on (RunConfig.validate refuses
+    # any other); None: every strategy its input kind allows.
+    strategies: Tuple[str, ...] | None = None
 
 
 def init_model(model: LayerModel, key: jax.Array):
@@ -659,9 +667,24 @@ def inverted_residual(name: str, out_ch: int, stride: int, expand: int) -> Layer
     return Layer(name, init, apply)
 
 
+def routing_counters(model_state):
+    """The step's routing counters out of a model state, {} for a model
+    without expert layers (whose state holds them under ``"moe"``,
+    models/kanana2.expert_block): held slots summed over the layers, the
+    load ratio of the most uneven layer."""
+    found = [s["moe"] for s in model_state
+             if isinstance(s, dict) and "moe" in s]
+    if not found:
+        return {}
+    return {"moe_held_slots": sum(c["held_slots"] for c in found),
+            "moe_load_max_over_mean": jnp.max(jnp.stack(
+                [c["load_max_over_mean"] for c in found]))}
+
+
 def param_count(params) -> int:
-    return sum(int(jnp.size(l)) for l in jax.tree.leaves(params))
+    return sum(math.prod(l.shape) for l in jax.tree.leaves(params))
 
 
 def param_bytes(params) -> int:
-    return sum(int(jnp.size(l)) * l.dtype.itemsize for l in jax.tree.leaves(params))
+    return sum(math.prod(l.shape) * l.dtype.itemsize
+               for l in jax.tree.leaves(params))

@@ -209,6 +209,9 @@ FLASH_AUTO_MIN_SEQ = 640  # base threshold; see flash_pays_off for the table
 
 def flash_pays_off(seq_len: int, batch: int, prefix_len: int) -> bool:
     """Shape-aware flash-vs-XLA decision table (the "auto" backend policy).
+    Head widths are no input: every crossing below was measured at dh = 64;
+    wider heads (q/k 192, v 128 at T = 4096 ran on the chip, PERF.md PR 27)
+    sit far past it.
 
     The table below encodes the reproducible signals of
     perf_runs/attn_crossover.json (one 2026-07-31 sweep, before PR 1) and
@@ -282,7 +285,9 @@ def causal_attention(q, k, v, q_offset: int = 0, k_offset: int = 0,
                      prefix_len: int = 0):
     """Masked attention for blocks of a causal (or prefix-LM) sequence.
 
-    q: [B, H, Tq, Dh]; k/v: [B, H, Tk, Dh]. Offsets give each block's absolute
+    q: [B, H, Tq, Dh]; k: [B, H, Tk, Dh]; v: [B, H, Tk, Dv] (Dv = Dh but
+    for latent attention, models/kanana2.py: 192 and 128); the scale is
+    1/sqrt(Dh) and the output is Dv wide on both paths. Offsets give each block's absolute
     position so the same primitive serves full attention (offsets 0) and ring
     attention over sequence shards (parallel/sp.py). ``prefix_len`` > 0 adds
     the prefix-LM rule: key positions < prefix_len are visible to every query
@@ -367,7 +372,8 @@ def ring_attention(q, k, v, axis: str, prefix_len: int = 0):
 
     m0 = vary(jnp.full((B, H, Tl, 1), -jnp.inf, jnp.float32), (axis,))
     l0 = vary(jnp.zeros((B, H, Tl, 1), jnp.float32), (axis,))
-    acc0 = vary(jnp.zeros((B, H, Tl, dh), jnp.float32), (axis,))
+    # the output is as wide as v, which need not be q's width
+    acc0 = vary(jnp.zeros((B, H, Tl, v.shape[-1]), jnp.float32), (axis,))
     (k, v, m, l, acc), _ = lax.scan(step, (k, v, m0, l0, acc0), jnp.arange(n))
     return (acc / jnp.maximum(l, 1e-20)).astype(q.dtype)
 
@@ -390,7 +396,8 @@ def _ring_attention_flash(q, k, v, axis: str, interpret: bool):
 
     n = lax.psum(1, axis)
     idx = lax.axis_index(axis)
-    B, H, Tl, dh = q.shape
+    B, H, Tl, _ = q.shape
+    dv = v.shape[-1]  # the output is as wide as v, not as q
 
     def full_blk(q, kb, vb):
         return flash_attention_lse(q, kb, vb, Tl, 0, 0, interpret=interpret)
@@ -399,7 +406,7 @@ def _ring_attention_flash(q, k, v, axis: str, interpret: bool):
         return flash_attention_lse(q, kb, vb, 0, 0, 0, interpret=interpret)
 
     def skip_blk(q, kb, vb):
-        return (vary(jnp.zeros_like(q), (axis,)),
+        return (vary(jnp.zeros((B, H, Tl, dv), q.dtype), (axis,)),
                 vary(jnp.full((B, H, Tl), NEG_INF, jnp.float32), (axis,)))
 
     def step(carry, i):
@@ -417,7 +424,7 @@ def _ring_attention_flash(q, k, v, axis: str, interpret: bool):
         v_blk = lax.ppermute(v_blk, axis, perm)
         return (k_blk, v_blk, o, new_lse), None
 
-    o0 = vary(jnp.zeros((B, H, Tl, dh), jnp.float32), (axis,))
+    o0 = vary(jnp.zeros((B, H, Tl, dv), jnp.float32), (axis,))
     lse0 = vary(jnp.full((B, H, Tl), NEG_INF, jnp.float32), (axis,))
     (k, v, o, lse), _ = lax.scan(step, (k, v, o0, lse0), jnp.arange(n))
     return o.astype(q.dtype)

@@ -18,7 +18,35 @@ MODEL_NAMES = ("resnet18", "resnet50", "resnet152", "vgg11", "vgg16",
                "densenet121", "inception", "nasnet", "transformer_t",
                "transformer_s",
                "transformer_m", "transformer_moe_s", "seq2seq_s", "seq2seq_m",
-               "seq2seq_lstm_s")
+               "seq2seq_lstm_s", "kanana2_30b_a3b")
+
+ARCH_HELP = ("one of MODEL_NAMES; kanana2_30b_a3b may carry the share one "
+             "chip holds: -l<layers kept>, -e<experts held>[r<rank>], e.g. "
+             "kanana2_30b_a3b-l5-e8 (models/kanana2.py)")
+
+
+def arch_name(arch: str) -> str:
+    """``arch`` if this registry can build it (the argparse ``type`` of
+    every ``--model``/``--arch`` option), else a ValueError naming what it
+    can."""
+    from ddlbench_tpu.models import kanana2
+
+    if arch in MODEL_NAMES or kanana2.is_family(arch):
+        kanana2.parse_arch(arch)  # a share the family cannot cut raises
+        return arch
+    raise ValueError(f"unknown arch {arch!r}; known: {MODEL_NAMES}")
+
+
+def collects_aux_loss(arch: str) -> bool:
+    """True for the Switch-routed archs (models/moe.py): their router's
+    capacity and load-balance loss are statistics of the whole routed batch,
+    collected through a trace-time sink — what a checkpointed layer cannot
+    let out and a shard_map over the batch would make per-shard. (The
+    dropless sigmoid router of models/kanana2.py routes token by token and
+    collects nothing.)"""
+    from ddlbench_tpu.models.moe import _VARIANTS
+
+    return arch in _VARIANTS
 
 
 def get_model(arch: str, dataset: str | DatasetSpec,
@@ -37,6 +65,12 @@ def get_model(arch: str, dataset: str | DatasetSpec,
 
         return build_seq2seq(arch, spec.image_size, spec.num_classes,
                              spec.src_len)
+    from ddlbench_tpu.models import kanana2
+
+    if kanana2.is_family(arch):
+        if spec.kind != "tokens":
+            raise ValueError(f"{arch} requires a token dataset, got {spec.name}")
+        return kanana2.build(arch, spec.image_size, spec.num_classes)
     if arch.startswith("transformer"):
         if spec.kind != "tokens":
             raise ValueError(f"{arch} requires a token dataset, got {spec.name}")

@@ -77,16 +77,21 @@ RESIDENT_DQ_MAX_BYTES = 2 * 1024 * 1024
 
 
 def _use_streaming(t_inner: int, dh: int, itemsize: int, bq: int, bk: int,
-                   stream, dq_rows: int = 0, interpret: bool = False) -> bool:
-    """``dq_rows``: rows of the f32 dQ block the kernel keeps resident (the
-    one-pass backward: Tq; 0 for the forward)."""
+                   stream, dq_rows: int = 0, interpret: bool = False,
+                   dv: int | None = None) -> bool:
+    """``dh`` is the q/k width and ``dv`` the v/o width (``dh`` when None).
+    The inner side holds one operand of each width: K and V in the forward,
+    Q and dO in the one-pass backward. ``dq_rows``: rows of the f32 dQ block
+    the kernel keeps resident (the one-pass backward: Tq; 0 for the
+    forward)."""
+    dv = dh if dv is None else dv
     if stream is not None:
         return bool(stream)
     if not interpret and bq % 128:
         # the resident kernels put the queries on the lanes: [bk, bq] tiles,
         # [1, bq] slices of the lse row
         return True
-    resident = 2 * t_inner * dh * itemsize
+    resident = t_inner * (dh + dv) * itemsize
     return (resident > RESIDENT_MAX_BYTES
             or dq_rows * dh * 4 > RESIDENT_DQ_MAX_BYTES
             or (max(bq, bk) > 512 and resident > 1024 * 1024))
@@ -246,7 +251,7 @@ def _dot(a, b, dims):
 def _fwd_kernel_res(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, block_k,
                     q_offset, k_offset, num_k, prefix_len):
     bq = q_ref.shape[1]
-    dh = q_ref.shape[2]
+    dv = v_ref.shape[2]
     q = q_ref[0]  # [bq, dh] native dtype; MXU accumulates f32 below
     qi = pl.program_id(1)
     q_pos = q_offset + qi * bq + jax.lax.broadcasted_iota(jnp.int32, (1, bq), 1)
@@ -254,7 +259,7 @@ def _fwd_kernel_res(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, block_k,
                              num_k, prefix_len)
 
     def body(j, carry):
-        m, l, acc = carry  # [1, bq], [1, bq], [dh, bq]
+        m, l, acc = carry  # [1, bq], [1, bq], [dv, bq]
         rows = pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
         k_blk = k_ref[0, rows, :]
         v_blk = v_ref[0, rows, :]
@@ -273,7 +278,7 @@ def _fwd_kernel_res(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, block_k,
 
     m0 = jnp.full((1, bq), NEG_INF, jnp.float32)
     l0 = jnp.zeros((1, bq), jnp.float32)
-    acc0 = jnp.zeros((dh, bq), jnp.float32)
+    acc0 = jnp.zeros((dv, bq), jnp.float32)
     m, l, acc = jax.lax.fori_loop(0, bound, body, (m0, l0, acc0))
     l_safe = jnp.maximum(l, 1e-20)
     o_ref[0] = (acc / l_safe).T.astype(o_ref.dtype)
@@ -437,6 +442,8 @@ def _bh(x):
 def flash_attention(q, k, v, q_offset=0, k_offset=0, prefix_len=0,
                     block_q=512, block_k=512, interpret=False, stream=None):
     """Causal / prefix-LM attention, [B, H, T, dh] -> [B, H, Tq, dh], fused.
+    q and k share a width (which sets the scale, 1/sqrt of it); v and the
+    output may have another (latent attention: q/k 192 wide, v 128).
 
     Semantics match models/transformer.py causal_attention (including the
     q_offset/k_offset absolute-position convention and the prefix-LM rule:
@@ -460,8 +467,8 @@ def _flash_fwd_impl(q, k, v, q_offset, k_offset, prefix_len, block_q, block_k,
     dimensions, 128 times its size) and what the resident kernels read."""
     from jax.experimental.pallas import tpu as pltpu
 
-    B, H, Tq, dh = q.shape
-    Tk = k.shape[2]
+    B, H, Tq, dh = q.shape  # dh: the q/k width, which sets the scale
+    Tk, dv = k.shape[2], v.shape[3]  # dv: the v/o width
     bq = _pick_block(Tq, block_q, interpret)
     bk = _pick_block(Tk, block_k, interpret)
     num_q, num_k = Tq // bq, Tk // bk
@@ -469,7 +476,7 @@ def _flash_fwd_impl(q, k, v, q_offset, k_offset, prefix_len, block_q, block_k,
     qr, kr, vr = _bh(q), _bh(k), _bh(v)
     BH = B * H
     streaming = _use_streaming(Tk, dh, q.dtype.itemsize, bq, bk, stream,
-                               interpret=interpret)
+                               interpret=interpret, dv=dv)
     f32 = jnp.float32
 
     kw = dict(scale=scale, block_k=bk, q_offset=q_offset, k_offset=k_offset,
@@ -480,16 +487,16 @@ def _flash_fwd_impl(q, k, v, q_offset, k_offset, prefix_len, block_q, block_k,
         in_specs = [
             pl.BlockSpec((1, bq, dh), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, bk, dh), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bk, dh), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, bk, dv), lambda b, i, j: (b, j, 0)),
         ]
         out_specs = [
-            pl.BlockSpec((1, bq, dh), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, bq, dv), lambda b, i, j: (b, i, 0)),
             # [T, 1] (not [T]): TPU block tiling wants two trailing dims
             pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
         ]
         lse_shape = (BH, Tq, 1)
         scratch = [pltpu.VMEM((bq, 1), f32), pltpu.VMEM((bq, 1), f32),
-                   pltpu.VMEM((bq, dh), f32)]
+                   pltpu.VMEM((bq, dv), f32)]
         semantics = ("parallel", "parallel", "arbitrary")
     else:
         kern = functools.partial(_fwd_kernel_res, **kw)
@@ -497,10 +504,10 @@ def _flash_fwd_impl(q, k, v, q_offset, k_offset, prefix_len, block_q, block_k,
         in_specs = [
             pl.BlockSpec((1, bq, dh), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, Tk, dh), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, Tk, dh), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((1, Tk, dv), lambda b, i: (b, 0, 0)),
         ]
         out_specs = [
-            pl.BlockSpec((1, bq, dh), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, bq, dv), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, 1, bq), lambda b, i: (b, 0, i)),
         ]
         lse_shape = (BH, 1, Tq)
@@ -513,7 +520,7 @@ def _flash_fwd_impl(q, k, v, q_offset, k_offset, prefix_len, block_q, block_k,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=[
-            _out_struct((BH, Tq, dh), q.dtype, q, k, v),
+            _out_struct((BH, Tq, dv), q.dtype, q, k, v),
             _out_struct(lse_shape, jnp.float32, q, k, v),
         ],
         scratch_shapes=scratch,
@@ -521,7 +528,7 @@ def _flash_fwd_impl(q, k, v, q_offset, k_offset, prefix_len, block_q, block_k,
         name="flash_attn_fwd",
         **_grid_params(interpret, *semantics),
     )(qr, kr, vr)
-    return o.reshape(B, H, Tq, dh), lse.reshape(BH, 1, Tq)
+    return o.reshape(B, H, Tq, dv), lse.reshape(BH, 1, Tq)
 
 
 def _flash_fwd(q, k, v, q_offset, k_offset, prefix_len, block_q, block_k,
@@ -542,8 +549,8 @@ def _flash_bwd_core(q_offset, k_offset, prefix_len, block_q, block_k,
     from jax.experimental.pallas import tpu as pltpu
 
     q, k, v, o, lse = res
-    B, H, Tq, dh = q.shape
-    Tk = k.shape[2]
+    B, H, Tq, dh = q.shape  # dh: the q/k width; dv: the v/o width
+    Tk, dv = k.shape[2], v.shape[3]
     bq = _pick_block(Tq, block_q, interpret)
     bk = _pick_block(Tk, block_k, interpret)
     num_q, num_k = Tq // bq, Tk // bk
@@ -559,23 +566,25 @@ def _flash_bwd_core(q_offset, k_offset, prefix_len, block_q, block_k,
         delta = delta - g_lse.astype(jnp.float32)
     qr, kr, vr, gr = _bh(q), _bh(k), _bh(v), _bh(g)
     f32 = jnp.float32
-    shape4 = lambda x, T: x.reshape(B, H, T, dh)
+    shape4 = lambda x, T: x.reshape(B, H, T, x.shape[-1])
     grad_of = lambda x: _out_struct(x.shape, x.dtype, qr, kr, vr, gr)
     kw = dict(scale=1.0 / math.sqrt(dh), q_offset=q_offset,
               k_offset=k_offset, prefix_len=prefix_len)
 
     # the one-pass kernel keeps the Q side resident: Q, dO, lse, delta, dQ
     if not _use_streaming(Tq, dh, isz, bq, bk, stream, dq_rows=Tq,
-                          interpret=interpret):
+                          interpret=interpret, dv=dv):
         k_blk = pl.BlockSpec((1, bk, dh), lambda b, j: (b, j, 0))
+        v_blk = pl.BlockSpec((1, bk, dv), lambda b, j: (b, j, 0))
         q_all = pl.BlockSpec((1, Tq, dh), lambda b, j: (b, 0, 0))
+        do_all = pl.BlockSpec((1, Tq, dv), lambda b, j: (b, 0, 0))
         row = pl.BlockSpec((1, 1, Tq), lambda b, j: (b, 0, 0))
         dq, dk, dv = pl.pallas_call(
             functools.partial(_dq_dkv_kernel_res, block_q=bq, num_q=num_q,
                               num_k=num_k, **kw),
             grid=(BH, num_k),
-            in_specs=[k_blk, k_blk, q_all, q_all, row, row],
-            out_specs=[q_all, k_blk, k_blk],
+            in_specs=[k_blk, v_blk, q_all, do_all, row, row],
+            out_specs=[q_all, k_blk, v_blk],
             out_shape=[grad_of(qr), grad_of(kr), grad_of(vr)],
             scratch_shapes=[pltpu.VMEM((dh, Tq), f32)],
             interpret=interpret,
@@ -592,12 +601,14 @@ def _flash_bwd_core(q_offset, k_offset, prefix_len, block_q, block_k,
     lse_c, delta_c = lse.reshape(BH, Tq, 1), delta.reshape(BH, Tq, 1)
     semantics = ("parallel", "parallel", "arbitrary")
     q_blk = pl.BlockSpec((1, bq, dh), lambda b, i, j: (b, i, 0))
+    do_blk = pl.BlockSpec((1, bq, dv), lambda b, i, j: (b, i, 0))
     q_col = pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0))
     k_blk = pl.BlockSpec((1, bk, dh), lambda b, i, j: (b, j, 0))
+    v_blk = pl.BlockSpec((1, bk, dv), lambda b, i, j: (b, j, 0))
     dq = pl.pallas_call(
         functools.partial(_dq_kernel_stream, block_k=bk, num_k=num_k, **kw),
         grid=(BH, num_q, num_k),
-        in_specs=[q_blk, k_blk, k_blk, q_blk, q_col, q_col],
+        in_specs=[q_blk, k_blk, v_blk, do_blk, q_col, q_col],
         out_specs=q_blk,
         out_shape=grad_of(qr),
         scratch_shapes=[pltpu.VMEM((bq, dh), f32)],
@@ -608,15 +619,17 @@ def _flash_bwd_core(q_offset, k_offset, prefix_len, block_q, block_k,
 
     # the dkv kernel streams Q-side operands: Q, dO, lse, delta
     k_blk = pl.BlockSpec((1, bk, dh), lambda b, j, i: (b, j, 0))
+    v_blk = pl.BlockSpec((1, bk, dv), lambda b, j, i: (b, j, 0))
     q_blk = pl.BlockSpec((1, bq, dh), lambda b, j, i: (b, i, 0))
+    do_blk = pl.BlockSpec((1, bq, dv), lambda b, j, i: (b, i, 0))
     q_col = pl.BlockSpec((1, bq, 1), lambda b, j, i: (b, i, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel_stream, block_q=bq, num_q=num_q, **kw),
         grid=(BH, num_k, num_q),
-        in_specs=[k_blk, k_blk, q_blk, q_blk, q_col, q_col],
-        out_specs=[k_blk, k_blk],
+        in_specs=[k_blk, v_blk, q_blk, do_blk, q_col, q_col],
+        out_specs=[k_blk, v_blk],
         out_shape=[grad_of(kr), grad_of(vr)],
-        scratch_shapes=[pltpu.VMEM((bk, dh), f32), pltpu.VMEM((bk, dh), f32)],
+        scratch_shapes=[pltpu.VMEM((bk, dh), f32), pltpu.VMEM((bk, dv), f32)],
         interpret=interpret,
         name="flash_attn_dkv",
         **_grid_params(interpret, *semantics),

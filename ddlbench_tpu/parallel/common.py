@@ -657,14 +657,20 @@ def gradual_warmup_lr(scaled_lr: float, world: int, epoch0: int, step: int,
     return scaled_lr * lr_adj
 
 
-def cast_params(params, dtype):
-    """Cast floating-point leaves to the compute dtype (bf16 on TPU)."""
+def cast_params(params, dtype, layers=None):
+    """Cast floating-point leaves to the compute dtype (bf16 on TPU). With
+    ``layers`` (the Layers whose per-layer list ``params`` is), what a layer
+    names in ``f32_params`` stays as it is."""
     if dtype is None:
         return params
-    return jax.tree.map(
+    cast = lambda tree: jax.tree.map(
         lambda x: x.astype(dtype) if jnp.issubdtype(x.dtype, jnp.floating) else x,
-        params,
+        tree,
     )
+    if layers is None or not any(l.f32_params for l in layers):
+        return cast(params)
+    return [{k: v if k in l.f32_params else cast(v) for k, v in p.items()}
+            if l.f32_params else cast(p) for l, p in zip(layers, params)]
 
 
 def vary(v, axes):
@@ -749,7 +755,7 @@ def eval_metrics(model, cfg, params, model_state, x, y, compute_dtype):
     """Shared eval step core for single/dp/tp/fsdp: returns the metric dict
     {loss, correct, correct5, count}. Uses the fused head path (no [N, V]
     logits) when available and enabled."""
-    p = cast_params(params, compute_dtype)
+    p = cast_params(params, compute_dtype, model.layers)
     xc = cast_input(x, compute_dtype)
     if cfg.fused_head_loss and model.layers[-1].fused_eval is not None:
         ce_sum, correct, correct5, count = fused_head_eval_sums(
@@ -788,7 +794,7 @@ def loss_with_moe_aux(model, params, model_state, x, y, train, compute_dtype,
     from ddlbench_tpu.models.layers import apply_model
     from ddlbench_tpu.models.moe import collect_aux_losses
 
-    p = cast_params(params, compute_dtype)
+    p = cast_params(params, compute_dtype, model.layers)
     xc = cast_input(x, compute_dtype)
     aux: list = []
     if fused and train and head_fusable(model):

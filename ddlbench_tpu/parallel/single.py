@@ -14,7 +14,8 @@ import jax
 import jax.numpy as jnp
 
 from ddlbench_tpu.config import RunConfig
-from ddlbench_tpu.models.layers import LayerModel, init_model
+from ddlbench_tpu.models.layers import (LayerModel, init_model,
+                                        routing_counters)
 from ddlbench_tpu.parallel.common import make_optimizer
 
 
@@ -69,6 +70,8 @@ class SingleStrategy:
             }
             if guard is not None:
                 metrics.update(gm)
+            # an expert model's routing counters (none for any other model)
+            metrics.update(routing_counters(new_state))
             return TrainState(params, new_state, opt), metrics
 
         def eval_step(ts: TrainState, x, y):

@@ -544,7 +544,7 @@ def _rewrite_fields(cfg: RunConfig, winner: Candidate, micro_batch: int,
         # weight update) — except MoE archs, whose router statistics need
         # the replicated engine (config.validate).
         base.update(strategy="dp", batch_size=global_batch // dp,
-                    dp_shard_update="moe" not in cfg.arch or force_shard)
+                    dp_shard_update=not cfg.collects_aux_loss() or force_shard)
         return base
     if pp == 1 and dp == 1:
         # pure tensor parallelism: the standalone Megatron-sharded engine
@@ -688,7 +688,7 @@ def plan_for_config(cfg: RunConfig, input_time_ms: float = 0.0
         tp_candidates=(_model_tp_widths(cfg.arch, cfg.num_devices)
                        if token_model else []),
         remat=cfg.remat_stages, pin_pp=pin_pp, pin_bounds=pin_bounds,
-        zero1="moe" not in cfg.arch, h2_stash=cfg.zb_h2_stash,
+        zero1=not cfg.collects_aux_loss(), h2_stash=cfg.zb_h2_stash,
         search_budget=cfg.sched_search_budget,
         search_seed=cfg.sched_search_seed)
     rewrite = _rewrite_fields(cfg, plan.winner, mb, chunks,

@@ -40,6 +40,16 @@ HEAD = "head"    # lm_head's projection to the vocabulary
 LOSS = "loss"    # cross entropy, fused (<head instance>/loss) or not
 KINDS = (CONV, BN, POOL, FC, EMBED, LN, ATTN, MLP, HEAD, LOSS)
 
+# parts of a layer's work that a model names beside the kinds. The kinds are
+# a closed vocabulary on the benchmark's side (an accepted test of
+# tests/benchmark/ pins the ten), so these are read by a reader of their own
+# (benchmarks/metrics/readers/scope_part_ms.py): the innermost token of
+# KINDS + PARTS on an instruction's path decides what it counts as.
+LATENT = "latent"    # kanana2: kv_a, the latent's norm, kv_b, k and v assembled
+ROUTE = "route"      # kanana2: router, top-k, sort, gather, weighted scatter
+EXPERTS = "experts"  # kanana2: the grouped products over the experts held
+PARTS = (LATENT, ROUTE, EXPERTS)
+
 # step phases outside the differentiated model
 OPTIMIZER = "optimizer"  # common.make_optimizer's update
 GRAD_SYNC = "grad_sync"  # dp's explicit gradient collectives, /bucket<b>

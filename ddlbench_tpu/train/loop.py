@@ -752,6 +752,14 @@ def _run_benchmark(cfg: RunConfig, strategy, data, logger: MetricLogger,
                                                    f"ending step {step + 1}")
                             guard.flush(epoch, step + 1)
                         loss_sum, host_loss_sum, interval_steps = None, 0.0, 0
+                        if tracer.enabled:
+                            # an expert model's routing counters of this
+                            # step (the interval's sync has just landed)
+                            for key in ("moe_held_slots",
+                                        "moe_load_max_over_mean"):
+                                if key in metrics:
+                                    tracer.counter("moe/" + key[4:],
+                                                   float(metrics[key]))
                         now = time.perf_counter()
                         logger.train_interval(
                             epoch,

@@ -30,6 +30,16 @@ jax.config.update("jax_platforms", "cpu")
 
 import pytest  # noqa: E402
 
+from ddlbench_tpu.models import kanana2  # noqa: E402
+
+# The test size of the kanana2 family (tests/test_kanana2.py, the rehearsal
+# configuration under tests/benchmark/data/kanana2): every code path, 1-core
+# CPU compiles. The program's own table holds published sizes only.
+kanana2.FAMILY["kanana2_t"] = kanana2.Dims(
+    d_model=64, n_heads=4, qk_nope=16, qk_rope=8, v_head=16, kv_latent=32,
+    dense_ff=96, expert_ff=24, n_experts=16, n_shared=2, top_k=3,
+    route_scale=2.448, n_layers=3)
+
 
 def pytest_addoption(parser):
     parser.addoption(

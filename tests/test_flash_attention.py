@@ -398,3 +398,102 @@ def test_attnbench_tile_sweep_needs_the_compiled_kernels():
 
     with pytest.raises(SystemExit):
         main(["--seq-lens", "64", "--tiles", "32x32", "--platform", "cpu"])
+
+
+# ---------------------------------------------------------------------------
+# q/k width apart from the v/o width (latent attention: models/kanana2.py)
+# ---------------------------------------------------------------------------
+
+SPLIT = dict(B=2, H=2, T=128, dqk=24, dv=16)
+
+
+def _split_qkv(seed=0):
+    s = SPLIT
+    ks = jax.random.split(jax.random.key(seed), 4)
+    q = _rand((s["B"], s["H"], s["T"], s["dqk"]), ks[0])
+    k = _rand((s["B"], s["H"], s["T"], s["dqk"]), ks[1])
+    v = _rand((s["B"], s["H"], s["T"], s["dv"]), ks[2])
+    g = _rand((s["B"], s["H"], s["T"], s["dv"]), ks[3])
+    return q, k, v, g
+
+
+def _einsum_attention(q, k, v):
+    """The plain thing: scale from the q/k width, output as wide as v."""
+    T = q.shape[2]
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    s = jnp.where(jnp.tril(jnp.ones((T, T), bool)), s, -jnp.inf)
+    return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), v)
+
+
+@pytest.mark.parametrize("stream", [False, True],
+                         ids=["resident", "streaming"])
+def test_split_widths_forward_and_gradients(stream):
+    """q/k 24 wide, v/o 16 wide: forward and all three gradients of both
+    grid designs against the einsum (the scale is 1/sqrt(24))."""
+    q, k, v, g = _split_qkv()
+    with jax.default_matmul_precision("highest"):
+        ref, ref_vjp = jax.vjp(_einsum_attention, q, k, v)
+        got, got_vjp = jax.vjp(
+            lambda q, k, v: flash_attention(q, k, v, 0, 0, 0, 32, 32, True,
+                                            stream), q, k, v)
+        assert got.shape == v.shape
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   atol=2e-5)
+        for a, b, name in zip(got_vjp(g), ref_vjp(g), "qkv"):
+            assert a.shape == b.shape
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=5e-5, err_msg=f"d{name}")
+
+
+def test_split_widths_through_the_dispatch():
+    """causal_attention's XLA path and the forced kernel agree on split
+    widths too (the einsum path scales by the q/k width as well)."""
+    q, k, v, _ = _split_qkv(1)
+    with jax.default_matmul_precision("highest"):
+        xla = causal_attention(q, k, v)
+        np.testing.assert_allclose(np.asarray(xla),
+                                   np.asarray(_einsum_attention(q, k, v)),
+                                   atol=2e-5)
+        set_attention_backend("flash")
+        np.testing.assert_allclose(np.asarray(causal_attention(q, k, v)),
+                                   np.asarray(xla), atol=2e-5)
+
+
+def test_split_widths_lse_and_its_cotangent():
+    q, k, v, g = _split_qkv(2)
+    with jax.default_matmul_precision("highest"):
+        (o, lse), vjp = jax.vjp(
+            lambda q, k, v: flash_attention_lse(q, k, v, 0, 0, 0, 32, 32,
+                                                True), q, k, v)
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(q.shape[-1])
+        s = jnp.where(jnp.tril(jnp.ones((128, 128), bool)), s, -jnp.inf)
+        np.testing.assert_allclose(
+            np.asarray(lse), np.asarray(jax.nn.logsumexp(s, -1)), atol=2e-5)
+        ref = jax.grad(lambda q: jnp.sum(jax.nn.logsumexp(
+            jnp.where(jnp.tril(jnp.ones((128, 128), bool)),
+                      jnp.einsum("bhqd,bhkd->bhqk", q, k)
+                      / np.sqrt(q.shape[-1]), -jnp.inf), -1)))(q)
+        dq = vjp((jnp.zeros_like(o), jnp.ones_like(lse)))[0]
+        np.testing.assert_allclose(np.asarray(dq), np.asarray(ref),
+                                   atol=5e-5)
+
+
+@pytest.mark.parametrize("T,dqk,dv,fwd_streams,bwd_streams", [
+    (4096, 192, 128, False, True),   # kanana2-ep16-train: K + V 2.5 MiB
+                                     # resident; f32 dQ 3 MiB: the pair
+    (2048, 192, 128, False, False),  # dQ 1.5 MiB: the one-pass backward
+    (8192, 192, 128, True, True),    # K + V 5 MiB
+    (4096, 64, 64, False, False),    # equal widths: the rule as it was
+    (8192, 64, 64, False, False),
+])
+def test_streaming_rule_on_split_widths(T, dqk, dv, fwd_streams,
+                                        bwd_streams):
+    """The inner side holds one operand of each width (K and V forward, Q
+    and dO backward); the f32 dQ block is q/k wide."""
+    from ddlbench_tpu.ops.flash_attention import _use_streaming
+
+    assert _use_streaming(T, dqk, 2, 512, 512, None, dv=dv) == fwd_streams
+    assert _use_streaming(T, dqk, 2, 512, 512, None, dq_rows=T, dv=dv) \
+        == bwd_streams
+    if dqk == dv:  # dv left out means dv = dh: what every old caller gets
+        assert _use_streaming(T, dqk, 2, 512, 512, None) == fwd_streams

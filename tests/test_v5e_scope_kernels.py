@@ -151,3 +151,46 @@ def test_fused_xent_keeps_its_names_inside_the_scopes(one_chip, as_on_tpu):
     _assert_kernels(found, "lm_head", "loss", (
         ("fused_xent_fwd", "jvp({})"), ("fused_xent_dh", "transpose(jvp({}))"),
         ("fused_xent_dw", "transpose(jvp({}))")))
+
+
+def test_the_latent_attention_block_compiles_with_named_kernels(one_chip,
+                                                                as_on_tpu):
+    """kanana-2-30b-a3b's expert block at its published widths and the
+    cell's batch (4 x 4096): the flash forward resident and the backward
+    the streaming pair at q/k 192, v 128, all three under the block's
+    ``attn``; the grouped products' Pallas kernels under ``experts``, with
+    names the benchmark's moe_gmm.EVENTS find."""
+    from ddlbench_tpu.models import kanana2
+    from ddlbench_tpu.models.layers import apply_slice
+
+    dims = kanana2.FAMILY["kanana2_30b_a3b"]
+    block = kanana2.expert_block("block2", dims, (0, 8))
+    params, state = jax.eval_shape(
+        lambda k: block.init(k, (4096, dims.d_model))[:2], jax.random.key(0))
+    leaves, tree = jax.tree.flatten(params)
+
+    def loss(x, *flat):
+        p = jax.tree.unflatten(tree, flat)
+        y, _ = apply_slice([block], [p], [state], x, True)
+        return y.astype(jnp.float32).sum()
+
+    found = _mosaic_calls(
+        jax.grad(loss), one_chip, ((4, 4096, dims.d_model), jnp.bfloat16),
+        *[(a.shape, jnp.float32 if a.ndim == 1 and a.shape[0] == 128
+           or a.shape == (dims.d_model, 128) else jnp.bfloat16)
+          for a in leaves])
+    flash = sorted(n.split(".")[0] for n in found if "flash" in n)
+    assert flash == ["flash_attn_dkv", "flash_attn_dq", "flash_attn_fwd"]
+    for n, op in found.items():
+        if "flash" in n:
+            assert "(block2)" in op and "/attn/" in op, op
+    from benchmarks.kernels.moe_gmm import EVENTS
+
+    products = [n for n in found if "flash" not in n]
+    # two branches (the common buffer and the one with room for every
+    # slot) x (3 forward + 3 for the rows' gradient; the weights' tgmm are
+    # not asked for: the gradient here is the input's)
+    assert len(products) == 2 * 6
+    assert all(any(e in n for e in EVENTS) for n in products), products
+    assert all("/route/" in found[n] and "/experts/" in found[n]
+               for n in products)
