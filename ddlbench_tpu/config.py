@@ -67,8 +67,8 @@ DATASETS: Mapping[str, DatasetSpec] = {
     # LM context and a long-context stressor for sequence parallelism.
     "synthtext": DatasetSpec("synthtext", (1024,), 32_768, 100_000, 10_000, kind="tokens"),
     "longctx": DatasetSpec("longctx", (8192,), 32_768, 20_000, 2_000, kind="tokens"),
-    # 32k context: single-chip-trainable ONLY via the streaming flash
-    # kernels (ops/flash_attention.py round 3) + fused head — XLA attention
+    # 32k context: single-chip-trainable ONLY via the flash kernels
+    # (ops/flash_attention.py) + fused head — XLA attention
     # would need a 2 GB score matrix per layer per 8k, and at 32k a single
     # layer's matrix alone exceeds one chip's HBM even under remat.
     "longctx32k": DatasetSpec("longctx32k", (32_768,), 32_768, 5_000, 500,

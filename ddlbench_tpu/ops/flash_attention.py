@@ -32,16 +32,34 @@ Two grid designs:
   compiles (T=32k on one chip). Forward, dq and dkv kernels, row-major
   tiles. Dead causal cells still pay their fetch.
 
-_use_streaming picks: resident while what the kernel keeps resident fits a
-conservative budget, streaming beyond (or under oversized block requests).
-Block-level causal skipping in both: resident bounds its fori, streaming
-skips dead cells' compute under @pl.when. Measured on one v5e at B*H=192,
-dh=64, bf16 (PERF.md, PR 25), device ms per call, forward / backward:
-T=1024 0.78 / 1.34 (row-major tiles and a dq + dkv pair, before: 0.97 /
-2.35); T=8192 (B*H=24) 3.9 / 6.0 against 6.8 / 15.5 streaming. 512x512
-tiles won every sweep of {128, 256, 512}^2 at T=1024..8192: the sweeps are
-1-2 iterations long and do not pipeline, so a finer causal tiling loses more
-per tile than it saves in area.
+_use_streaming picks by ONE accounting, _resident_vmem_bytes: what the
+resident kernel would hold in VMEM at the call's shapes (its blocks as
+Mosaic pads and double-buffers them, its scratch, its tile temporaries).
+Resident while that sum fits RESIDENT_VMEM_BUDGET, half the chip's VMEM;
+streaming beyond; the same sum and a quarter more is the kernel's
+``vmem_limit_bytes``. The two benchmark shapes (bf16, 512x512), forward /
+one-pass backward: T=1024 dh=64 (gpt2s-train) 4.1 / 6.5 MiB; T=4096 q/k 192
+v 128 (kanana2-ep16-train) 9.5 / 18.9 MiB. Block-level causal skipping in
+both designs: resident bounds its fori, streaming skips dead cells' compute
+under @pl.when.
+
+Measured on one v5e, bf16, device ms per call (PERF.md: PR 25 for dh=64,
+PR 28 for the rest), forward / backward, resident against streaming:
+
+    B*H 192, T=1024, dh=64          0.78 /  1.34     1.21 /  2.59
+    B*H  24, T=8192, dh=64          3.89 /  6.24     6.83 / 14.54
+    B*H 128, T=4096, q/k 192 v 128  7.20 / 14.95    12.77 / 30.34
+    B*H  16, T=16384, q/k 192 v 128 12.68 / 25.92   22.68 / 52.64
+    B*H   8, T=32768, dh=64         19.38 / 31.07   36.17 / 75.73
+
+(before PR 25's one-pass backward: row-major tiles and a resident dq + dkv
+pair, 0.97 / 2.35 at T=1024.) No shape that compiles ran slower resident,
+f32 operands, a prefix and (256, 1024) blocks included. 512x512 tiles won
+every sweep: of {128, 256, 512}^2 at T=1024..8192 and dh=64, and at T=4096,
+q/k 192, v 128 the one-pass backward takes 14.95 ms at 512x512, 16.11 at
+(512, 256), 19.89 at 256x256, 20.82 at (256, 512) (forward 7.20, 8.02,
+10.68, 9.04): the sweeps are a few iterations long and do not pipeline, so
+a finer causal tiling loses more per tile than it saves in area.
 
 ``q_offset``/``k_offset`` give each block its absolute position — the same
 convention as causal_attention — so the kernel also serves blocks of a
@@ -63,27 +81,80 @@ from ddlbench_tpu.ops.util import pallas_out_struct as _out_struct
 
 NEG_INF = -1e30
 
-# Inner-side resident bytes (both streamed operands, raw) past which the
-# streaming design is used. 3 MiB keeps every benchmarked shape on the fast
-# resident path (T=8192, dh=64, bf16 -> 2 MiB) while dh=128 or f32 at 8k+
-# stream. Oversized blocks (max > 512) also stream once the inner side is
-# nontrivial: a resident kernel measured 16.8 MiB scoped VMEM at
-# (bq=256, bk=1024, T=8192).
-RESIDENT_MAX_BYTES = 3 * 1024 * 1024
-# The one-pass backward also keeps dQ of the whole row block resident, in f32
-# (Tq * dh * 4 bytes): 256 KiB at T=1024 dh=64, 2 MiB at T=8192. Past this the
-# backward is the streaming two-kernel pair.
-RESIDENT_DQ_MAX_BYTES = 2 * 1024 * 1024
+# A resident kernel may hold half of a v5e TensorCore's 128 MiB of VMEM (as
+# _resident_vmem_bytes sums it); past that the streaming design runs. Half,
+# because the other half has to take the kernel's margin (_vmem_limit_bytes:
+# a limit of up to 80 MiB), and what XLA keeps in VMEM across the call. No
+# shape was found that compiles and runs slower resident: the largest run
+# on the chip (PERF.md, PR 28: 61.2 MiB, T 32768 dh 64; 58.1 MiB, T 16384
+# 192/128) beat streaming 1.9x forward and 2.0-2.4x backward.
+RESIDENT_VMEM_BUDGET = (128 << 20) // 2
+
+
+def _tile_bytes(rows: int, cols: int, itemsize: int) -> int:
+    """Bytes of a [rows, cols] array in VMEM: the lanes padded to 128, the
+    sublanes to a whole tile (8 rows of 32 bits: 16 of bf16)."""
+    up = lambda n, m: -(-n // m) * m
+    return up(rows, 8 * max(1, 4 // itemsize)) * up(cols, 128) * itemsize
+
+
+def _resident_vmem_bytes(t_inner: int, dh: int, dv: int, itemsize: int,
+                         bq: int, bk: int, backward: bool) -> int:
+    """What a resident kernel holds in VMEM at these shapes, summed by its
+    BlockSpecs as Mosaic lays them out (_tile_bytes; every input and output
+    block twice, the pipeline's double buffer). ``dh`` is the q/k width,
+    ``dv`` the v/o width; ``t_inner`` the resident side's length: Tk for the
+    forward (_fwd_kernel_res), Tq for the one-pass backward
+    (_dq_dkv_kernel_res). Checked against the least ``vmem_limit_bytes``
+    Mosaic accepts for each kernel (v5e compile, B*H 128; PERF.md, PR 28) at
+    22 shapes: T 768..16384, widths 64/64, 128/128, 192/128, bf16 and f32,
+    tiles 128^2..1024^2. The sum reads 0.975-1.06 of Mosaic's number
+    wherever the sweep is 8 tiles or longer and the tiles are 512 or less
+    (1.008 for the backward at T 4096, 192/128), least at (256, 1024); up to
+    1.36 at 1024^2 tiles and up to 2.0 at T <= 2048, whose short sweeps
+    Mosaic holds in less."""
+    f32 = 4  # bytes
+    if backward:
+        # K, V in and dK, dV out, blockwise
+        blocks = 2 * 2 * (_tile_bytes(bk, dh, itemsize)
+                          + _tile_bytes(bk, dv, itemsize))
+        # Q and dO in, dQ out: whole rows of a head
+        resident = 2 * (2 * _tile_bytes(t_inner, dh, itemsize)
+                        + _tile_bytes(t_inner, dv, itemsize))
+        # lse and delta: [1, Tq] f32 blocks are (1, 128)-tiled, not padded
+        # to 8 sublanes
+        rows = 2 * 2 * t_inner * f32
+        scratch = _tile_bytes(dh, t_inner, f32)  # dQ^T
+        # s -> p and dp -> ds in f32, p and ds in the operands' dtype for
+        # their products; the dK / dV carry; K^T; one dQ^T tile
+        temps = (bq * bk * (2 * f32 + 2 * itemsize)
+                 + _tile_bytes(bk, dh, f32) + _tile_bytes(bk, dv, f32)
+                 + _tile_bytes(dh, bk, itemsize) + _tile_bytes(dh, bq, f32))
+        return blocks + resident + rows + scratch + temps
+    # Q in, O and the lse row out, blockwise
+    blocks = (2 * (_tile_bytes(bq, dh, itemsize) + _tile_bytes(bq, dv, itemsize))
+              + 2 * bq * f32)
+    resident = 2 * (_tile_bytes(t_inner, dh, itemsize)
+                    + _tile_bytes(t_inner, dv, itemsize))  # K, V
+    # s and p in f32, p in the operands' dtype for PV; the O^T accumulator
+    temps = bq * bk * (2 * f32 + itemsize) + _tile_bytes(dv, bq, f32)
+    return blocks + resident + temps
+
+
+def _vmem_limit_bytes(held: int) -> int:
+    """The ``vmem_limit_bytes`` of a resident kernel that holds ``held``: a
+    quarter more, for Mosaic's own scratch and for the shapes at which the
+    accounting reads under its report (_resident_vmem_bytes)."""
+    return held + held // 4
 
 
 def _use_streaming(t_inner: int, dh: int, itemsize: int, bq: int, bk: int,
-                   stream, dq_rows: int = 0, interpret: bool = False,
+                   stream, backward: bool = False, interpret: bool = False,
                    dv: int | None = None) -> bool:
-    """``dh`` is the q/k width and ``dv`` the v/o width (``dh`` when None).
-    The inner side holds one operand of each width: K and V in the forward,
-    Q and dO in the one-pass backward. ``dq_rows``: rows of the f32 dQ block
-    the kernel keeps resident (the one-pass backward: Tq; 0 for the
-    forward)."""
+    """``dh`` is the q/k width and ``dv`` the v/o width (``dh`` when None);
+    ``t_inner`` the length of the side a resident kernel would hold: Tk for
+    the forward (K and V), Tq for the one-pass backward (``backward``: Q, dO,
+    dQ and its f32 scratch)."""
     dv = dh if dv is None else dv
     if stream is not None:
         return bool(stream)
@@ -91,10 +162,8 @@ def _use_streaming(t_inner: int, dh: int, itemsize: int, bq: int, bk: int,
         # the resident kernels put the queries on the lanes: [bk, bq] tiles,
         # [1, bq] slices of the lse row
         return True
-    resident = t_inner * (dh + dv) * itemsize
-    return (resident > RESIDENT_MAX_BYTES
-            or dq_rows * dh * 4 > RESIDENT_DQ_MAX_BYTES
-            or (max(bq, bk) > 512 and resident > 1024 * 1024))
+    return _resident_vmem_bytes(t_inner, dh, dv, itemsize, bq, bk,
+                                backward) > RESIDENT_VMEM_BUDGET
 
 
 def _grid_params(interpret: bool, *semantics: str, vmem_limit_bytes=None):
@@ -452,8 +521,11 @@ def flash_attention(q, k, v, q_offset=0, k_offset=0, prefix_len=0,
     ``block_k`` are upper bounds: blocks shrink to divide the sequence. The
     default 512x512 was the fastest of {128, 256, 512}^2 for the forward and
     for the one-pass backward at T=1024, 2048, 4096 and 8192 (dh=64, bf16,
-    one v5e; PERF.md, PR 25). ``stream`` forces the streaming (True) or
-    resident (False) grid design; None picks per kernel (module docstring).
+    one v5e; PERF.md, PR 25) and of {256, 512}^2 at T=4096, q/k 192, v 128
+    (PR 28). ``stream`` forces the streaming (True) or resident (False) grid
+    design; None picks per kernel, resident while what the kernel would hold
+    in VMEM fits the budget (_use_streaming; module docstring for the
+    accounted bytes and the measured ms of both designs).
     """
     o, _ = _flash_fwd_impl(q, k, v, q_offset, k_offset, prefix_len, block_q,
                            block_k, interpret, stream)
@@ -475,7 +547,8 @@ def _flash_fwd_impl(q, k, v, q_offset, k_offset, prefix_len, block_q, block_k,
     scale = 1.0 / math.sqrt(dh)
     qr, kr, vr = _bh(q), _bh(k), _bh(v)
     BH = B * H
-    streaming = _use_streaming(Tk, dh, q.dtype.itemsize, bq, bk, stream,
+    isz = q.dtype.itemsize
+    streaming = _use_streaming(Tk, dh, isz, bq, bk, stream,
                                interpret=interpret, dv=dv)
     f32 = jnp.float32
 
@@ -498,6 +571,7 @@ def _flash_fwd_impl(q, k, v, q_offset, k_offset, prefix_len, block_q, block_k,
         scratch = [pltpu.VMEM((bq, 1), f32), pltpu.VMEM((bq, 1), f32),
                    pltpu.VMEM((bq, dv), f32)]
         semantics = ("parallel", "parallel", "arbitrary")
+        vmem_limit = None  # T-independent blocks: Mosaic's default holds them
     else:
         kern = functools.partial(_fwd_kernel_res, **kw)
         grid = (BH, num_q)
@@ -513,6 +587,8 @@ def _flash_fwd_impl(q, k, v, q_offset, k_offset, prefix_len, block_q, block_k,
         lse_shape = (BH, 1, Tq)
         scratch = []
         semantics = ("parallel", "parallel")
+        vmem_limit = _vmem_limit_bytes(_resident_vmem_bytes(
+            Tk, dh, dv, isz, bq, bk, backward=False))
 
     o, lse = pl.pallas_call(
         kern,
@@ -526,7 +602,7 @@ def _flash_fwd_impl(q, k, v, q_offset, k_offset, prefix_len, block_q, block_k,
         scratch_shapes=scratch,
         interpret=interpret,
         name="flash_attn_fwd",
-        **_grid_params(interpret, *semantics),
+        **_grid_params(interpret, *semantics, vmem_limit_bytes=vmem_limit),
     )(qr, kr, vr)
     return o.reshape(B, H, Tq, dv), lse.reshape(BH, 1, Tq)
 
@@ -572,7 +648,7 @@ def _flash_bwd_core(q_offset, k_offset, prefix_len, block_q, block_k,
               k_offset=k_offset, prefix_len=prefix_len)
 
     # the one-pass kernel keeps the Q side resident: Q, dO, lse, delta, dQ
-    if not _use_streaming(Tq, dh, isz, bq, bk, stream, dq_rows=Tq,
+    if not _use_streaming(Tq, dh, isz, bq, bk, stream, backward=True,
                           interpret=interpret, dv=dv):
         k_blk = pl.BlockSpec((1, bk, dh), lambda b, j: (b, j, 0))
         v_blk = pl.BlockSpec((1, bk, dv), lambda b, j: (b, j, 0))
@@ -591,9 +667,10 @@ def _flash_bwd_core(q_offset, k_offset, prefix_len, block_q, block_k,
             # benchmarks/kernels/flash_attn.py finds the flash kernels'
             # trace events by substring: "flash_attn_dq" has to be in it
             name="flash_attn_dq_dkv",
-            # 25 MiB at T=8192 (dh=64, bf16), over the 16 MiB default
-            **_grid_params(interpret, "parallel", "arbitrary",
-                           vmem_limit_bytes=(16 << 20) + 3072 * Tq),
+            **_grid_params(
+                interpret, "parallel", "arbitrary",
+                vmem_limit_bytes=_vmem_limit_bytes(_resident_vmem_bytes(
+                    Tq, dh, dv, isz, bq, bk, backward=True))),
         )(kr, vr, qr, gr, lse, delta.reshape(BH, 1, Tq))
         return shape4(dq, Tq), shape4(dk, Tk), shape4(dv, Tk)
 

@@ -13,17 +13,20 @@ Sync discipline follows tools/timing.py: chain nothing (the primitive is
 stateless) and close each timed region on jax.block_until_ready.
 
 ``--tiles 256x256,512x512`` sweeps the flash kernels' (block_q x block_k)
-instead — the evidence behind flash_attention's default blocks. It reads
-DEVICE time per kernel from a profiler trace (a host clock around a 1 ms
-kernel times the dispatch), one JSON line per (T, tile):
+instead — the evidence behind flash_attention's default blocks and behind
+its resident budget (``--design`` forces either grid design where the shape
+rule would pick). It reads DEVICE time per kernel from a profiler trace (a
+host clock around a 1 ms kernel times the dispatch), one JSON line per
+(T, tile):
 
-    {"T": 1024, ..., "block_q": 512, "block_k": 512,
+    {"T": 1024, ..., "block_q": 512, "block_k": 512, "design": "auto",
      "kernel_ms": {"flash_attn_fwd": N, "flash_attn_dq_dkv": N}}
 
 Usage:
     python -m ddlbench_tpu.tools.attnbench [--seq-lens 128,256,512,1024]
-        [--batch 16] [--heads 8] [--head-dim 64] [--prefix 0] [--steps 50]
-        [--tiles 128x128,256x256,512x512]
+        [--batch 16] [--heads 8] [--head-dim 64] [--v-dim 64] [--prefix 0]
+        [--steps 50] [--tiles 128x128,256x256,512x512]
+        [--design auto|resident|streaming]
 """
 
 from __future__ import annotations
@@ -74,7 +77,11 @@ def main(argv=None) -> int:
     p.add_argument("--seq-lens", default="128,256,512,768,1024,2048")
     p.add_argument("--batch", type=int, default=16)
     p.add_argument("--heads", type=int, default=8)
-    p.add_argument("--head-dim", type=int, default=64)
+    p.add_argument("--head-dim", type=int, default=64,
+                   help="the q/k width")
+    p.add_argument("--v-dim", type=int, default=None,
+                   help="the v/o width (default: --head-dim); latent "
+                        "attention is 192 / 128")
     p.add_argument("--prefix", type=int, default=0,
                    help="prefix-LM visible-prefix length (seq2seq shape)")
     p.add_argument("--steps", type=int, default=50)
@@ -84,6 +91,10 @@ def main(argv=None) -> int:
     p.add_argument("--tiles", default="",
                    help="BQxBK,...: sweep the flash kernels' blocks (TPU "
                         "only), device ms per kernel from a trace")
+    p.add_argument("--design", default="auto",
+                   choices=("auto", "resident", "streaming"),
+                   help="with --tiles: the flash kernels' grid design; auto "
+                        "is the shape rule's pick")
     from ddlbench_tpu.distributed import add_platform_arg, apply_platform
 
     add_platform_arg(p)
@@ -100,6 +111,8 @@ def main(argv=None) -> int:
     enable_compilation_cache()
     backends = ("flash", "xla") if is_tpu_backend() else ("xla",)
     dtype = jnp.dtype(args.dtype)
+    v_dim = args.head_dim if args.v_dim is None else args.v_dim
+    stream = {"auto": None, "resident": False, "streaming": True}[args.design]
     tiles = [tuple(int(b) for b in t.split("x"))
              for t in args.tiles.split(",") if t]
     if tiles and not is_tpu_backend():
@@ -120,24 +133,24 @@ def main(argv=None) -> int:
 
     for T in (int(t) for t in args.seq_lens.split(",")):
         ks = jax.random.split(jax.random.key(0), 3)
-        q, k, v = (jax.random.normal(kk, (args.batch, args.heads, T,
-                                          args.head_dim), dtype) for kk in ks)
+        q, k, v = (jax.random.normal(kk, (args.batch, args.heads, T, d), dtype)
+                   for kk, d in zip(ks, (args.head_dim, args.head_dim, v_dim)))
 
         def loss(q, k, v):
             out = causal_attention(q, k, v, prefix_len=args.prefix)
             return jnp.sum(out.astype(jnp.float32))
 
         row = {"T": T, "B": args.batch, "H": args.heads,
-               "dh": args.head_dim, "prefix": args.prefix,
+               "dh": args.head_dim, "dv": v_dim, "prefix": args.prefix,
                "dtype": args.dtype, "repeats": args.repeats}
         for bq, bk in tiles:
             from ddlbench_tpu.ops.flash_attention import flash_attention
 
             g = jax.jit(jax.grad(lambda q, k, v: jnp.sum(flash_attention(
-                q, k, v, 0, 0, args.prefix, bq, bk).astype(jnp.float32)),
-                argnums=(0, 1, 2)))
+                q, k, v, 0, 0, args.prefix, bq, bk, False, stream
+                ).astype(jnp.float32)), argnums=(0, 1, 2)))
             print(json.dumps({**row, "block_q": bq, "block_k": bk,
-                              "kernel_ms": flash_kernel_ms(
+                              "design": args.design, "kernel_ms": flash_kernel_ms(
                                   g, (q, k, v), args.steps)}), flush=True)
         if tiles:
             continue

@@ -337,27 +337,36 @@ def test_sweep_bounds_cover_every_live_tile(T, bq, bk, qoff, koff, pfx):
         assert sum(live.values()) == 3  # the benchmark cell: 3 of 4 tiles
 
 
+MiB = 1 << 20
+
+
 def test_use_streaming_rule():
-    from ddlbench_tpu.ops.flash_attention import (RESIDENT_DQ_MAX_BYTES,
-                                                  RESIDENT_MAX_BYTES,
+    """The rule reads one accounting of what a resident kernel holds in VMEM
+    (_resident_vmem_bytes) against one budget. Each case the old byte
+    constants streamed and the budget admits names its chip run (PERF.md §6
+    PR 28, device ms per call resident / streaming)."""
+    from ddlbench_tpu.ops.flash_attention import (RESIDENT_VMEM_BUDGET,
                                                   _use_streaming)
 
-    # benchmarked shapes stay resident: T=8192, dh=64, bf16 = 2 MiB
+    assert RESIDENT_VMEM_BUDGET == 64 * MiB  # half of a v5e core's VMEM
+    # benchmarked shapes stay resident, as before
     assert not _use_streaming(8192, 64, 2, 512, 512, None)
     assert not _use_streaming(1024, 64, 2, 512, 512, None)
-    # long context streams: T=16384, dh=64, bf16 = 4 MiB > 3 MiB
-    assert _use_streaming(16384, 64, 2, 512, 512, None)
-    # wide heads / f32 stream at 8k
-    assert _use_streaming(8192, 128, 2, 512, 512, None)
-    assert _use_streaming(8192, 64, 4, 512, 512, None)
-    # oversized blocks stream once the inner side is nontrivial (the
-    # measured 16.8 MiB Mosaic rejection at (256, 1024, T=8192))
-    assert _use_streaming(8192, 64, 2, 256, 1024, None)
-    assert not _use_streaming(1024, 64, 2, 1024, 1024, None)  # small T fine
+    # K + V of T=16384, dh=64 (19.1 MiB held): forward 9.92 / 18.35 ms
+    assert not _use_streaming(16384, 64, 2, 512, 512, None)
+    # wide heads at 8k (11.3 MiB): 5.89 / 9.61; f32 at 8k (20.1): 3.67 / 8.33
+    assert not _use_streaming(8192, 128, 2, 512, 512, None)
+    assert not _use_streaming(8192, 64, 4, 512, 512, None)
+    # the tile temporaries are in the sum, so oversized blocks need no
+    # clause of their own: (256, 1024) at T=8192 holds 10.8 MiB, 5.18 / 5.94
+    assert not _use_streaming(8192, 64, 2, 256, 1024, None)
+    assert not _use_streaming(1024, 64, 2, 1024, 1024, None)
+    # what passes the budget streams: 67.1 and 68.1 MiB of K + V
+    assert _use_streaming(65536, 64, 2, 512, 512, None)
+    assert _use_streaming(32768, 64, 4, 512, 512, None)
     # explicit override wins both ways
     assert _use_streaming(64, 8, 2, 8, 8, True)
     assert not _use_streaming(1 << 20, 64, 2, 512, 512, False)
-    assert RESIDENT_MAX_BYTES == 3 << 20 and RESIDENT_DQ_MAX_BYTES == 2 << 20
     # the resident kernels put the queries on the lanes: a compiled q block
     # that is no multiple of 128 (T=648 -> 216) streams; the interpreter
     # takes any
@@ -367,37 +376,127 @@ def test_use_streaming_rule():
 
 
 @pytest.mark.parametrize("T,dh,itemsize,fused", [
-    (1024, 64, 2, True),     # the benchmark cell: dQ block 256 KiB
-    (8192, 64, 2, True),     # longctx: 2 MiB, the most that stays resident
-    (16384, 64, 2, False),   # 4 MiB of f32 dQ (and 4 MiB of Q + dO)
-    (32768, 64, 2, False),
-    (8192, 128, 2, False),   # wide heads: 4 MiB of dQ at 8k
+    (1024, 64, 2, True),     # the benchmark cell: 6.5 MiB held
+    (8192, 64, 2, True),     # longctx: 18.8 MiB
+    (16384, 64, 2, True),    # 32.9 MiB: 15.87 / 38.70 ms (chip, PR 28)
+    (32768, 64, 2, True),    # 61.2 MiB, the largest run: 31.07 / 75.73
+    (8192, 128, 2, True),    # wide heads, 21.0 MiB: 9.12 / 20.11
     (4096, 128, 2, True),
-    (8192, 64, 4, False),    # f32 operands: Q + dO alone pass the budget
+    (8192, 64, 4, True),     # f32 operands, 32.9 MiB: 7.18 / 16.30
+    (65536, 64, 2, False),   # 117.7 MiB
+    (32768, 128, 2, False),  # 69.4 MiB
+    (32768, 64, 4, False),   # 111.3 MiB
 ])
-def test_one_pass_backward_where_its_dq_block_fits(T, dh, itemsize, fused):
-    """The backward's one more term in the rule: the one-pass kernel keeps
-    the f32 dQ of the whole row block resident, so it serves a shape only
-    while that fits; the streaming pair takes the rest. Tiles: 512x512 at
-    every length (the chip sweep's winner at T=1024..8192)."""
+def test_one_pass_backward_where_it_fits_the_budget(T, dh, itemsize, fused):
+    """The one-pass kernel keeps Q, dO, the dQ block and its f32 scratch of
+    a whole head resident, so it serves a shape only while their sum fits
+    the budget; the streaming pair takes the rest. Tiles: 512x512 at every
+    length (the chip sweeps' winner at T=1024..8192, dh 64 and 192/128)."""
     from ddlbench_tpu.ops.flash_attention import _use_streaming
 
     bq = bk = _pick_block(T, 512)
     assert bq == 512
-    assert _use_streaming(T, dh, itemsize, bq, bk, None, dq_rows=T) \
+    assert _use_streaming(T, dh, itemsize, bq, bk, None, backward=True) \
         == (not fused)
-    assert _use_streaming(T, dh, itemsize, bq, bk, True, dq_rows=T)
-    assert not _use_streaming(T, dh, itemsize, bq, bk, False, dq_rows=T)
+    assert _use_streaming(T, dh, itemsize, bq, bk, True, backward=True)
+    assert not _use_streaming(T, dh, itemsize, bq, bk, False, backward=True)
 
 
-def test_attnbench_tile_sweep_needs_the_compiled_kernels():
+# (T, dqk, dv, backward) at bf16, 512x512: the two benchmark shapes, summed
+# by hand from the kernels' BlockSpecs. Lanes pad to 128 (64 -> 128, 192 ->
+# 256); every input and output block is double-buffered.
+HAND_SUMS = {
+    # Q, O blocks 2 x 2 x 128 KiB + lse 2 x 2 KiB; K, V 2 x 2 x 256 KiB;
+    # s, p f32 + p bf16 = 10 B x 512^2; O^T accumulator [64, 512] f32
+    (1024, 64, 64, False): (524288 + 4096) + 1048576 + (2621440 + 131072),
+    # K, V, dK, dV blocks 4 x 2 x 128 KiB; Q, dO, dQ 3 x 2 x 256 KiB; lse,
+    # delta rows 2 x 2 x 4 KiB; dQ^T scratch [64, 1024] f32; tiles 12 B x
+    # 512^2, dK + dV carry 2 x 256 KiB, K^T 64 KiB, a dQ^T tile 128 KiB
+    (1024, 64, 64, True): (1048576 + 1572864 + 16384 + 262144
+                           + (3145728 + 524288 + 65536 + 131072)),
+    # kanana2-ep16-train: Q 256 KiB + O 128 KiB blocks, K 2 MiB + V 1 MiB
+    (4096, 192, 128, False): ((786432 + 4096) + 6291456
+                              + (2621440 + 262144)),
+    # K, dK 256 KiB + V, dV 128 KiB blocks; Q, dQ 2 MiB + dO 1 MiB, twice;
+    # rows 64 KiB; scratch [192, 4096] f32 = 3 MiB; tiles 3 MiB, carry
+    # 512 + 256 KiB, K^T [192, 512] bf16, a dQ^T tile [192, 512] f32
+    (4096, 192, 128, True): (1572864 + 10485760 + 65536 + 3145728
+                             + (3145728 + 786432 + 196608 + 393216)),
+}
+
+
+@pytest.mark.parametrize("T,dqk,dv,backward", HAND_SUMS)
+def test_resident_vmem_bytes_against_hand_sums(T, dqk, dv, backward):
+    got = fa._resident_vmem_bytes(T, dqk, dv, 2, 512, 512, backward)
+    assert got == HAND_SUMS[T, dqk, dv, backward]
+    # and against Mosaic's own number (local v5e compile, PR 28: the least
+    # vmem_limit_bytes it accepts at B*H = 128): never under, within a third
+    mosaic = {(1024, 64, 64, False): 2.939, (1024, 64, 64, True): 4.928,
+              (4096, 192, 128, False): 9.021, (4096, 192, 128, True): 18.727}
+    assert 1.0 <= got / MiB / mosaic[T, dqk, dv, backward] < 1.45
+
+
+@pytest.mark.parametrize("T,dqk,dv,itemsize,bq,bk", [
+    (1024, 64, 64, 2, 512, 512), (4096, 192, 128, 2, 512, 512),
+    (8192, 64, 64, 2, 512, 512), (8192, 64, 64, 2, 256, 1024),
+    (8192, 64, 64, 4, 512, 512), (16384, 192, 128, 2, 512, 512),
+    (32768, 64, 64, 2, 512, 512), (768, 64, 64, 2, 384, 384)])
+def test_vmem_limit_covers_what_the_kernel_holds(T, dqk, dv, itemsize, bq,
+                                                 bk):
+    """Every admitted case asks Mosaic for its accounted sum and a quarter
+    more, in both kernels, and stays inside the chip's 128 MiB."""
+    for backward in (False, True):
+        assert not fa._use_streaming(T, dqk, itemsize, bq, bk, None,
+                                     backward=backward, dv=dv)
+        held = fa._resident_vmem_bytes(T, dqk, dv, itemsize, bq, bk,
+                                       backward)
+        assert held <= fa.RESIDENT_VMEM_BUDGET
+        assert held * 1.25 - 1 <= fa._vmem_limit_bytes(held) <= 80 * MiB
+
+
+@pytest.mark.parametrize("shape,dv", [
+    ((4, 32, 4096, 192), 128),   # kanana2-ep16-train
+    ((16, 12, 1024, 64), 64),    # gpt2s-train
+])
+def test_benchmark_shapes_take_the_one_pass_backward(shape, dv):
+    """By tracing alone (nothing is lowered): at both cells' call shapes the
+    compiled path's rule picks the resident forward and ONE backward kernel,
+    whose name holds the substrings benchmarks/kernels/flash_attn.py finds
+    its events by."""
+    import re
+
+    qk = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    v = jax.ShapeDtypeStruct(shape[:3] + (dv,), jnp.bfloat16)
+    jaxpr = str(jax.make_jaxpr(jax.grad(
+        lambda q, k, v: flash_attention(q, k, v).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2)))(qk, qk, v))
+    assert sorted(re.findall(r"name=(flash_attn_\w+)", jaxpr)) == [
+        "flash_attn_dq_dkv", "flash_attn_fwd"]
+    # the forward is the resident one: grid (B*H, T / 512), no inner axis
+    assert f"grid=({shape[0] * shape[1]}, {shape[2] // 512})" in jaxpr
+
+
+@pytest.mark.parametrize("extra", [
+    [], ["--head-dim", "24", "--v-dim", "16", "--design", "resident"]],
+    ids=["equal_widths", "split_widths"])
+def test_attnbench_tile_sweep_needs_the_compiled_kernels(extra, capsys):
     """`attnbench --tiles` reads device time per kernel from a trace: off
     the TPU there is nothing to time, and it says so instead of timing the
-    interpreter."""
+    interpreter. The XLA cell of the same shape runs anywhere, split widths
+    (``--v-dim``) included."""
+    import json
+
     from ddlbench_tpu.tools.attnbench import main
 
+    shape = ["--seq-lens", "64", "--batch", "1", "--heads", "2", "--steps",
+             "1", "--platform", "cpu"] + extra
     with pytest.raises(SystemExit):
-        main(["--seq-lens", "64", "--tiles", "32x32", "--platform", "cpu"])
+        main(shape + ["--tiles", "32x32"])
+    capsys.readouterr()
+    assert main(shape) == 0
+    row = json.loads(capsys.readouterr().out.splitlines()[-1])
+    want = (24, 16) if extra else (64, 64)
+    assert (row["dh"], row["dv"]) == want and row["xla_ms"] > 0
 
 
 # ---------------------------------------------------------------------------
@@ -479,21 +578,59 @@ def test_split_widths_lse_and_its_cotangent():
 
 
 @pytest.mark.parametrize("T,dqk,dv,fwd_streams,bwd_streams", [
-    (4096, 192, 128, False, True),   # kanana2-ep16-train: K + V 2.5 MiB
-                                     # resident; f32 dQ 3 MiB: the pair
-    (2048, 192, 128, False, False),  # dQ 1.5 MiB: the one-pass backward
-    (8192, 192, 128, True, True),    # K + V 5 MiB
-    (4096, 64, 64, False, False),    # equal widths: the rule as it was
+    # kanana2-ep16-train: forward 9.5 MiB held; the one-pass backward 18.9
+    # (chip, PR 28: 14.95 ms a call at B*H 128 against the pair's 30.34)
+    (4096, 192, 128, False, False),
+    (2048, 192, 128, False, False),
+    # 15.5 / 31.9 MiB: forward 6.62 / 11.80 ms, backward 13.56 / 27.46
+    (8192, 192, 128, False, False),
+    # 27.5 / 58.1 MiB: forward 12.68 / 22.68, backward 25.92 / 52.64
+    (16384, 192, 128, False, False),
+    (32768, 192, 128, False, True),  # 51.5 MiB of K + V; 110.3 MiB
+    (65536, 192, 128, True, True),
+    (4096, 64, 64, False, False),    # equal widths: the same rule
     (8192, 64, 64, False, False),
 ])
 def test_streaming_rule_on_split_widths(T, dqk, dv, fwd_streams,
                                         bwd_streams):
-    """The inner side holds one operand of each width (K and V forward, Q
-    and dO backward); the f32 dQ block is q/k wide."""
+    """The resident side holds one operand of each width (K and V forward,
+    Q and dO backward); the dQ block and its f32 scratch are q/k wide."""
     from ddlbench_tpu.ops.flash_attention import _use_streaming
 
     assert _use_streaming(T, dqk, 2, 512, 512, None, dv=dv) == fwd_streams
-    assert _use_streaming(T, dqk, 2, 512, 512, None, dq_rows=T, dv=dv) \
+    assert _use_streaming(T, dqk, 2, 512, 512, None, backward=True, dv=dv) \
         == bwd_streams
     if dqk == dv:  # dv left out means dv = dh: what every old caller gets
         assert _use_streaming(T, dqk, 2, 512, 512, None) == fwd_streams
+
+
+@pytest.mark.parametrize("T,bwd_streams", [(64, False), (128, True)])
+def test_auto_rule_gradients_on_each_side_of_the_budget(T, bwd_streams,
+                                                        monkeypatch):
+    """stream=None at split widths (q/k 24, v 16, 32x32 tiles): with the
+    budget put between what the one-pass backward holds at T=64 and at
+    T=128, the rule's own pick is the one-pass kernel on one side and the
+    pair on the other (the forward stays resident), and either way the
+    gradients are the einsum's."""
+    held = {t: fa._resident_vmem_bytes(t, 24, 16, 4, 32, 32, True)
+            for t in (64, 128)}
+    fwd_held = fa._resident_vmem_bytes(128, 24, 16, 4, 32, 32, False)
+    assert fwd_held <= held[64] < held[128]
+    monkeypatch.setattr(fa, "RESIDENT_VMEM_BUDGET", held[64])
+    ks = jax.random.split(jax.random.key(3), 4)
+    q, k = _rand((1, 2, T, 24), ks[0]), _rand((1, 2, T, 24), ks[1])
+    v, g = _rand((1, 2, T, 16), ks[2]), _rand((1, 2, T, 16), ks[3])
+    auto = lambda q, k, v: flash_attention(q, k, v, 0, 0, 0, 32, 32, True)
+    names = _kernel_names(jax.grad(lambda *a: jnp.sum(auto(*a) * g),
+                                   argnums=(0, 1, 2)), q, k, v)
+    assert names == ({"flash_attn_fwd", "flash_attn_dq", "flash_attn_dkv"}
+                     if bwd_streams else
+                     {"flash_attn_fwd", "flash_attn_dq_dkv"})
+    with jax.default_matmul_precision("highest"):
+        ref, ref_vjp = jax.vjp(_einsum_attention, q, k, v)
+        got, got_vjp = jax.vjp(auto, q, k, v)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   atol=2e-5)
+        for a, b, name in zip(got_vjp(g), ref_vjp(g), "qkv"):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=5e-5, err_msg=f"d{name}")

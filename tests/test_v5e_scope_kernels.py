@@ -111,23 +111,33 @@ def test_flash_attention_keeps_its_names_inside_the_scopes(one_chip,
     assert all(any(e in n for e in EVENTS) for n in found)
 
 
-# (B, H, T, dh): what the shape rule picks has to pass Mosaic (scoped VMEM
-# above all) at a realistic batch*heads — a refusal found without a chip call
-@pytest.mark.parametrize("shape,kernels", [
-    ((2, 12, 8192, 64), ["flash_attn_dq_dkv", "flash_attn_fwd"]),
-    ((4, 12, 4096, 64), ["flash_attn_dq_dkv", "flash_attn_fwd"]),
-    ((1, 12, 32768, 64), ["flash_attn_dkv", "flash_attn_dq",
-                          "flash_attn_fwd"]),
+# (B, H, T, q/k width), v width: what the shape rule picks has to pass Mosaic
+# (scoped VMEM above all: the resident kernels ask for what they hold and a
+# quarter more) at a realistic batch*heads — a refusal found without a chip
+# call
+ONE_PASS = ["flash_attn_dq_dkv", "flash_attn_fwd"]
+PAIR = ["flash_attn_dkv", "flash_attn_dq", "flash_attn_fwd"]
+
+
+@pytest.mark.parametrize("shape,dv,kernels", [
+    ((2, 12, 8192, 64), 64, ONE_PASS),
+    ((4, 12, 4096, 64), 64, ONE_PASS),
+    ((4, 32, 4096, 192), 128, ONE_PASS),   # kanana2-ep16-train
+    ((1, 8, 16384, 192), 128, ONE_PASS),   # 58.1 MiB held: near the budget
+    ((1, 12, 32768, 64), 64, ONE_PASS),    # 61.2 MiB
+    ((1, 8, 32768, 192), 128, PAIR),       # resident forward (51.5 MiB)
+    ((1, 4, 65536, 64), 64, PAIR),
 ])
 def test_long_sequences_compile_with_the_kernels_the_rule_picks(
-        one_chip, as_on_tpu, shape, kernels):
+        one_chip, as_on_tpu, shape, dv, kernels):
     from ddlbench_tpu.ops.flash_attention import flash_attention
 
     def loss(q, k, v):
         return flash_attention(q, k, v).astype(jnp.float32).sum()
 
     found = _mosaic_calls(jax.grad(loss, argnums=(0, 1, 2)), one_chip,
-                          *[(shape, jnp.bfloat16)] * 3)
+                          *[(shape, jnp.bfloat16)] * 2,
+                          (shape[:3] + (dv,), jnp.bfloat16))
     # outside the program's scopes the instruction is jvp_<name>_.N; the
     # op_name ends [transpose(]jvp(<name>)[)]/pallas_call
     assert sorted(re.search(r"(\w+)\)*/pallas_call$", op).group(1)
@@ -157,7 +167,7 @@ def test_the_latent_attention_block_compiles_with_named_kernels(one_chip,
                                                                 as_on_tpu):
     """kanana-2-30b-a3b's expert block at its published widths and the
     cell's batch (4 x 4096): the flash forward resident and the backward
-    the streaming pair at q/k 192, v 128, all three under the block's
+    the one-pass kernel at q/k 192, v 128, both under the block's
     ``attn``; the grouped products' Pallas kernels under ``experts``, with
     names the benchmark's moe_gmm.EVENTS find."""
     from ddlbench_tpu.models import kanana2
@@ -180,7 +190,7 @@ def test_the_latent_attention_block_compiles_with_named_kernels(one_chip,
            or a.shape == (dims.d_model, 128) else jnp.bfloat16)
           for a in leaves])
     flash = sorted(n.split(".")[0] for n in found if "flash" in n)
-    assert flash == ["flash_attn_dkv", "flash_attn_dq", "flash_attn_fwd"]
+    assert flash == ["flash_attn_dq_dkv", "flash_attn_fwd"]
     for n, op in found.items():
         if "flash" in n:
             assert "(block2)" in op and "/attn/" in op, op
