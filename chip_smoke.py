@@ -114,8 +114,7 @@ def phase_kernels():
     import jax.numpy as jnp
     import numpy as np
 
-    from ddlbench_tpu.models.transformer import (causal_attention,
-                                                 set_attention_backend)
+    from ddlbench_tpu.models.transformer import causal_attention
     from ddlbench_tpu.ops import paged_decode as pd
     from ddlbench_tpu.ops.flash_attention import flash_attention
     from ddlbench_tpu.ops.fused_xent import fused_linear_xent
@@ -163,11 +162,7 @@ def phase_kernels():
         return jax.jit(f)
 
     def ref_attn(q, k, v):
-        set_attention_backend("xla")
-        try:
-            return causal_attention(q, k, v)
-        finally:
-            set_attention_backend("auto")
+        return causal_attention(q, k, v, backend="xla")
 
     for T, Hh, stream in ((1024, 12, None), (8192, 2, None), (8192, 2, True)):
         q, k, v = (jax.random.normal(key(10 + i), (1, Hh, T, 64),

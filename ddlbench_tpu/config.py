@@ -83,8 +83,10 @@ DATASETS: Mapping[str, DatasetSpec] = {
 
 STRATEGIES = ("single", "dp", "gpipe", "pipedream", "sp", "tp", "fsdp", "ep")
 
-# "auto" = Pallas flash-attention kernel on TPU, jnp elsewhere. Single source
-# for the CLI choices, validate(), and models.transformer.set_attention_backend.
+# "auto" = the Pallas flash-attention kernel where ops/flash_attention.
+# flash_dispatch says it applies, the einsum elsewhere; "flash"/"xla" force
+# one. The CLI choices and validate() read this; make_strategy hands
+# RunConfig.attention_backend to zoo.get_model, whose builders pass it down.
 ATTENTION_BACKENDS = ("auto", "flash", "xla")
 
 # Per-framework default batch sizes from the reference harness

@@ -164,8 +164,9 @@ def _swiglu_init(key, d, f):
             "w_down": _dense_init(k3, f, d)}
 
 
-def mla_sublayer(p, x, dims: Dims):
-    """x + MLA(RMSNorm(x)), causal, positions 0..T-1. The projections are
+def mla_sublayer(p, x, dims: Dims, backend: str = "auto"):
+    """x + MLA(RMSNorm(x)), causal, positions 0..T-1; ``backend`` is the
+    attention backend (transformer.causal_attention). The projections are
     einsums straight into and out of the kernels' [B, H, T, width] layout
     (W_kvb's columns split into their k_nope and v halves as weights, not
     as activations: a [.., H, 256] activation cut in two cost a relayout
@@ -193,7 +194,7 @@ def mla_sublayer(p, x, dims: Dims):
         q = jnp.concatenate(
             [q[..., :nope], rope_interleaved(q[..., nope:], pos,
                                              dims.rope_theta)], axis=-1)
-        o = causal_attention(q, k, v)  # [B, H, T, dv]
+        o = causal_attention(q, k, v, backend=backend)  # [B, H, T, dv]
         return x + jnp.einsum("bhtv,hvd->btd", o, p["wo"].astype(
             x.dtype).reshape(H, dv, d))
 
@@ -349,7 +350,7 @@ def embed_tokens(name: str, vocab: int, d_model: int) -> Layer:
     return Layer(name, init, apply)
 
 
-def dense_block(name: str, dims: Dims) -> Layer:
+def dense_block(name: str, dims: Dims, attention_backend: str) -> Layer:
     def init(key, in_shape):
         T, d = in_shape
         assert d == dims.d_model
@@ -358,7 +359,7 @@ def dense_block(name: str, dims: Dims) -> Layer:
         return p, {}, (T, d)
 
     def apply(p, s, x, train):
-        x = mla_sublayer(p, x, dims)
+        x = mla_sublayer(p, x, dims, attention_backend)
         h = rms_norm(p["ln2"], x, dims.rms_eps)
         with scopes.scope(scopes.MLP):
             return x + swiglu(p, h), s
@@ -366,7 +367,8 @@ def dense_block(name: str, dims: Dims) -> Layer:
     return Layer(name, init, apply)
 
 
-def expert_block(name: str, dims: Dims, held: Tuple[int, int]) -> Layer:
+def expert_block(name: str, dims: Dims, held: Tuple[int, int],
+                 attention_backend: str) -> Layer:
     """Its state holds the step's routing counters (``moe/held_slots``,
     ``moe/load_max_over_mean``): outputs of the apply, so they leave a
     rematerialized layer like BatchNorm's statistics do."""
@@ -393,7 +395,7 @@ def expert_block(name: str, dims: Dims, held: Tuple[int, int]) -> Layer:
 
     def apply(p, s, x, train):
         B, T, d = x.shape
-        x = mla_sublayer(p, x, dims)
+        x = mla_sublayer(p, x, dims, attention_backend)
         h = rms_norm(p["ln2"], x, dims.rms_eps)
         with scopes.scope(scopes.ROUTE):
             y, counters = routed_experts(p, h.reshape(B * T, d), dims, held)
@@ -436,13 +438,15 @@ def lm_head(name: str, vocab: int, dims: Dims) -> Layer:
                  fused_eval=fused_eval)
 
 
-def build(arch: str, in_shape, vocab: int) -> LayerModel:
+def build(arch: str, in_shape, vocab: int,
+          attention_backend: str = "auto") -> LayerModel:
     dims, n_layers, held = parse_arch(arch)
     layers: List[Layer] = [embed_tokens("embed", vocab, dims.d_model)]
     for i in range(n_layers):
         name = f"block{i + 1}"
-        layers.append(dense_block(name, dims) if i < dims.first_dense
-                      else expert_block(name, dims, held))
+        layers.append(dense_block(name, dims, attention_backend)
+                      if i < dims.first_dense
+                      else expert_block(name, dims, held, attention_backend))
     layers.append(lm_head("lm_head", vocab, dims))
     # one chip's share of an expert-parallel group, without its exchange:
     # no strategy across chips is brought up

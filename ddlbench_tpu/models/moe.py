@@ -175,7 +175,8 @@ def moe_mlp(p, x, capacity_factor: float):
 
 
 def moe_block(name: str, d_model: int, n_heads: int, n_experts: int,
-              mlp_ratio: int = 4, capacity_factor: float = 1.25) -> Layer:
+              mlp_ratio: int = 4, capacity_factor: float = 1.25, *,
+              attention_backend: str) -> Layer:
     """Pre-LN transformer block whose MLP is a switch-routed expert bank."""
     d_ff = mlp_ratio * d_model
 
@@ -203,7 +204,7 @@ def moe_block(name: str, d_model: int, n_heads: int, n_experts: int,
         return p, {}, (T, dm)
 
     def apply(p, s, x, train):
-        x = attention_sublayer(p, x, n_heads)
+        x = attention_sublayer(p, x, n_heads, backend=attention_backend)
         h = layer_norm(p["ln2"], x)
         x = x + moe_mlp(
             {"gate": p["gate"], "experts": p["experts"]}, h, capacity_factor
@@ -226,7 +227,8 @@ def moe_block(name: str, d_model: int, n_heads: int, n_experts: int,
 
     def prefill(p, s, cache, x, start):
         _reject_ep()
-        x, cache = attn_prefill_op(p, x, cache, n_heads, 0, start)
+        x, cache = attn_prefill_op(p, x, cache, n_heads, 0, start,
+                                   attention_backend)
         return _moe_ffn(p, x), cache
 
     def _moe_ffn_token(p, x):
@@ -266,7 +268,8 @@ def moe_block(name: str, d_model: int, n_heads: int, n_experts: int,
 
     def paged_prefill(p, s, cache, x, start):
         _reject_ep()
-        x, cache = attn_paged_prefill_op(p, x, cache, n_heads, 0, start)
+        x, cache = attn_paged_prefill_op(p, x, cache, n_heads, 0, start,
+                                         attention_backend)
         return _moe_ffn(p, x), cache
 
     def paged_decode(p, s, cache, x, pos):
@@ -282,7 +285,8 @@ def moe_block(name: str, d_model: int, n_heads: int, n_experts: int,
 
 
 def build_transformer_moe(arch: str, in_shape, vocab: int,
-                          capacity_factor: float = 1.25) -> LayerModel:
+                          capacity_factor: float = 1.25,
+                          attention_backend: str = "auto") -> LayerModel:
     """MoE variant of the transformer LM: dense and MoE blocks alternate."""
     from ddlbench_tpu.models.transformer import transformer_block
 
@@ -294,10 +298,13 @@ def build_transformer_moe(arch: str, in_shape, vocab: int,
             layers.append(moe_block(
                 f"moe_block{i + 1}", cfgv["d_model"], cfgv["n_heads"],
                 cfgv["n_experts"], capacity_factor=capacity_factor,
+                attention_backend=attention_backend,
             ))
         else:
             layers.append(
-                transformer_block(f"block{i + 1}", cfgv["d_model"], cfgv["n_heads"])
+                transformer_block(f"block{i + 1}", cfgv["d_model"],
+                                  cfgv["n_heads"],
+                                  attention_backend=attention_backend)
             )
     layers.append(lm_head("lm_head", vocab))
     return LayerModel(arch, layers, tuple(in_shape), vocab, input_kind="tokens")

@@ -93,7 +93,8 @@ def seq2seq_embed(name: str, vocab: int, d_model: int, max_len: int,
     return Layer(name, init, apply, decode=decode)
 
 
-def build_seq2seq(arch: str, in_shape, vocab: int, src_len: int) -> LayerModel:
+def build_seq2seq(arch: str, in_shape, vocab: int, src_len: int,
+                  attention_backend: str = "auto") -> LayerModel:
     cfgv = _VARIANTS[arch]
     T = in_shape[0]
     if not 0 < src_len < T:
@@ -104,7 +105,8 @@ def build_seq2seq(arch: str, in_shape, vocab: int, src_len: int) -> LayerModel:
     for i in range(cfgv["n_layers"]):
         layers.append(
             transformer_block(f"block{i + 1}", cfgv["d_model"],
-                              cfgv["n_heads"], prefix_len=src_len)
+                              cfgv["n_heads"], prefix_len=src_len,
+                              attention_backend=attention_backend)
         )
     layers.append(lm_head("lm_head", vocab))
     return LayerModel(arch, layers, tuple(in_shape), vocab,

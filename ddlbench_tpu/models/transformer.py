@@ -190,99 +190,8 @@ def embed(name: str, vocab: int, d_model: int, max_len: int) -> Layer:
                                 serve_verify))
 
 
-# Attention backend: "auto" uses the Pallas flash kernel on TPU and the jnp
-# path elsewhere; "flash"/"xla" force one (flash off-TPU runs the kernel in
-# interpret mode — tests only, it is slow).
-_ATTENTION_BACKEND = ["auto"]
-
-# "auto" takes the flash kernel only past this (local) sequence length.
-# Measured on v5e (bf16, H=8, dh=64, fwd+bwd, 50-step avg): XLA's fused
-# attention wins short sequences — flash/XLA ratio 0.64x at B=64 T=256
-# (prefix-LM), 0.94-0.97x at T=128-512 — and flash wins past the crossover:
-# 1.24x at T=768, 1.55x at T=1024, 2.06x at T=2048, 3.4x end-to-end at
-# T=8192 (where un-remat'd XLA attention cannot fit one chip at all). At
-# short T the kernel's grid/stream overhead exceeds its HBM savings; the
-# quadratic score tensor is small enough for XLA to keep in registers/VMEM
-# through its own fusions. (perf_runs + PERF.md "auto dispatch", round 3.)
-FLASH_AUTO_MIN_SEQ = 640  # base threshold; see flash_pays_off for the table
-
-
-def flash_pays_off(seq_len: int, batch: int, prefix_len: int) -> bool:
-    """Shape-aware flash-vs-XLA decision table (the "auto" backend policy).
-    Head widths are no input: every crossing below was measured at dh = 64;
-    wider heads (q/k 192, v 128 at T = 4096 ran on the chip, PERF.md PR 27)
-    sit far past it.
-
-    The table below encodes the reproducible signals of
-    perf_runs/attn_crossover.json (one 2026-07-31 sweep, before PR 1) and
-    PERF.md's auto-dispatch section; tools/attnbench.py re-measures it and
-    tools/attnpolicy.py reduces a sweep to a recommendation:
-
-    * T >= 768: flash wins monotonically (1.24x @ 768 -> 2.06x @ 2048,
-      B=16 causal) — flash.
-    * T < 640: XLA's fused attention wins (0.82-0.96x) — xla.
-    * [640, 768) is the noise band (sub-2ms cells swung run to run in that
-      sweep); flash only for the plain causal shape that measured above
-      1.0 there (prefix == 0, B <= 32).
-    * Prefix-LM at large batch is the strongest XLA signal (0.61x at
-      B=64, T=256 — the synthmt shape): with prefix > 0 and B >= 64,
-      require T >= 1024 until the b64pfx sweep shows the crossover.
-    """
-    if seq_len >= 1024:
-        return True
-    if prefix_len > 0 and batch >= 64:
-        return False
-    if seq_len >= 768:
-        return True
-    if seq_len >= FLASH_AUTO_MIN_SEQ:
-        return prefix_len == 0 and batch <= 32
-    return False
-
-
-def set_attention_backend(backend: str) -> None:
-    from ddlbench_tpu.config import ATTENTION_BACKENDS
-
-    if backend not in ATTENTION_BACKENDS:
-        raise ValueError(f"unknown attention backend {backend!r}")
-    _ATTENTION_BACKEND[0] = backend
-
-
-def _flash_dispatch(*operands, prefix_len: int = 0):
-    """Return (use_flash, interpret) for the current backend setting.
-
-    "auto" picks the Pallas kernel only where it partitions correctly:
-    pallas_call has no GSPMD partitioning rule, so under a multi-device jit
-    with sharded operands XLA would gather them to every device (ADVICE r1).
-    Inside shard_map (nonempty varying-manual-axes type on an operand) and on
-    a single device the kernel shapes are already local — flash is safe."""
-    from ddlbench_tpu.distributed import is_tpu_backend
-
-    mode = _ATTENTION_BACKEND[0]
-    if mode == "xla":
-        return False, False
-    on_tpu = is_tpu_backend()
-    if mode == "flash":
-        return True, not on_tpu
-    if not on_tpu:
-        return False, False
-    from ddlbench_tpu.ops.util import pallas_partitions_safely
-
-    # compiled kernels need 8-aligned sequence blocks (flash_attention.py
-    # _pick_block); odd sequence lengths take the XLA einsum path
-    if any(o.ndim >= 3 and o.shape[2] % 8 for o in operands):
-        return False, False
-    # shape-aware crossover (flash_pays_off table): local sequence length,
-    # batch, and the prefix-LM flag all shift the flash/XLA winner; ring
-    # attention applies the same rule to its per-shard block length
-    T = max(o.shape[2] for o in operands if o.ndim >= 3)
-    B = max(o.shape[0] for o in operands if o.ndim >= 3)
-    if not flash_pays_off(T, B, prefix_len):
-        return False, False
-    return pallas_partitions_safely(*operands), False
-
-
 def causal_attention(q, k, v, q_offset: int = 0, k_offset: int = 0,
-                     prefix_len: int = 0):
+                     prefix_len: int = 0, backend: str = "auto"):
     """Masked attention for blocks of a causal (or prefix-LM) sequence.
 
     q: [B, H, Tq, Dh]; k: [B, H, Tk, Dh]; v: [B, H, Tk, Dv] (Dv = Dh but
@@ -291,15 +200,17 @@ def causal_attention(q, k, v, q_offset: int = 0, k_offset: int = 0,
     position so the same primitive serves full attention (offsets 0) and ring
     attention over sequence shards (parallel/sp.py). ``prefix_len`` > 0 adds
     the prefix-LM rule: key positions < prefix_len are visible to every query
-    (the seq2seq source segment, models/seq2seq.py). On TPU this dispatches
-    to the fused Pallas flash-attention kernel (ops/flash_attention.py) —
-    which implements the same prefix rule with block-level skipping — unless
-    set_attention_backend("xla") was called.
+    (the seq2seq source segment, models/seq2seq.py). ``backend``
+    ("auto" | "flash" | "xla", config.ATTENTION_BACKENDS) goes to
+    ops/flash_attention.flash_dispatch, which says whether this call takes
+    the fused Pallas kernel — same prefix rule, with block-level skipping —
+    or the einsum below.
     """
-    use_flash, interpret = _flash_dispatch(q, k, v, prefix_len=prefix_len)
-    if use_flash:
-        from ddlbench_tpu.ops.flash_attention import flash_attention
+    from ddlbench_tpu.ops.flash_attention import (flash_attention,
+                                                  flash_dispatch)
 
+    use_flash, interpret = flash_dispatch(backend, q, k, v, prefix_len)
+    if use_flash:
         return flash_attention(q, k, v, q_offset, k_offset, prefix_len,
                                interpret=interpret)
     dh = q.shape[-1]
@@ -318,7 +229,8 @@ def causal_attention(q, k, v, q_offset: int = 0, k_offset: int = 0,
     return jnp.einsum("bhqk,bhkd->bhqd", e / jnp.maximum(z, 1e-20), v)
 
 
-def ring_attention(q, k, v, axis: str, prefix_len: int = 0):
+def ring_attention(q, k, v, axis: str, prefix_len: int = 0,
+                   backend: str = "auto"):
     """Causal (or prefix-LM) attention over a sequence sharded on mesh axis
     `axis`.
 
@@ -338,7 +250,9 @@ def ring_attention(q, k, v, axis: str, prefix_len: int = 0):
     block is data-dependent on the shard index, which the kernel's static
     offsets can't express).
     """
-    use_flash, interpret = _flash_dispatch(q, k, v, prefix_len=prefix_len)
+    from ddlbench_tpu.ops.flash_attention import flash_dispatch
+
+    use_flash, interpret = flash_dispatch(backend, q, k, v, prefix_len)
     if use_flash and prefix_len == 0:
         return _ring_attention_flash(q, k, v, axis, interpret)
     n = lax.psum(1, axis)
@@ -430,14 +344,16 @@ def _ring_attention_flash(q, k, v, axis: str, interpret: bool):
     return o.astype(q.dtype)
 
 
-def attention_sublayer(p, x, n_heads: int, prefix_len: int = 0):
+def attention_sublayer(p, x, n_heads: int, prefix_len: int = 0,
+                       backend: str = "auto"):
     """Pre-LN self-attention sublayer with residual: reads p["ln1"],
     p["wqkv"], p["wo"]. Dispatches to ring attention over the active
     sequence_parallel axis, so every block (dense and MoE) gets the
     sequence-parallel path from one implementation; under an active
     tensor_parallel context the shard computes its local head group and the
     output projection psums over the TP axis. ``prefix_len`` selects the
-    prefix-LM mask (seq2seq) on both paths."""
+    prefix-LM mask (seq2seq) on both paths, ``backend`` the attention
+    backend its builder was given (causal_attention)."""
     B, T, d = x.shape
     dh = d // n_heads
     # Sliced-vs-replicated is decided by the PARAMS the shard actually
@@ -466,13 +382,13 @@ def attention_sublayer(p, x, n_heads: int, prefix_len: int = 0):
         axis = _seq_axis()
         if axis is None:
             o = causal_attention(heads(q), heads(k), heads(v),
-                                 prefix_len=prefix_len)
+                                 prefix_len=prefix_len, backend=backend)
         else:
             assert tp is None, (
                 "ring (sequence-parallel) attention composed with tensor "
                 "parallelism is not supported")
             o = ring_attention(heads(q), heads(k), heads(v), axis,
-                               prefix_len=prefix_len)
+                               prefix_len=prefix_len, backend=backend)
         o = o.transpose(0, 2, 1, 3).reshape(B, T, n_local * dh)
         proj = o @ p["wo"].astype(x.dtype)
         if sliced:
@@ -481,9 +397,12 @@ def attention_sublayer(p, x, n_heads: int, prefix_len: int = 0):
 
 
 def transformer_block(name: str, d_model: int, n_heads: int, mlp_ratio: int = 4,
-                      prefix_len: int = 0) -> Layer:
+                      prefix_len: int = 0, *,
+                      attention_backend: str) -> Layer:
     """Pre-LN block; ``prefix_len`` > 0 switches the attention to the
-    prefix-LM mask (the seq2seq workload, models/seq2seq.py)."""
+    prefix-LM mask (the seq2seq workload, models/seq2seq.py);
+    ``attention_backend`` is what its full-sequence attention calls pass to
+    causal_attention / ring_attention."""
     dh = d_model // n_heads
 
     def init(key, in_shape):
@@ -503,7 +422,7 @@ def transformer_block(name: str, d_model: int, n_heads: int, mlp_ratio: int = 4,
         return p, {}, (T, d)
 
     def apply(p, s, x, train):
-        x = attention_sublayer(p, x, n_heads, prefix_len)
+        x = attention_sublayer(p, x, n_heads, prefix_len, attention_backend)
         return mlp(p, x), s
 
     def mlp(p, x):
@@ -521,7 +440,8 @@ def transformer_block(name: str, d_model: int, n_heads: int, mlp_ratio: int = 4,
             return x + proj + p["b2"].astype(x.dtype)
 
     def prefill(p, s, cache, x, start):
-        x, cache = attn_prefill_op(p, x, cache, n_heads, prefix_len, start)
+        x, cache = attn_prefill_op(p, x, cache, n_heads, prefix_len, start,
+                                   attention_backend)
         return mlp(p, x), cache
 
     def decode(p, s, cache, x, pos):
@@ -530,7 +450,7 @@ def transformer_block(name: str, d_model: int, n_heads: int, mlp_ratio: int = 4,
 
     def paged_prefill(p, s, cache, x, start):
         x, cache = attn_paged_prefill_op(p, x, cache, n_heads, prefix_len,
-                                         start)
+                                         start, attention_backend)
         return mlp(p, x), cache
 
     def paged_decode(p, s, cache, x, pos):
@@ -596,7 +516,8 @@ def _qkv_heads(p, x, n_heads: int):
             for t in (q, k, v)]
 
 
-def attn_prefill_op(p, x, cache, n_heads: int, prefix_len: int, start: int):
+def attn_prefill_op(p, x, cache, n_heads: int, prefix_len: int, start: int,
+                    backend: str = "auto"):
     """Attention sublayer (incl. residual) over a whole prompt, recording K/V.
 
     Attention runs only within the segment, so the prompt must start the
@@ -611,7 +532,8 @@ def attn_prefill_op(p, x, cache, n_heads: int, prefix_len: int, start: int):
         "v": lax.dynamic_update_slice_in_dim(
             cache["v"], v.astype(cache["v"].dtype), start, axis=2),
     }
-    o = causal_attention(q, k, v, start, start, prefix_len=prefix_len)
+    o = causal_attention(q, k, v, start, start, prefix_len=prefix_len,
+                         backend=backend)
     x = x + o.transpose(0, 2, 1, 3).reshape(B, T, d) @ p["wo"].astype(x.dtype)
     return x, cache
 
@@ -626,7 +548,7 @@ def attn_paged_cache_init(n_heads: int, dh: int):
 
 
 def attn_paged_prefill_op(p, x, cache, n_heads: int, prefix_len: int,
-                          start: int):
+                          start: int, backend: str = "auto"):
     """attn_prefill_op with the K/V recorded into pages ([rows, T, H, dh]
     page layout; ops/paged_decode.py)."""
     from ddlbench_tpu.ops.paged_decode import paged_prefill_write
@@ -636,7 +558,8 @@ def attn_paged_prefill_op(p, x, cache, n_heads: int, prefix_len: int,
     q, k, v = _qkv_heads(p, x, n_heads)
     cache = paged_prefill_write(cache, k.transpose(0, 2, 1, 3),
                                 v.transpose(0, 2, 1, 3))
-    o = causal_attention(q, k, v, start, start, prefix_len=prefix_len)
+    o = causal_attention(q, k, v, start, start, prefix_len=prefix_len,
+                         backend=backend)
     x = x + o.transpose(0, 2, 1, 3).reshape(B, T, d) @ p["wo"].astype(x.dtype)
     return x, cache
 
@@ -821,13 +744,15 @@ def lm_head(name: str, vocab: int) -> Layer:
                  fused_eval=fused_eval)
 
 
-def build_transformer(arch: str, in_shape, vocab: int) -> LayerModel:
+def build_transformer(arch: str, in_shape, vocab: int,
+                      attention_backend: str = "auto") -> LayerModel:
     cfgv = _VARIANTS[arch]
     T = in_shape[0]
     layers: List[Layer] = [embed("embed", vocab, cfgv["d_model"], T)]
     for i in range(cfgv["n_layers"]):
         layers.append(
-            transformer_block(f"block{i + 1}", cfgv["d_model"], cfgv["n_heads"])
+            transformer_block(f"block{i + 1}", cfgv["d_model"], cfgv["n_heads"],
+                              attention_backend=attention_backend)
         )
     layers.append(lm_head("lm_head", vocab))
     return LayerModel(arch, layers, tuple(in_shape), vocab, input_kind="tokens")

@@ -50,7 +50,12 @@ def collects_aux_loss(arch: str) -> bool:
 
 
 def get_model(arch: str, dataset: str | DatasetSpec,
-              moe_capacity_factor: float = 1.25) -> LayerModel:
+              moe_capacity_factor: float = 1.25,
+              attention_backend: str = "auto") -> LayerModel:
+    """``attention_backend`` (config.ATTENTION_BACKENDS) goes to the builders
+    of the archs that attend, as ``moe_capacity_factor`` goes to the
+    Switch-routed ones: a model is built for one backend and carries it in
+    its layers' closures."""
     spec = dataset if isinstance(dataset, DatasetSpec) else DATASETS[dataset]
     if arch.startswith("seq2seq"):
         if spec.kind != "seq2seq":
@@ -64,13 +69,14 @@ def get_model(arch: str, dataset: str | DatasetSpec,
         from ddlbench_tpu.models.seq2seq import build_seq2seq
 
         return build_seq2seq(arch, spec.image_size, spec.num_classes,
-                             spec.src_len)
+                             spec.src_len, attention_backend)
     from ddlbench_tpu.models import kanana2
 
     if kanana2.is_family(arch):
         if spec.kind != "tokens":
             raise ValueError(f"{arch} requires a token dataset, got {spec.name}")
-        return kanana2.build(arch, spec.image_size, spec.num_classes)
+        return kanana2.build(arch, spec.image_size, spec.num_classes,
+                             attention_backend)
     if arch.startswith("transformer"):
         if spec.kind != "tokens":
             raise ValueError(f"{arch} requires a token dataset, got {spec.name}")
@@ -80,10 +86,12 @@ def get_model(arch: str, dataset: str | DatasetSpec,
             return build_transformer_moe(
                 arch, spec.image_size, spec.num_classes,
                 capacity_factor=moe_capacity_factor,
+                attention_backend=attention_backend,
             )
         from ddlbench_tpu.models.transformer import build_transformer
 
-        return build_transformer(arch, spec.image_size, spec.num_classes)
+        return build_transformer(arch, spec.image_size, spec.num_classes,
+                                 attention_backend)
     if spec.kind != "image":
         raise ValueError(f"{arch} requires an image dataset, got {spec.name}")
     if arch.startswith(("inception", "nasnet")):

@@ -183,8 +183,8 @@ def _pick_block(t: int, preferred: int, interpret: bool = False) -> int:
     """Largest divisor of t <= preferred tiling the sequence dimension; on
     real TPU it must also be a multiple of 8 (Mosaic sublane tile —
     ops/util.py:pick_block). Sequence lengths with no aligned divisor get a
-    clear error instead of a raw Mosaic one; the attention dispatch
-    (models/transformer.py:_flash_dispatch) avoids flash for such shapes."""
+    clear error instead of a raw Mosaic one; flash_dispatch below keeps
+    "auto" away from such shapes."""
     from ddlbench_tpu.ops.util import pick_block
 
     b = pick_block(t, preferred, 1 if interpret else 8)
@@ -194,6 +194,65 @@ def _pick_block(t: int, preferred: int, interpret: bool = False) -> int:
             f"multiple of 8; pad the sequence or use the XLA attention "
             f"backend")
     return b
+
+
+# Where "auto" stops preferring XLA's fused attention to the kernel. The
+# thresholds below come from ONE sweep (perf_runs/attn_crossover.json,
+# 2026-07-31, before PR 1: v5e, bf16, H=8, dh=64, forward + backward) of
+# kernels PR 25 replaced; nothing has re-measured them. They are kept because
+# no cell sits within a factor of 1.5 of any of them (gpt2s-train T 1024,
+# kanana2-ep16-train T 4096), and are to be re-measured with the first cell
+# that does (ROADMAP.md S8; tools/attnbench.py sweeps, tools/attnpolicy.py
+# reduces a sweep to a table).
+FLASH_AUTO_MIN_SEQ = 640
+
+
+def flash_pays_off(seq_len: int, batch: int, prefix_len: int) -> bool:
+    """The "auto" backend's flash-or-XLA table over (local sequence length,
+    batch, prefix-LM or not). Head widths are no input: every crossing was
+    read at dh = 64, and the wider heads that ran on the chip (q/k 192,
+    v 128 at T = 4096, PERF.md PR 27-28) sit far past it. What that sweep
+    read, flash time over XLA time inverted:
+
+    * T >= 768: flash ahead and growing (1.24x at 768, 2.06x at 2048, B=16
+      causal) — flash.
+    * T < 640: XLA ahead (0.82-0.96x) — xla.
+    * [640, 768): sub-2 ms cells that swung run to run; flash only for the
+      plain causal shape that read above 1.0 there (prefix == 0, B <= 32).
+    * Prefix-LM at a large batch read 0.61x at B=64, T=256 (the synthmt
+      shape): with prefix > 0 and B >= 64, flash from T >= 1024 only.
+    """
+    if seq_len >= 1024:
+        return True
+    if prefix_len > 0 and batch >= 64:
+        return False
+    if seq_len >= 768:
+        return True
+    if seq_len >= FLASH_AUTO_MIN_SEQ:
+        return prefix_len == 0 and batch <= 32
+    return False
+
+
+def flash_dispatch(backend: str, q, k, v, prefix_len: int = 0):
+    """(use the kernel, interpreted) for one attention call: the only place
+    that decides it, from its arguments, the platform and the operands'
+    shapes and vma. ``backend``: "xla" never, "flash" always (interpreted
+    off a TPU — tests only, it is slow), "auto" on a TPU where the kernel
+    partitions safely (ops/util.takes_pallas), the sequence blocks are
+    8-aligned for Mosaic (_pick_block) and flash_pays_off says so for the
+    LOCAL shapes (ring attention passes its per-shard blocks)."""
+    from ddlbench_tpu.distributed import is_tpu_backend
+    from ddlbench_tpu.ops.util import takes_pallas
+
+    if not takes_pallas(backend, "flash", q, k, v):
+        return False, False
+    if backend == "flash":
+        return True, not is_tpu_backend()
+    if any(o.shape[2] % 8 for o in (q, k, v)):
+        return False, False
+    T = max(o.shape[2] for o in (q, k, v))
+    B = max(o.shape[0] for o in (q, k, v))
+    return flash_pays_off(T, B, prefix_len), False
 
 
 def _causal_kv_bound(q_hi_pos, k_offset: int, block_k: int, num_k: int,

@@ -38,7 +38,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 
 from ddlbench_tpu.ops.util import pallas_out_struct as _pl_out
-
+from ddlbench_tpu.ops.util import takes_pallas
 
 from ddlbench_tpu.compat import pcast_varying as _pcast_to
 from ddlbench_tpu.compat import vma_of as _vma
@@ -69,30 +69,6 @@ def _row_stats(z, labels, smoothing: float):
         obj = nll
     correct = (jnp.argmax(z, axis=-1) == labels) & mask
     return nll, obj, correct, mask, lse
-
-
-
-
-def _use_pallas(backend: str, *operands) -> bool:
-    """Kernel dispatch. "auto" picks the Pallas kernels only where they
-    partition correctly: pallas_call has no GSPMD partitioning rule, so under
-    a plain multi-device jit with sharded operands XLA would gather/replicate
-    them (inverting the fusion's memory win for dp/tp/fsdp). Inside shard_map
-    the operands are per-shard (nonempty varying-manual-axes type) and on a
-    single device there is nothing to partition — Pallas is safe in both.
-    jit-based multi-device strategies get the chunked-XLA scan, which GSPMD
-    partitions natively."""
-    if backend == "xla":
-        return False
-    if backend == "pallas":
-        return True
-    from ddlbench_tpu.distributed import is_tpu_backend
-
-    if not is_tpu_backend():
-        return False
-    from ddlbench_tpu.ops.util import pallas_partitions_safely
-
-    return pallas_partitions_safely(*operands)
 
 
 def _pallas_feasible(h, w, backend: str, interpret: bool) -> bool:
@@ -139,7 +115,9 @@ def fused_linear_xent(h, w, labels, smoothing: float = 0.0,
     h: [N, D] hidden rows (compute dtype); w: [D, V] head weights (compute
     dtype); labels: [N] int (-1 = masked). Objective uses ``smoothing``; ce is
     the unsmoothed CE (the headline metric). Gradients flow to h and w from
-    BOTH sums. ``backend``: "auto" = Pallas kernels on TPU, chunked-XLA scan
+    BOTH sums. ``backend``: "auto" = Pallas kernels on TPU where they
+    partition safely (ops/util.takes_pallas; dp/tp/fsdp's plain jits get the
+    chunked-XLA scan, which GSPMD partitions natively), chunked-XLA scan
     elsewhere; "pallas"/"xla" force one (pallas off-TPU needs interpret=True).
     """
     out, _ = _fxent_fwd(h, w, labels, smoothing, row_chunk, backend, interpret)
@@ -148,7 +126,7 @@ def fused_linear_xent(h, w, labels, smoothing: float = 0.0,
 
 def _fxent_fwd(h, w, labels, smoothing: float, row_chunk: int, backend: str,
                interpret: bool):
-    if (_use_pallas(backend, h, w, labels)
+    if (takes_pallas(backend, "pallas", h, w, labels)
             and _pallas_feasible(h, w, backend, interpret)):
         return _fxent_fwd_pallas(h, w, labels, smoothing, interpret)
     N = h.shape[0]
@@ -183,7 +161,7 @@ def _fxent_bwd(smoothing: float, row_chunk: int, backend: str,
     go, gce, _ = cots  # correct-count cotangent is float0 — ignored
     go = go.astype(jnp.float32)
     gce = gce.astype(jnp.float32)
-    if (_use_pallas(backend, h, w, labels)
+    if (takes_pallas(backend, "pallas", h, w, labels)
             and _pallas_feasible(h, w, backend, interpret)):
         dh, dw = _fxent_bwd_pallas(h, w, labels, lses, go, gce, smoothing,
                                    interpret)

@@ -2,24 +2,32 @@
 
 from __future__ import annotations
 
-import contextlib
-
 import jax
 
-_IN_SHARDED_JIT = [False]
 
+class gspmd_jit:
+    """Trace-time marker: the enclosed trace is a plain multi-device jit
+    whose operands GSPMD shards (the dp, tp and fsdp step bodies enter it;
+    ``single`` and every shard_map body never do). It carries no value:
+    pallas_partitions_safely below, its one reader, asks only whether the
+    trace is inside one. Kernel dispatch happens at trace time, so the
+    answer is captured into the traced program. Same idiom as
+    models/layers.axis_context and paged_decode.live_pages: a class-level
+    stack, re-entrant, popped on exit."""
 
-@contextlib.contextmanager
-def sharded_jit_tracing():
-    """Mark the enclosed trace as a plain multi-device jit over GSPMD-sharded
-    operands (dp/tp/fsdp strategies wrap their step bodies in this). Pallas
-    dispatch happens at trace time, so the flag is captured into the traced
-    program."""
-    _IN_SHARDED_JIT[0] = True
-    try:
-        yield
-    finally:
-        _IN_SHARDED_JIT[0] = False
+    _stack: list = []
+
+    def __enter__(self):
+        gspmd_jit._stack.append(self)
+        return self
+
+    def __exit__(self, *exc):
+        gspmd_jit._stack.pop()
+        return False
+
+    @staticmethod
+    def active() -> bool:
+        return bool(gspmd_jit._stack)
 
 
 def pallas_partitions_safely(*operands) -> bool:
@@ -27,16 +35,32 @@ def pallas_partitions_safely(*operands) -> bool:
     instead of being gathered: pallas_call has no GSPMD partitioning rule, so
     under a plain multi-device jit with sharded operands XLA replicates them
     onto every device (ADVICE r1). Inside shard_map the operands are already
-    per-shard (nonempty varying-manual-axes type), and outside a sharded jit
-    (single-device programs, whatever the host's chip count) there is nothing
-    to partition — both are safe. The shared policy behind the "auto"
-    backends of ops/fused_xent.py and the flash-attention dispatch
-    (models/transformer.py)."""
+    per-shard (nonempty varying-manual-axes type), and outside a gspmd_jit
+    trace (single-device programs, whatever the host's chip count) there is
+    nothing to partition — both are safe."""
     from ddlbench_tpu.compat import vma_of
 
     if any(vma_of(o) for o in operands):
         return True
-    return not _IN_SHARDED_JIT[0]
+    return not gspmd_jit.active()
+
+
+def takes_pallas(backend: str, forced: str, *operands) -> bool:
+    """The rule every kernel's ``backend`` argument shares: ``"xla"`` never
+    takes the Pallas kernel, ``forced`` (the kernel's own name for it:
+    ``"flash"``, ``"pallas"``) always does, and ``"auto"`` takes it on a TPU
+    where it partitions safely. A kernel adds what only it knows on top
+    (flash_attention.flash_dispatch: alignment and the crossover)."""
+    if backend == "xla":
+        return False
+    if backend == forced:
+        return True
+    if backend != "auto":
+        raise ValueError(f"unknown backend {backend!r}; known: auto, "
+                         f"{forced}, xla")
+    from ddlbench_tpu.distributed import is_tpu_backend
+
+    return is_tpu_backend() and pallas_partitions_safely(*operands)
 
 
 def pick_block(t: int, preferred: int, unit: int = 1):

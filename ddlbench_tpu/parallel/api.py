@@ -256,11 +256,9 @@ def make_strategy(cfg: RunConfig, devices: Optional[Sequence[jax.Device]] = None
 
         cfg = resolve_auto_plan(cfg, input_time_ms=input_time_ms)
     cfg.validate()
-    from ddlbench_tpu.models.transformer import set_attention_backend
-
-    set_attention_backend(cfg.attention_backend)
     model = get_model(cfg.arch, cfg.benchmark,
-                      moe_capacity_factor=cfg.moe_capacity_factor)
+                      moe_capacity_factor=cfg.moe_capacity_factor,
+                      attention_backend=cfg.attention_backend)
 
     stage_bounds = None
     if cfg.auto_partition and cfg.strategy in ("gpipe", "pipedream"):

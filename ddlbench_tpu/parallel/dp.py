@@ -100,6 +100,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ddlbench_tpu.config import RunConfig
 from ddlbench_tpu.models.layers import LayerModel, init_model
+from ddlbench_tpu.ops.util import gspmd_jit
 from ddlbench_tpu.parallel.common import make_optimizer
 from ddlbench_tpu.parallel.single import TrainState
 from ddlbench_tpu.telemetry import scopes
@@ -146,28 +147,24 @@ class DPStrategy:
             # inside the scan — the carry needs a concrete sharding — so the
             # wire cost is K allreduces; the explicit sharded engine below
             # halves that with K reduce-scatters.)
-            from ddlbench_tpu.ops.util import sharded_jit_tracing
             from ddlbench_tpu.parallel.common import loss_and_grads
 
-            if guard is None:
-                with sharded_jit_tracing():  # auto-Pallas unsafe under GSPMD
-                    ce, (correct, valid), new_state, grads = loss_and_grads(
-                        model, cfg, ts.params, ts.model_state, x, y,
-                        self.compute_dtype, smooth)
-                params, opt = opt_update(ts.params, grads, ts.opt, lr)
-            else:
-                # Stability guard (same shape as the single engine): scaled
-                # objective, fused health pair, in-step skip-select. GSPMD
-                # shards the norm reduction like any other reduction.
+            # Stability guard (same shape as the single engine): scaled
+            # objective, fused health pair, in-step skip-select. GSPMD
+            # shards the norm reduction like any other reduction.
+            smul, opt_in = None, ts.opt
+            if guard is not None:
                 opt_in, gstate = guard.split_opt(ts.opt)
                 smul = guard.smul(gstate, lr)
-                with sharded_jit_tracing():
-                    ce, (correct, valid), new_state, grads = loss_and_grads(
-                        model, cfg, ts.params, ts.model_state, x, y,
-                        self.compute_dtype, smooth, obj_scale=smul)
+            with gspmd_jit():  # auto-Pallas unsafe under GSPMD
+                ce, (correct, valid), new_state, grads = loss_and_grads(
+                    model, cfg, ts.params, ts.model_state, x, y,
+                    self.compute_dtype, smooth, obj_scale=smul)
+            if guard is not None:
                 grads = guard.unscale(grads, smul)
                 finite, gnorm = guard.health(ce, grads)
-                params, opt = opt_update(ts.params, grads, opt_in, lr)
+            params, opt = opt_update(ts.params, grads, opt_in, lr)
+            if guard is not None:
                 params, new_state, opt, gm = guard.commit(
                     finite, gnorm, gstate, (params, new_state, opt),
                     (ts.params, ts.model_state, opt_in))
@@ -181,10 +178,9 @@ class DPStrategy:
             return TrainState(params, new_state, opt), metrics
 
         def eval_step(ts: TrainState, x, y):
-            from ddlbench_tpu.ops.util import sharded_jit_tracing
             from ddlbench_tpu.parallel.common import eval_metrics
 
-            with sharded_jit_tracing():
+            with gspmd_jit():
                 return eval_metrics(model, cfg, self._params_pytree(ts),
                                     ts.model_state, x, y, self.compute_dtype)
 
@@ -469,11 +465,8 @@ class DPStrategy:
                 xk, yk = xy
 
                 def f(p):
-                    from ddlbench_tpu.ops.util import sharded_jit_tracing
-
-                    with sharded_jit_tracing():
-                        obj_sum, ce_sum, correct, valid, _norm, new_st = \
-                            self._local_loss_sums(p, st, xk, yk, smooth)
+                    obj_sum, ce_sum, correct, valid, _norm, new_st = \
+                        self._local_loss_sums(p, st, xk, yk, smooth)
                     obj = obj_sum / denom
                     if smul is not None:  # guard: loss scale / poison
                         obj = obj * smul
@@ -505,13 +498,10 @@ class DPStrategy:
             — and divides the reduced sum by the total weight at the end.
             Wire-wise this still halves replicated accum's cost: K
             reduce-scatters vs K full allreduces."""
-            from ddlbench_tpu.ops.util import sharded_jit_tracing
-
             if K == 1:
                 def loss_fn(p):
-                    with sharded_jit_tracing():
-                        obj_sum, ce_sum, correct, valid, norm, new_state = \
-                            self._local_loss_sums(p, state, x, y, smooth)
+                    obj_sum, ce_sum, correct, valid, norm, new_state = \
+                        self._local_loss_sums(p, state, x, y, smooth)
                     denom = jnp.maximum(
                         1.0, lax.psum(norm, "data").astype(jnp.float32))
                     obj = psum_keepgrad(obj_sum, "data") / denom
@@ -542,9 +532,8 @@ class DPStrategy:
                 yk = lax.dynamic_index_in_dim(ys, k, axis=1, keepdims=False)
 
                 def f(p):
-                    with sharded_jit_tracing():
-                        obj_sum, ce_sum, correct, valid, norm, new_st = \
-                            self._local_loss_sums(p, st, xk, yk, smooth)
+                    obj_sum, ce_sum, correct, valid, norm, new_st = \
+                        self._local_loss_sums(p, st, xk, yk, smooth)
                     denom = jnp.maximum(
                         1.0, lax.psum(norm, "data").astype(jnp.float32))
                     obj = psum_keepgrad(obj_sum, "data") / denom

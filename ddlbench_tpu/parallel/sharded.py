@@ -27,6 +27,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ddlbench_tpu.config import RunConfig
 from ddlbench_tpu.models.layers import LayerModel, init_model
+from ddlbench_tpu.ops.util import gspmd_jit
 from ddlbench_tpu.parallel.common import make_optimizer, opt_state_sharding
 from ddlbench_tpu.parallel.single import TrainState
 
@@ -81,7 +82,6 @@ class _ShardedParamStrategy:
         smooth = cfg.resolved_label_smoothing()
 
         def train_step(ts: TrainState, x, y, lr):
-            from ddlbench_tpu.ops.util import sharded_jit_tracing
             from ddlbench_tpu.parallel.common import loss_and_grads
 
             # Stability guard (ROADMAP item 4): tp/fsdp run the SAME
@@ -94,7 +94,7 @@ class _ShardedParamStrategy:
             if guard is not None:
                 opt_in, gstate = guard.split_opt(ts.opt)
                 smul = guard.smul(gstate, lr)
-            with sharded_jit_tracing():  # auto-Pallas unsafe under GSPMD
+            with gspmd_jit():  # auto-Pallas unsafe under GSPMD
                 ce, (correct, valid), new_state, grads = loss_and_grads(
                     model, cfg, ts.params, ts.model_state, x, y,
                     self.compute_dtype, smooth, obj_scale=smul)
@@ -117,10 +117,9 @@ class _ShardedParamStrategy:
             return TrainState(params, new_state, opt), metrics
 
         def eval_step(ts: TrainState, x, y):
-            from ddlbench_tpu.ops.util import sharded_jit_tracing
             from ddlbench_tpu.parallel.common import eval_metrics
 
-            with sharded_jit_tracing():
+            with gspmd_jit():
                 return eval_metrics(model, cfg, ts.params, ts.model_state,
                                     x, y, self.compute_dtype)
 

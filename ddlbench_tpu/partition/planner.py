@@ -666,7 +666,8 @@ def plan_for_config(cfg: RunConfig, input_time_ms: float = 0.0
             f"solves the split at a fixed strategy)")
     mb, chunks = cfg.resolved_batches()
     model = get_model(cfg.arch, cfg.benchmark,
-                      moe_capacity_factor=cfg.moe_capacity_factor)
+                      moe_capacity_factor=cfg.moe_capacity_factor,
+                      attention_backend=cfg.attention_backend)
     graph = profile_model(model, mb, mode=cfg.profile_mode, hw=cfg.hardware,
                           input_time_ms=input_time_ms)
     graph = fold_input_node(graph)

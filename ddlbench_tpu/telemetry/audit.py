@@ -12,7 +12,8 @@ and replica groups. This module walks those out into a per-program
 * :func:`collective_ledger` — parse the optimized HLO into
   :class:`CollectiveOp` records (kind, dtype, shape, per-participant
   payload bytes, replica groups incl. the iota ``[G,g]<=[N]`` form,
-  ring-model wire bytes).
+  ring-model wire bytes), one per OPERAND: an instruction the compiler
+  combined out of several collectives gives several records.
 * :func:`program_manifest` — flops / bytes-accessed / memory components
   / the ledger, with graceful degradation: on backends where
   cost_analysis or memory_analysis are unavailable the fields are
@@ -67,7 +68,7 @@ _KINDS = ("all-reduce", "reduce-scatter", "all-gather",
 
 _OP_RE = re.compile(
     r"^\s*(?:ROOT\s+)?%?(?P<name>[\w.\-]+)\s*=\s*"
-    r"(?P<shape>\([^)]*\)|[\w\[\],{}:]+)\s+"
+    r"(?P<shape>\((?:[^()]|\([^()]*\))*\)|[\w\[\],{}:]+)\s+"
     r"(?P<kind>" + "|".join(_KINDS) + r")(?P<phase>-start|-done)?\(")
 
 _SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
@@ -94,6 +95,44 @@ class CollectiveOp:
     n_pairs: int = 0              # collective-permute only
     axes: Optional[str] = None    # mesh axes resolved from replica groups
     wire_bytes: float = 0.0       # ring-model wire for one execution
+
+
+def _tuple_parts(tok: str) -> List[str]:
+    """Top-level components of an HLO tuple shape ``(a, b, (c, d))``; the
+    token itself when it is no tuple. Commas inside ``[..]``, ``{..}`` and
+    nested ``(..)`` do not split."""
+    tok = tok.strip()
+    if not tok.startswith("("):
+        return [tok]
+    parts, depth, start = [], 0, 1
+    for i, ch in enumerate(tok[1:-1], 1):
+        if ch in "([{":
+            depth += 1
+        elif ch in ")]}":
+            depth -= 1
+        elif ch == "," and depth == 0:
+            parts.append(tok[start:i].strip())
+            start = i + 1
+    parts.append(tok[start:-1].strip())
+    return [p for p in parts if p]
+
+
+def _operand_shapes(tok: str, kind: str, phase: Optional[str]) -> List[str]:
+    """One result-shape token per OPERAND of a collective instruction.
+
+    XLA's combiner passes merge collectives that share replica groups into
+    ONE instruction with a tuple result — ``(f32[1,1040], f32[1,1], f32[],
+    f32[]) all-reduce(%grads, %state, %loss, %norm)`` is a gradient row, a
+    state row and two metric psums (the jax 0.9.0 CPU compiler does this to
+    every step audited in tests/test_audit.py) — and the analytic side
+    prices operands, so the ledger holds one record a component. The async
+    starts wrap their results: ``all-gather-start`` is (operands, results),
+    ``collective-permute-start`` (operand, result, context words..); an
+    ``all-reduce-start`` has its all-reduce's shape."""
+    parts = _tuple_parts(tok)
+    wrapped = phase == "-start" and kind in ("all-gather",
+                                             "collective-permute")
+    return _tuple_parts(parts[1]) if wrapped and len(parts) >= 2 else parts
 
 
 def _parse_shape(tok: str) -> Tuple[str, Tuple[int, ...], int, float]:
@@ -196,8 +235,7 @@ def collective_ledger(hlo_text: str,
         m = _OP_RE.match(line)
         if not m or m.group("phase") == "-done":
             continue
-        kind = m.group("kind")
-        dtype, shape, elems, payload = _parse_shape(m.group("shape"))
+        name, kind = m.group("name"), m.group("kind")
         groups = _parse_replica_groups(line)
         n_pairs = 0
         if kind == "collective-permute":
@@ -206,17 +244,21 @@ def collective_ledger(hlo_text: str,
                 n_pairs = pm.group(1).count("{")
         n_groups = len(groups) if groups else 1
         g = len(groups[0]) if groups else 1
-        op = CollectiveOp(
-            name=m.group("name"), kind=kind, dtype=dtype, shape=shape,
-            elements=elems, payload_bytes=payload,
-            # rank-0 single elements are the metric/scale psums; a
-            # rank>=1 single element (a padded [1] state row) is payload
-            scalar=(elems <= 1 and not shape), groups=groups,
-            n_groups=n_groups, group_size=g, n_pairs=n_pairs,
-            axes=resolve_axes(groups, mesh_axes or ()),
-        )
-        op.wire_bytes = _ring_wire(kind, payload, g, n_groups, n_pairs)
-        ops.append(op)
+        axes = resolve_axes(groups, mesh_axes or ())
+        parts = _operand_shapes(m.group("shape"), kind, m.group("phase"))
+        for i, part in enumerate(parts):
+            dtype, shape, elems, payload = _parse_shape(part)
+            ops.append(CollectiveOp(
+                # a combined instruction's operands: name[0], name[1], ..
+                name=name if len(parts) == 1 else f"{name}[{i}]",
+                kind=kind, dtype=dtype, shape=shape,
+                elements=elems, payload_bytes=payload,
+                # rank-0 single elements are the metric/scale psums; a
+                # rank>=1 single element (a padded [1] state row) is payload
+                scalar=(elems <= 1 and not shape), groups=groups,
+                n_groups=n_groups, group_size=g, n_pairs=n_pairs, axes=axes,
+                wire_bytes=_ring_wire(kind, payload, g, n_groups, n_pairs),
+            ))
     return ops
 
 

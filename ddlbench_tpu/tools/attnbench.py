@@ -1,7 +1,7 @@
 """Attention-kernel microbenchmark: flash (Pallas) vs XLA across sequence
 lengths.
 
-The evidence behind ``FLASH_AUTO_MIN_SEQ`` (models/transformer.py): one
+What re-measures ``flash_pays_off`` (ops/flash_attention.py): one
 fwd+bwd jitted step per (backend, T) cell over the bare attention primitive,
 so the crossover where the kernel's grid/stream overhead stops paying for
 its HBM savings can be re-measured when shapes, kernels, or hardware change.
@@ -32,6 +32,7 @@ Usage:
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import sys
 import time
@@ -105,8 +106,7 @@ def main(argv=None) -> int:
     import jax.numpy as jnp
 
     from ddlbench_tpu.distributed import enable_compilation_cache, is_tpu_backend
-    from ddlbench_tpu.models.transformer import (causal_attention,
-                                                 set_attention_backend)
+    from ddlbench_tpu.models.transformer import causal_attention
 
     enable_compilation_cache()
     backends = ("flash", "xla") if is_tpu_backend() else ("xla",)
@@ -136,8 +136,9 @@ def main(argv=None) -> int:
         q, k, v = (jax.random.normal(kk, (args.batch, args.heads, T, d), dtype)
                    for kk, d in zip(ks, (args.head_dim, args.head_dim, v_dim)))
 
-        def loss(q, k, v):
-            out = causal_attention(q, k, v, prefix_len=args.prefix)
+        def loss(q, k, v, backend):
+            out = causal_attention(q, k, v, prefix_len=args.prefix,
+                                   backend=backend)
             return jnp.sum(out.astype(jnp.float32))
 
         row = {"T": T, "B": args.batch, "H": args.heads,
@@ -155,12 +156,9 @@ def main(argv=None) -> int:
         if tiles:
             continue
         for mode in backends:
-            set_attention_backend(mode)
-            try:
-                g = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))
-                row[f"{mode}_ms"] = round(timed(g, q, k, v) * 1e3, 3)
-            finally:
-                set_attention_backend("auto")
+            g = jax.jit(jax.value_and_grad(
+                functools.partial(loss, backend=mode), argnums=(0, 1, 2)))
+            row[f"{mode}_ms"] = round(timed(g, q, k, v) * 1e3, 3)
         if "flash_ms" in row and "xla_ms" in row:
             row["flash_speedup"] = round(row["xla_ms"] / row["flash_ms"], 3)
         print(json.dumps(row), flush=True)

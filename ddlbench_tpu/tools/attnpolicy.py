@@ -3,7 +3,7 @@
 Reads every ``perf_runs/attnsweep_*.json`` (and legacy attn_crossover.json)
 produced by tools/attnbench.py median-of-N sweeps, computes the measured
 winner per (T, B, prefix) cell, and reports where
-``models.transformer.flash_pays_off`` disagrees: policy from medians,
+``ops.flash_attention.flash_pays_off`` disagrees: policy from medians,
 re-checkable whenever a sweep is re-run on the chip.
 
 One JSON document on stdout:
@@ -61,7 +61,7 @@ def main(argv=None) -> int:
                    help="speedups within 1 +- margin count as ties")
     args = p.parse_args(argv)
 
-    from ddlbench_tpu.models.transformer import flash_pays_off
+    from ddlbench_tpu.ops.flash_attention import flash_pays_off
 
     raw = load_cells(args.dir)
     # aggregate repeated measurements of the same (T, B, prefix) cell to the

@@ -161,21 +161,24 @@ def main(argv=None) -> int:
             cfg = cfg.replace(micro_batch_size=max(l, mb // l * l))
         return cfg
 
+    def model_of(cfg):
+        return get_model(cfg.arch, cfg.benchmark,
+                         attention_backend=cfg.attention_backend)
+
     def run_point(which):
         """Measure one engine point in this process; returns its record."""
         if which == "uneven":
             cfg = base_cfg(uneven)
             cfg.validate()
             return _measure("hetero", uneven, cfg,
-                            hetero_cls(get_model(cfg.arch, cfg.benchmark),
-                                       cfg),
+                            hetero_cls(model_of(cfg), cfg),
                             args.steps, args.warmup)
         cfg = base_cfg(plan)
         cfg.validate()
         if which == "hetero":
             # conveyor engine constructed directly — the strategy factory
             # rewrites uniform plans onto the grid (api.py:122-134)
-            strat = hetero_cls(get_model(cfg.arch, cfg.benchmark), cfg)
+            strat = hetero_cls(model_of(cfg), cfg)
         else:
             # the same topology on the regular 2-D mesh (make_strategy's pick)
             strat = make_strategy(cfg)

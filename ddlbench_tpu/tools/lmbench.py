@@ -61,7 +61,6 @@ def main(argv=None) -> int:
     from ddlbench_tpu.config import DATASETS, RunConfig
     from ddlbench_tpu.data.synthetic import make_synthetic
     from ddlbench_tpu.distributed import is_tpu_backend
-    from ddlbench_tpu.models.transformer import set_attention_backend
     from ddlbench_tpu.parallel.api import make_strategy
 
     token_benchmarks = sorted(
@@ -77,7 +76,7 @@ def main(argv=None) -> int:
         "xla+fused": ("xla", True),
         "xla+logits": ("xla", False),
         # what a default run actually gets: the length-based dispatch
-        # (models/transformer.py FLASH_AUTO_MIN_SEQ) + fused head. Not in
+        # (ops/flash_attention.py flash_dispatch) + fused head. Not in
         # the default sweep (it duplicates one of the forced cells); use
         # --configs auto to check the dispatch picks the winning backend.
         "auto": ("auto", True),
@@ -168,9 +167,6 @@ def main(argv=None) -> int:
                     "detail": str(e).splitlines()[0][:200],
                     **prov,
                 }), flush=True)
-            finally:
-                # reset the backend override for the next config
-                set_attention_backend("auto")
     return 0 if ok else 1
 
 

@@ -1,10 +1,11 @@
-"""The "auto" attention backend's dispatch policy (models/transformer.py).
+"""Where an attention call takes the Pallas kernel: flash_dispatch
+(ops/flash_attention.py), the one rule, over ops/util.takes_pallas.
 
-Pure shape/flag logic — testable off-TPU by monkeypatching the backend
-probe. Pins the round-3 measured rule: on TPU, auto takes the Pallas flash
-kernel only for 8-aligned local sequences past FLASH_AUTO_MIN_SEQ (XLA's
-fused attention wins shorter ones; see PERF.md "auto dispatch"), and the
-explicit "flash"/"xla" overrides bypass the heuristics entirely.
+Pure logic over its arguments, the platform and the operands' shapes —
+testable off-TPU by monkeypatching the backend probe. Pins the rule: on TPU,
+"auto" takes the flash kernel only for 8-aligned local sequences past
+FLASH_AUTO_MIN_SEQ (the table's provenance stands beside it), and a forced
+"flash"/"xla" bypasses the heuristics entirely.
 """
 
 import jax.numpy as jnp
@@ -12,6 +13,8 @@ import numpy as np
 import pytest
 
 from ddlbench_tpu.models import transformer as tfm
+from ddlbench_tpu.ops.flash_attention import (FLASH_AUTO_MIN_SEQ,
+                                              flash_dispatch, flash_pays_off)
 
 
 @pytest.fixture
@@ -27,23 +30,23 @@ def _qkv(T, B=2, H=4, dh=8):
 
 
 def test_auto_short_seq_takes_xla(on_tpu):
-    use_flash, _ = tfm._flash_dispatch(*_qkv(256))
+    use_flash, _ = flash_dispatch("auto", *_qkv(256))
     assert not use_flash
 
 
 def test_auto_long_seq_takes_flash(on_tpu):
-    use_flash, interpret = tfm._flash_dispatch(*_qkv(1024))
+    use_flash, interpret = flash_dispatch("auto", *_qkv(1024))
     assert use_flash and not interpret
 
 
 def test_auto_threshold_boundary(on_tpu):
-    T = tfm.FLASH_AUTO_MIN_SEQ
-    assert tfm._flash_dispatch(*_qkv(T))[0]
-    assert not tfm._flash_dispatch(*_qkv(T - 8))[0]
+    T = FLASH_AUTO_MIN_SEQ
+    assert flash_dispatch("auto", *_qkv(T))[0]
+    assert not flash_dispatch("auto", *_qkv(T - 8))[0]
 
 
 def test_auto_unaligned_seq_takes_xla(on_tpu):
-    use_flash, _ = tfm._flash_dispatch(*_qkv(1027))
+    use_flash, _ = flash_dispatch("auto", *_qkv(1027))
     assert not use_flash
 
 
@@ -51,17 +54,17 @@ def test_policy_prefix_lm_large_batch_takes_xla(on_tpu):
     """The strongest measured XLA signal: prefix-LM at B=64 (synthmt shape,
     0.61x flash) stays on XLA through the noise band; plain causal at the
     same length flips to flash."""
-    assert not tfm._flash_dispatch(*_qkv(768, B=64), prefix_len=128)[0]
-    assert tfm._flash_dispatch(*_qkv(768, B=64), prefix_len=0)[0]
+    assert not flash_dispatch("auto", *_qkv(768, B=64), prefix_len=128)[0]
+    assert flash_dispatch("auto", *_qkv(768, B=64), prefix_len=0)[0]
     # but 1024+ is a flash win in every measured configuration
-    assert tfm._flash_dispatch(*_qkv(1024, B=64), prefix_len=128)[0]
+    assert flash_dispatch("auto", *_qkv(1024, B=64), prefix_len=128)[0]
 
 
 def test_policy_noise_band_is_conservative(on_tpu):
     """[640, 768): flash only for the plain causal small-batch shape."""
-    assert tfm._flash_dispatch(*_qkv(640, B=16))[0]
-    assert not tfm._flash_dispatch(*_qkv(640, B=64))[0]
-    assert not tfm._flash_dispatch(*_qkv(640, B=16), prefix_len=64)[0]
+    assert flash_dispatch("auto", *_qkv(640, B=16))[0]
+    assert not flash_dispatch("auto", *_qkv(640, B=64))[0]
+    assert not flash_dispatch("auto", *_qkv(640, B=16), prefix_len=64)[0]
 
 
 def test_policy_table_is_monotone_in_seq_len():
@@ -69,30 +72,22 @@ def test_policy_table_is_monotone_in_seq_len():
     back OFF — the table must stay a crossover, not an interval."""
     for B in (2, 16, 32, 64, 128):
         for prefix in (0, 128):
-            decisions = [tfm.flash_pays_off(T, B, prefix)
+            decisions = [flash_pays_off(T, B, prefix)
                          for T in (128, 256, 512, 640, 768, 1024, 2048, 8192)]
             assert decisions == sorted(decisions), (B, prefix, decisions)
 
 
 def test_forced_flash_ignores_threshold(on_tpu):
-    tfm.set_attention_backend("flash")
-    try:
-        use_flash, interpret = tfm._flash_dispatch(*_qkv(256))
-        assert use_flash and not interpret
-    finally:
-        tfm.set_attention_backend("auto")
+    use_flash, interpret = flash_dispatch("flash", *_qkv(256))
+    assert use_flash and not interpret
 
 
 def test_forced_xla_ignores_length(on_tpu):
-    tfm.set_attention_backend("xla")
-    try:
-        assert not tfm._flash_dispatch(*_qkv(4096))[0]
-    finally:
-        tfm.set_attention_backend("auto")
+    assert not flash_dispatch("xla", *_qkv(4096))[0]
 
 
 def test_off_tpu_auto_never_flash():
-    assert not tfm._flash_dispatch(*_qkv(4096))[0]
+    assert not flash_dispatch("auto", *_qkv(4096))[0]
 
 
 def test_values_match_across_backends():

@@ -91,6 +91,45 @@ def test_ledger_parses_kinds_groups_and_wire():
     assert ops["cps"].n_pairs == 2  # async start; done not double-counted
 
 
+_G8 = "replica_groups={{0,1,2,3,4,5,6,7}}, to_apply=%add"
+
+
+@pytest.mark.parametrize("line,want", [
+    # what XLA's combiner makes of a gradient row, a [1,1] state row and two
+    # metric psums that share replica groups (gpipe's step on the jax 0.9.0
+    # CPU compiler): four operands, two of them scalars
+    ("%ar = (f32[1,1040]{1,0}, f32[1,1]{1,0}, f32[], f32[]) "
+     "all-reduce(%a, %b, %c, %d), " + _G8,
+     [("ar[0]", 4160.0, False), ("ar[1]", 4.0, False),
+      ("ar[2]", 4.0, True), ("ar[3]", 4.0, True)]),
+    ("%ars = (bf16[8]{0}, f32[]) all-reduce-start(%a, %b), " + _G8,
+     [("ars[0]", 16.0, False), ("ars[1]", 4.0, True)]),
+    # an async all-gather's shape is (operand, result): the result counts
+    ("%ags = (f32[16]{0}, f32[128]{0}) all-gather-start(%a), "
+     "replica_groups={{0,1,2,3,4,5,6,7}}, dimensions={0}",
+     [("ags", 512.0, False)]),
+    ("%agc = ((f32[16]{0}, bf16[8]{0}), (f32[128]{0}, bf16[64]{0})) "
+     "all-gather-start(%a, %b), replica_groups={{0,1,2,3,4,5,6,7}}, "
+     "dimensions={0}",
+     [("agc[0]", 512.0, False), ("agc[1]", 128.0, False)]),
+    # (operand, result, two context words)
+    ("%cps = (f32[2,8]{1,0}, f32[2,8]{1,0}, u32[], u32[]) "
+     "collective-permute-start(%a), source_target_pairs={{0,1},{1,2}}",
+     [("cps", 64.0, False)]),
+])
+def test_ledger_counts_a_combined_collective_per_operand(line, want):
+    ops = collective_ledger("HloModule probe\n  " + line + "\n")
+    assert [(op.name, op.payload_bytes, op.scalar) for op in ops] == want
+    # the wire model is linear in the payload: the operands' wire sums to
+    # what the instruction moves
+    kind, g, pairs = ops[0].kind, ops[0].group_size, ops[0].n_pairs
+    total = sum(op.payload_bytes for op in ops)
+    assert sum(op.wire_bytes for op in ops) == pytest.approx({
+        "all-reduce": 2.0 * (g - 1) / g * total,
+        "all-gather": (g - 1) / g * total,
+        "collective-permute": total * pairs}[kind])
+
+
 def test_resolve_axes_against_mesh_partitions():
     mesh_axes = [("data", 2), ("model", 4)]
     assert resolve_axes([[0, 1, 2, 3], [4, 5, 6, 7]], mesh_axes) == "model"

@@ -15,24 +15,18 @@ pytestmark = pytest.mark.slow  # compile-heavy (see conftest --runslow)
 import ddlbench_tpu.models.seq2seq as s2s
 import ddlbench_tpu.models.decode as dec
 from ddlbench_tpu.models.layers import apply_model, init_model
-from ddlbench_tpu.models.transformer import set_attention_backend
 
 TINY = dict(d_model=32, n_layers=2, n_heads=4)
 s2s._VARIANTS["seq2seq_t"] = TINY
 T_TOTAL, SRC, VOCAB = 16, 8, 64
 
 
-@pytest.fixture(autouse=True)
-def _xla_backend():
-    # the full-forward reference path and cached path must share numerics
-    set_attention_backend("xla")
-    yield
-    set_attention_backend("auto")
-
-
+# the full-forward reference path and cached path must share numerics: both
+# models are built on the einsum attention
 @pytest.fixture(scope="module")
 def mt_model():
-    model = s2s.build_seq2seq("seq2seq_t", (T_TOTAL,), VOCAB, SRC)
+    model = s2s.build_seq2seq("seq2seq_t", (T_TOTAL,), VOCAB, SRC,
+                              attention_backend="xla")
     params, state, _ = init_model(model, jax.random.key(0))
     return model, params, state
 
@@ -42,7 +36,8 @@ def lm_model():
     from ddlbench_tpu.models.transformer import build_transformer, _VARIANTS
 
     _VARIANTS["transformer_t"] = TINY
-    model = build_transformer("transformer_t", (T_TOTAL,), VOCAB)
+    model = build_transformer("transformer_t", (T_TOTAL,), VOCAB,
+                              attention_backend="xla")
     params, state, _ = init_model(model, jax.random.key(3))
     return model, params, state
 

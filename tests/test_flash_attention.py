@@ -5,34 +5,26 @@ comparisons force highest matmul precision; tolerances then reflect only the
 kernel's own (f32-accumulated) arithmetic.
 """
 
-import importlib
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ddlbench_tpu.models.transformer import (
-    causal_attention,
-    set_attention_backend,
-)
+from ddlbench_tpu.models import transformer
+from ddlbench_tpu.ops import flash_attention as fa
 from ddlbench_tpu.ops.flash_attention import (_pick_block, flash_attention,
                                               flash_attention_lse)
 
-# the module itself: ddlbench_tpu.ops re-exports the function under its name
-fa = importlib.import_module("ddlbench_tpu.ops.flash_attention")
+
+# the reference of every comparison below: the einsum path, wherever this runs
+causal_attention = functools.partial(transformer.causal_attention,
+                                     backend="xla")
 
 
 def _rand(shape, key):
     return jax.random.normal(key, shape, jnp.float32)
-
-
-@pytest.fixture(autouse=True)
-def _xla_reference_backend():
-    """Keep the module-global backend at its default around every test."""
-    set_attention_backend("xla")
-    yield
-    set_attention_backend("auto")
 
 
 def test_pick_block():
@@ -144,24 +136,24 @@ def test_uneven_blocks():
 
 
 def test_backend_dispatch_forced_flash():
-    """set_attention_backend('flash') routes causal_attention through the
-    kernel (interpret mode off-TPU) with identical results."""
+    """backend="flash" routes causal_attention through the kernel
+    (interpret mode off-TPU) with identical results."""
     B, H, T, dh = 1, 2, 32, 8
     ks = jax.random.split(jax.random.key(5), 3)
     q, k, v = (_rand((B, H, T, dh), kk) for kk in ks)
     with jax.default_matmul_precision("highest"):
-        set_attention_backend("xla")
         ref = causal_attention(q, k, v)
-        set_attention_backend("flash")
-        got = causal_attention(q, k, v)
-        set_attention_backend("xla")
+        got = transformer.causal_attention(q, k, v, backend="flash")
     np.testing.assert_allclose(np.asarray(ref), np.asarray(got),
                                rtol=1e-4, atol=1e-5)
 
 
 def test_backend_validation():
+    q = jnp.zeros((1, 1, 8, 8), jnp.float32)
     with pytest.raises(ValueError, match="backend"):
-        set_attention_backend("cuda")
+        fa.flash_dispatch("cuda", q, q, q)
+    with pytest.raises(ValueError, match="backend"):
+        transformer.causal_attention(q, q, q, backend="cuda")
     from ddlbench_tpu.config import RunConfig
 
     with pytest.raises(ValueError, match="attention_backend"):
@@ -553,9 +545,10 @@ def test_split_widths_through_the_dispatch():
         np.testing.assert_allclose(np.asarray(xla),
                                    np.asarray(_einsum_attention(q, k, v)),
                                    atol=2e-5)
-        set_attention_backend("flash")
-        np.testing.assert_allclose(np.asarray(causal_attention(q, k, v)),
-                                   np.asarray(xla), atol=2e-5)
+        np.testing.assert_allclose(
+            np.asarray(transformer.causal_attention(q, k, v,
+                                                    backend="flash")),
+            np.asarray(xla), atol=2e-5)
 
 
 def test_split_widths_lse_and_its_cotangent():

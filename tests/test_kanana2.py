@@ -143,7 +143,7 @@ def test_program_matches_the_reference(dataset, arch, remat):
 
 
 def _expert_layer(held):
-    return kanana2.expert_block("b", DIMS, held)
+    return kanana2.expert_block("b", DIMS, held, "auto")
 
 
 def test_the_shares_add_up_to_the_uncut_layer(dataset):

@@ -491,15 +491,13 @@ def small_pages(monkeypatch):
 def mt_model():
     import ddlbench_tpu.models.seq2seq as s2s
     from ddlbench_tpu.models.layers import init_model
-    from ddlbench_tpu.models.transformer import set_attention_backend
 
     s2s._VARIANTS.setdefault("seq2seq_t",
                              dict(d_model=32, n_layers=2, n_heads=4))
-    set_attention_backend("xla")
-    model = s2s.build_seq2seq("seq2seq_t", (16,), 64, 8)
+    model = s2s.build_seq2seq("seq2seq_t", (16,), 64, 8,
+                              attention_backend="xla")
     params, state, _ = init_model(model, jax.random.key(0))
-    yield model, params, state
-    set_attention_backend("auto")
+    return model, params, state
 
 
 @pytest.mark.slow
@@ -543,22 +541,18 @@ def test_paged_causal_lm_greedy_token_identical(small_pages):
     """Causal LMs (plain transformer blocks) share the paged protocol."""
     import ddlbench_tpu.models.decode as dec
     from ddlbench_tpu.models.layers import init_model
-    from ddlbench_tpu.models.transformer import (_VARIANTS, build_transformer,
-                                                 set_attention_backend)
+    from ddlbench_tpu.models.transformer import _VARIANTS, build_transformer
 
     _VARIANTS.setdefault("transformer_t",
                          dict(d_model=32, n_layers=2, n_heads=4))
-    set_attention_backend("xla")
-    try:
-        model = build_transformer("transformer_t", (16,), 64)
-        params, state, _ = init_model(model, jax.random.key(3))
-        assert dec.supports_paged(model)
-        src = jax.random.randint(jax.random.key(6), (2, 5), 0, 64, jnp.int32)
-        ref = dec.greedy_decode(model, params, state, src, 16)
-        got = dec.greedy_decode(model, params, state, src, 16, paged=True)
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
-    finally:
-        set_attention_backend("auto")
+    model = build_transformer("transformer_t", (16,), 64,
+                              attention_backend="xla")
+    params, state, _ = init_model(model, jax.random.key(3))
+    assert dec.supports_paged(model)
+    src = jax.random.randint(jax.random.key(6), (2, 5), 0, 64, jnp.int32)
+    ref = dec.greedy_decode(model, params, state, src, 16)
+    got = dec.greedy_decode(model, params, state, src, 16, paged=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
 
 
 @pytest.mark.slow
@@ -568,22 +562,18 @@ def test_paged_moe_beam_token_identical(small_pages):
     import ddlbench_tpu.models.decode as dec
     import ddlbench_tpu.models.moe as moe
     from ddlbench_tpu.models.layers import init_model
-    from ddlbench_tpu.models.transformer import set_attention_backend
 
     moe._VARIANTS.setdefault(
         "transformer_moe_t", dict(d_model=32, n_layers=2, n_heads=4,
                                   n_experts=4))
-    set_attention_backend("xla")
-    try:
-        model = moe.build_transformer_moe("transformer_moe_t", (16,), 64,
-                                          capacity_factor=8.0)
-        params, state, _ = init_model(model, jax.random.key(5))
-        assert dec.supports_paged(model)
-        src = jax.random.randint(jax.random.key(7), (2, 5), 0, 64, jnp.int32)
-        ref_x, _ = dec.beam_search_decode(model, params, state, src, 16,
-                                          beam=2)
-        got_x, _ = dec.beam_search_decode(model, params, state, src, 16,
-                                          beam=2, paged=True)
-        np.testing.assert_array_equal(np.asarray(got_x), np.asarray(ref_x))
-    finally:
-        set_attention_backend("auto")
+    model = moe.build_transformer_moe("transformer_moe_t", (16,), 64,
+                                      capacity_factor=8.0,
+                                      attention_backend="xla")
+    params, state, _ = init_model(model, jax.random.key(5))
+    assert dec.supports_paged(model)
+    src = jax.random.randint(jax.random.key(7), (2, 5), 0, 64, jnp.int32)
+    ref_x, _ = dec.beam_search_decode(model, params, state, src, 16,
+                                      beam=2)
+    got_x, _ = dec.beam_search_decode(model, params, state, src, 16,
+                                      beam=2, paged=True)
+    np.testing.assert_array_equal(np.asarray(got_x), np.asarray(ref_x))

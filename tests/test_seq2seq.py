@@ -21,9 +21,10 @@ TINY_MT = DatasetSpec("tinymt", (16,), 64, 1000, 100, kind="seq2seq", src_len=8)
 s2s._VARIANTS["seq2seq_t"] = dict(d_model=32, n_layers=2, n_heads=4)
 
 
-def tiny_seq2seq():
+def tiny_seq2seq(attention_backend="auto"):
     return s2s.build_seq2seq("seq2seq_t", TINY_MT.image_size,
-                             TINY_MT.num_classes, TINY_MT.src_len)
+                             TINY_MT.num_classes, TINY_MT.src_len,
+                             attention_backend)
 
 
 @pytest.fixture(scope="module")
@@ -195,21 +196,13 @@ def test_decode_rejects_wrong_src_width(model_and_params):
 
 
 def test_seq2seq_flash_backend_matches_xla(model_and_params):
-    from ddlbench_tpu.models.transformer import set_attention_backend
-
-    model, params, state = model_and_params
+    _, params, state = model_and_params
     x = jax.random.randint(jax.random.key(9), (2, TINY_MT.image_size[0]),
                            0, 64, jnp.int32)
     with jax.default_matmul_precision("highest"):
-        set_attention_backend("xla")
-        try:
-            ref = _logits(model, params, state, x)
-        finally:
-            set_attention_backend("flash")  # interpret-mode kernel off-TPU
-        try:
-            got = _logits(model, params, state, x)
-        finally:
-            set_attention_backend("auto")
+        ref = _logits(tiny_seq2seq("xla"), params, state, x)
+        # interpret-mode kernel off-TPU
+        got = _logits(tiny_seq2seq("flash"), params, state, x)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=2e-4, atol=2e-4)
 

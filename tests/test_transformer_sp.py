@@ -125,8 +125,6 @@ def test_ring_attention_flash_matches_full(devices):
     """The TPU ring path (fused kernel per visiting block + logsumexp
     combination) must equal full causal attention — values AND grads.
     Forced 'flash' backend runs the kernels in interpret mode on CPU."""
-    from ddlbench_tpu.models.transformer import set_attention_backend
-
     B, H, T, dh, n = 1, 2, 32, 8, 4
     k1, k2, k3, k4 = jax.random.split(jax.random.key(3), 4)
     q = jax.random.normal(k1, (B, H, T, dh))
@@ -146,24 +144,21 @@ def test_ring_attention_flash_matches_full(devices):
         # runs under the default checked shard_map via the kernels'
         # vma-annotated out_shapes.
         return _shard_map(
-            lambda ql, kl, vl: ring_attention(ql, kl, vl, "seq"),
+            lambda ql, kl, vl: ring_attention(ql, kl, vl, "seq",
+                                              backend="flash"),
             mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
             check_vma=False,
         )(q, k, v)
 
-    try:
-        set_attention_backend("flash")
-        with jax.default_matmul_precision("highest"):
-            got = ringed(q, k, v)
-            got_g = jax.grad(
-                lambda *a: jnp.sum(ringed(*a) * g), argnums=(0, 1, 2)
-            )(q, k, v)
-    finally:
-        set_attention_backend("xla")
     with jax.default_matmul_precision("highest"):
-        ref = causal_attention(q, k, v)
+        got = ringed(q, k, v)
+        got_g = jax.grad(
+            lambda *a: jnp.sum(ringed(*a) * g), argnums=(0, 1, 2)
+        )(q, k, v)
+        ref = causal_attention(q, k, v, backend="xla")
         ref_g = jax.grad(
-            lambda *a: jnp.sum(causal_attention(*a) * g), argnums=(0, 1, 2)
+            lambda *a: jnp.sum(causal_attention(*a, backend="xla") * g),
+            argnums=(0, 1, 2)
         )(q, k, v)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=1e-4, atol=1e-5)

@@ -40,18 +40,14 @@ def one_chip():
 @pytest.fixture()
 def as_on_tpu(monkeypatch):
     """The kernels' dispatch asks the default backend, which is the CPU
-    here, and two process-wide settings an earlier test of the same worker
-    may have left set: steer all three in the test, quiet the compile cache
-    (an executable for a described chip cannot be read back)."""
+    here: steer it in the test (it reads nothing else a test could leave
+    set), quiet the compile cache (an executable for a described chip cannot
+    be read back)."""
     from jax.experimental.compilation_cache import compilation_cache as cc
 
     from ddlbench_tpu import distributed
-    from ddlbench_tpu.models import transformer
-    from ddlbench_tpu.ops import util
 
     monkeypatch.setattr(distributed, "is_tpu_backend", lambda: True)
-    monkeypatch.setattr(transformer, "_ATTENTION_BACKEND", ["auto"])
-    monkeypatch.setattr(util, "_IN_SHARDED_JIT", [False])
     before = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
@@ -85,7 +81,7 @@ def test_flash_attention_keeps_its_names_inside_the_scopes(one_chip,
     from ddlbench_tpu.models.layers import apply_slice
     from ddlbench_tpu.models.transformer import transformer_block
 
-    block = transformer_block("block3", D, H)
+    block = transformer_block("block3", D, H, attention_backend="auto")
     params = jax.eval_shape(lambda k: block.init(k, (T, D))[0],
                             jax.random.key(0))
     leaves, tree = jax.tree.flatten(params)
@@ -174,7 +170,7 @@ def test_the_latent_attention_block_compiles_with_named_kernels(one_chip,
     from ddlbench_tpu.models.layers import apply_slice
 
     dims = kanana2.FAMILY["kanana2_30b_a3b"]
-    block = kanana2.expert_block("block2", dims, (0, 8))
+    block = kanana2.expert_block("block2", dims, (0, 8), "auto")
     params, state = jax.eval_shape(
         lambda k: block.init(k, (4096, dims.d_model))[:2], jax.random.key(0))
     leaves, tree = jax.tree.flatten(params)
