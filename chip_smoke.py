@@ -47,7 +47,7 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 OUT_DIR = os.path.join(HERE, "chiprun_out", "chip_smoke")
 
 FLASH_KERNELS = {"flash_attn_fwd", "flash_attn_dq_dkv"}
-XENT_KERNELS = {"fused_xent_fwd", "fused_xent_dh", "fused_xent_dw"}
+XENT_KERNELS = {"fused_xent_fwd", "fused_xent_dh_dw"}
 
 
 class _Tee(io.TextIOBase):

@@ -35,13 +35,13 @@ Two grid designs:
 _use_streaming picks by ONE accounting, _resident_vmem_bytes: what the
 resident kernel would hold in VMEM at the call's shapes (its blocks as
 Mosaic pads and double-buffers them, its scratch, its tile temporaries).
-Resident while that sum fits RESIDENT_VMEM_BUDGET, half the chip's VMEM;
-streaming beyond; the same sum and a quarter more is the kernel's
-``vmem_limit_bytes``. The two benchmark shapes (bf16, 512x512), forward /
-one-pass backward: T=1024 dh=64 (gpt2s-train) 4.1 / 6.5 MiB; T=4096 q/k 192
-v 128 (kanana2-ep16-train) 9.5 / 18.9 MiB. Block-level causal skipping in
-both designs: resident bounds its fori, streaming skips dead cells' compute
-under @pl.when.
+Resident while that sum fits RESIDENT_VMEM_BUDGET (ops/util.py), half the
+chip's VMEM; streaming beyond; the same sum and a quarter more is the
+kernel's ``vmem_limit_bytes``. The two benchmark shapes (bf16, 512x512),
+forward / one-pass backward: T=1024 dh=64 (gpt2s-train) 4.1 / 6.5 MiB;
+T=4096 q/k 192 v 128 (kanana2-ep16-train) 9.5 / 18.9 MiB. Block-level causal
+skipping in both designs: resident bounds its fori, streaming skips dead
+cells' compute under @pl.when.
 
 Measured on one v5e, bf16, device ms per call (PERF.md: PR 25 for dh=64,
 PR 28 for the rest), forward / backward, resident against streaming:
@@ -77,25 +77,13 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ddlbench_tpu.ops.util import RESIDENT_VMEM_BUDGET  # patched by tests
+from ddlbench_tpu.ops.util import grid_params as _grid_params
 from ddlbench_tpu.ops.util import pallas_out_struct as _out_struct
+from ddlbench_tpu.ops.util import tile_bytes as _tile_bytes
+from ddlbench_tpu.ops.util import vmem_limit_bytes as _vmem_limit_bytes
 
 NEG_INF = -1e30
-
-# A resident kernel may hold half of a v5e TensorCore's 128 MiB of VMEM (as
-# _resident_vmem_bytes sums it); past that the streaming design runs. Half,
-# because the other half has to take the kernel's margin (_vmem_limit_bytes:
-# a limit of up to 80 MiB), and what XLA keeps in VMEM across the call. No
-# shape was found that compiles and runs slower resident: the largest run
-# on the chip (PERF.md, PR 28: 61.2 MiB, T 32768 dh 64; 58.1 MiB, T 16384
-# 192/128) beat streaming 1.9x forward and 2.0-2.4x backward.
-RESIDENT_VMEM_BUDGET = (128 << 20) // 2
-
-
-def _tile_bytes(rows: int, cols: int, itemsize: int) -> int:
-    """Bytes of a [rows, cols] array in VMEM: the lanes padded to 128, the
-    sublanes to a whole tile (8 rows of 32 bits: 16 of bf16)."""
-    up = lambda n, m: -(-n // m) * m
-    return up(rows, 8 * max(1, 4 // itemsize)) * up(cols, 128) * itemsize
 
 
 def _resident_vmem_bytes(t_inner: int, dh: int, dv: int, itemsize: int,
@@ -141,13 +129,6 @@ def _resident_vmem_bytes(t_inner: int, dh: int, dv: int, itemsize: int,
     return blocks + resident + temps
 
 
-def _vmem_limit_bytes(held: int) -> int:
-    """The ``vmem_limit_bytes`` of a resident kernel that holds ``held``: a
-    quarter more, for Mosaic's own scratch and for the shapes at which the
-    accounting reads under its report (_resident_vmem_bytes)."""
-    return held + held // 4
-
-
 def _use_streaming(t_inner: int, dh: int, itemsize: int, bq: int, bk: int,
                    stream, backward: bool = False, interpret: bool = False,
                    dv: int | None = None) -> bool:
@@ -164,19 +145,6 @@ def _use_streaming(t_inner: int, dh: int, itemsize: int, bq: int, bk: int,
         return True
     return _resident_vmem_bytes(t_inner, dh, dv, itemsize, bq, bk,
                                 backward) > RESIDENT_VMEM_BUDGET
-
-
-def _grid_params(interpret: bool, *semantics: str, vmem_limit_bytes=None):
-    """Mosaic grid hints: "parallel" grid axes are independent, an
-    "arbitrary" one is sequential — it carries a scratch accumulator (the
-    streamed inner dimension; the one-pass backward's K-block axis, across
-    which dQ accumulates). No-op under interpret (CPU tests)."""
-    if interpret:
-        return {}
-    from jax.experimental.pallas import tpu as pltpu
-
-    return {"compiler_params": pltpu.CompilerParams(
-        dimension_semantics=semantics, vmem_limit_bytes=vmem_limit_bytes)}
 
 
 def _pick_block(t: int, preferred: int, interpret: bool = False) -> int:

@@ -1,12 +1,14 @@
 """Fused LM-head loss: linear projection + softmax cross-entropy without ever
 materializing the full [N, V] logits tensor in HBM.
 
-Why: on the token workloads the vocabulary is 32k (config.DATASETS), so the
-unfused path writes logits [B*T, V] (plus an f32 log-softmax copy and an f32
-gradient) — gigabytes per step that dwarf every activation in the model. The
-reference has no analog (its classifiers top out at 1000 classes — this is
-the sequence-workload equivalent of SURVEY.md §2 D2's "hot op gets a custom
-kernel" rule). The fusion computes, per row chunk,
+Why: on the token workloads the vocabulary is 16k to 50k wide and a step has
+16k rows (config.DATASETS; gpt2-small's 50304 and kanana-2's 16128-row slice
+in the benchmark's cells), so the unfused path writes logits [B*T, V] (plus an
+f32 log-softmax copy and an f32 gradient) — gigabytes per step that dwarf
+every activation in the model. The reference has no analog (its classifiers
+top out at 1000 classes — this is the sequence-workload equivalent of
+SURVEY.md §2 D2's "hot op gets a custom kernel" rule). The fusion computes,
+per row chunk,
 
     z_c = h_c @ W          (MXU, f32 accumulation)
     lse = logsumexp(z_c);  nll = lse - z_gold;  argmax for top-1
@@ -21,6 +23,13 @@ so peak memory drops from O(N*V) to O(chunk*V) and the [N, V] round-trips
 through HBM disappear. Label smoothing follows parallel/common.py
 cross_entropy_loss semantics (GNMT-style: loss = (1-s)*NLL - s*mean_v logp_v);
 rows with label < 0 are masked (the seq2seq source segment).
+
+Two implementations of that mathematics: a chunked-XLA scan over row chunks
+(_fxent_fwd / _fxent_bwd_xla: dp / tp / fsdp's plain jits, which GSPMD
+partitions, and every off-TPU run) and two Pallas kernels (second half of
+this file: ``fused_xent_fwd`` and the one-pass backward
+``fused_xent_dh_dw``), whose blocks are sized by what the kernels hold in
+a v5e core's 128 MiB of VMEM.
 
 Returned values are SUMS over valid rows — (objective_sum, ce_sum, correct) —
 so sequence-parallel callers can psum numerators and denominators separately.
@@ -37,8 +46,10 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
+from ddlbench_tpu.ops.util import (RESIDENT_VMEM_BUDGET, grid_params,
+                                   takes_pallas, tile_bytes,
+                                   vmem_limit_bytes)
 from ddlbench_tpu.ops.util import pallas_out_struct as _pl_out
-from ddlbench_tpu.ops.util import takes_pallas
 
 from ddlbench_tpu.compat import pcast_varying as _pcast_to
 from ddlbench_tpu.compat import vma_of as _vma
@@ -72,37 +83,26 @@ def _row_stats(z, labels, smoothing: float):
 
 
 def _pallas_feasible(h, w, backend: str, interpret: bool) -> bool:
-    """Mosaic wants lane-dim blocks in multiples of 128 (a vocab with no
-    such divisor can't run the compiled kernels), and every kernel's block
-    working set must fit scoped VMEM even at the 128-lane floor — a very
-    wide D blows the dW accumulator alone (_budget_v_block -> None). The
-    budget is evaluated at the row block the kernels will actually use
-    (small row counts shrink it, and the dh fixed cost with it). auto falls
-    back to chunked-XLA; a forced "pallas" backend gets a clear error
-    instead of a Mosaic one."""
+    """Whether the kernels can take this head: on real TPU the vocabulary
+    has to be a multiple of 128 lanes (Mosaic's lane tiling; the blocks
+    themselves need not divide it), and both kernels need blocks whose
+    working set fits the VMEM budget (_blocks -> None: a very wide D, whose
+    [D, 128] float32 dW blocks alone pass it). auto falls back to
+    chunked-XLA; a forced "pallas" backend gets a clear error instead of a
+    Mosaic one."""
     if interpret:
         return True
-    D, V = w.shape
-    # Price with the wider of the two dtypes: the launch sites size blocks
-    # with h.dtype.itemsize (lines 442/555+), so a gate priced only on w
-    # could pass while _budget_v_block returns None at launch (ADVICE r3).
+    N, (D, V) = h.shape[0], w.shape
     isz = max(h.dtype.itemsize, w.dtype.itemsize)
-    br = _row_block(h.shape[0], interpret)
-    ok = (
-        _budget_v_block(V, D, br, isz, False) is not None  # fwd
-        and _budget_v_block(V, D, br, isz, False,
-                            **_dh_price(D, br, isz)) is not None
-        and _budget_v_block(V, D, br, isz, False,
-                            **_dw_price(D, br, isz)) is not None
-    )
-    if ok:
+    if all(_blocks(k, N, D, V, isz, False) for k in ("fwd", "bwd")):
         return True
     if backend == "pallas":
         raise ValueError(
             f"fused_linear_xent: no feasible Pallas blocking for head "
-            f"[D={D}, V={V}] — the vocab needs a 128-multiple block divisor "
-            f"and every kernel's block working set must fit scoped VMEM "
-            f"({VMEM_HARD >> 20} MiB); pad the vocab or use backend='xla'")
+            f"[D={D}, V={V}] — the vocab has to be a multiple of 128 lanes "
+            f"and every kernel's block working set must fit its VMEM budget "
+            f"({RESIDENT_VMEM_BUDGET >> 20} MiB); pad the vocab or use "
+            f"backend='xla'")
     return False
 
 
@@ -269,137 +269,213 @@ def fused_linear_xent_eval(h, w, labels, k: int = 5, row_chunk: int = 512):
 # ---------------------------------------------------------------------------
 # Pallas TPU kernels — same math, zero logits traffic to HBM.
 #
-# Forward: grid (row_blocks, v_blocks), W streamed blockwise through VMEM
-# (~16 MB/core, so [D, 32k] never fits whole); online-logsumexp scratch
-# carried across the inner v sweep; per-row (lse, gold, zsum, argmax) written
-# on the last v block and reduced to the three sums with trivial XLA ops.
-# Backward: dh kernel accumulates dz @ W_j^T over the inner v sweep; dW kernel
-# flips the grid and accumulates h_i^T @ dz over the inner row sweep (the
-# two-kernel split of ops/flash_attention.py's streaming dq / dkv pair).
+# Both kernels tile the N x V logits as [br, bv] grid steps and, inside a
+# step, compute z = h W for SUB_ROWS rows at a time (the f32 z tile and the
+# code Mosaic unrolls for it stay bounded while W's block is re-read only
+# once per br rows). The vocabulary block need not divide V: the last one is
+# cut by the column index (z masked for the statistics, dz and W's columns
+# past V zeroed: what Pallas pads a cut block with is not defined).
+#
+# Forward (fused_xent_fwd): grid (row blocks, vocabulary blocks), the
+# vocabulary inside. The running statistics are kept PER LANE: [br, 128]
+# scratch for the running max (which is also the arg-max's value), the
+# arg-max's 128-column chunk, the exp-sum, the gold logit and the logit sum,
+# updated elementwise per 128-lane column of the z tile and reduced across
+# the lanes once, on the last vocabulary block.
+#
+# Backward (fused_xent_dh_dw): ONE kernel, grid (vocabulary blocks, row
+# blocks), the rows inside. Each z tile, its exp and dz are formed once and
+# feed both products: dW_j = sum_i h_i^T dz stays in VMEM across the inner
+# sweep (its output block does not move); dh_i += dz W_j^T is float32 in
+# HBM, fetched, added to and written back within the grid step by the
+# kernel's own DMAs, each started and waited for inside that step: Pallas's
+# pipeline, which orders nothing between a block's write-back and a later
+# prefetch of the same block, never touches dh.
+#
+# Measured on one v5e, bf16, N x D x V, device ms per call (PERF.md, PR 30),
+# forward / backward; beside them the three kernels this file held before
+# (forward / dh + dw: [256, 384] and [256, 896 | 384 | 128] tiles, blocks
+# that had to divide V, five cross-lane reductions a tile, z and its exp
+# formed twice):
+#
+#     16384 x  768 x 50304 (gpt2s-train)          7.34 / 20.54   15.58 / 30.35
+#     16384 x 2048 x 16128 (kanana2-ep16-train)   5.93 / 17.24    6.84 / 26.14
+#
+# i.e. 88% / 94% and 93% / 96% of the MXU's peak for one / three N*D*V
+# products. Every block tried between (512, 1024) and (2048, 2048) ran within
+# 4% of these; the lane columns rolled into a real loop cost the forward 72%.
 # ---------------------------------------------------------------------------
 
-ROW_BLOCK = 256
-V_BLOCK = 2048
-# Per-kernel working-set target and hard ceiling. v5e gives ~16 MiB of
-# scoped VMEM per core; target well under it so double-buffering + compiler
-# temporaries fit (the dW kernel at (br=256, bv=2048, D=512) measures
-# 18.2 MiB on-chip and is rejected by Mosaic, hence the budget-aware block
-# choice below). A block between target and hard limit is best-effort
-# (returned, may still compile); past VMEM_HARD even the 128-lane floor
-# cannot fit and the caller must take the chunked-XLA path instead.
-VMEM_BUDGET = 12 * 1024 * 1024
-VMEM_HARD = 16 * 1024 * 1024
+ROW_BLOCK = 1024  # rows of a grid step, at most: W is re-read per row block
+V_BLOCK = 2048    # vocabulary columns of a grid step, at most
+SUB_ROWS = 256    # rows of one z tile inside a grid step
+NEG_INF = -1e30
 
 
-def _pick_block(t: int, preferred: int, unit: int = 1) -> Optional[int]:
-    """Tile-aligned block divisor (ops/util.py:pick_block); ``unit`` is 128
-    for the lane (vocab) dimension on real TPU."""
-    from ddlbench_tpu.ops.util import pick_block
-
-    return pick_block(t, preferred, unit)
+def _round_up(n: int, m: int) -> int:
+    return pl.cdiv(n, m) * m
 
 
-def _budget_v_block(V: int, D: int, br: int, in_size: int, interpret: bool,
-                    per_bv: int = 0, fixed: int = 0) -> int:
-    """Largest 128-multiple vocab-block divisor of ``V`` whose kernel
-    working set fits ``VMEM_BUDGET``.
+def _lanes(bv: int) -> int:
+    """Width of the per-lane statistics: a vreg's 128 lanes; the whole block
+    where it is no multiple of them (interpret mode's small vocabularies)."""
+    return 128 if bv % 128 == 0 else bv
 
-    Shared terms for all three kernels: double-buffered input blocks
-    (h [br, D], w [D, bv]) plus the recomputed f32 logit block [br, bv].
-    ``per_bv`` prices kernel-specific bytes per vocab lane (dz blocks, the
-    dW kernel's f32 [D, bv] scratch + double-buffered f32 out block);
-    ``fixed`` prices bv-independent extras (the dh kernel's [br, D] f32
-    accumulator and double-buffered out block).
 
-    Returns None when even the smallest lane-aligned block exceeds
-    VMEM_HARD (a very wide D — the bv-independent terms alone blow the
-    scoped-VMEM limit); the caller falls back to the chunked-XLA path via
-    _pallas_feasible. A pick between VMEM_BUDGET and VMEM_HARD is returned
-    best-effort."""
-    bv = _pick_block(V, V_BLOCK, 1 if interpret else 128)
-    if interpret or bv is None:
-        return bv
+def _v_block(V: int, cap: int, unit: int) -> int:
+    """Vocabulary block of at most ``cap`` columns, a multiple of ``unit``,
+    evened out over the blocks V needs: the last, cut one wastes least."""
+    cap = max(unit, cap - cap % unit)
+    return min(cap, _round_up(pl.cdiv(V, pl.cdiv(V, cap)), unit))
 
-    def footprint(b: int) -> int:
-        ins = 2 * (br * D + D * b) * in_size
-        return ins + br * b * 4 + per_bv * b + fixed
 
-    while bv > 128 and footprint(bv) > VMEM_BUDGET:
-        smaller = _pick_block(V, bv // 2, 128)
-        if smaller is None or smaller == bv:
-            break
-        bv = smaller
-    if footprint(bv) > VMEM_HARD:
+def _row_block(n: int, cap: int, interpret: bool) -> int:
+    """Rows of a grid step: ``cap``, or all of a smaller n in whole z tiles
+    (under one tile, on real TPU, in bf16's 16 sublanes; rows are padded up
+    to a block multiple either way)."""
+    if n >= cap:
+        return cap
+    return _round_up(n, SUB_ROWS if n > SUB_ROWS else 1 if interpret else 16)
+
+
+def _sub_rows(br: int) -> int:
+    return SUB_ROWS if br % SUB_ROWS == 0 else br
+
+
+def _held_vmem_bytes(kernel: str, br: int, bv: int, D: int, isz: int) -> int:
+    """What a kernel ("fwd" or "bwd") holds in VMEM at these blocks: its
+    operand and output blocks as Mosaic pads and double-buffers them
+    (util.tile_bytes), its scratch, and one float32 z tile (the backward
+    also one tile's dh rows and h^T, which dW's product contracts over the
+    rows; that product is added in place). Checked against the least
+    ``vmem_limit_bytes`` Mosaic accepts (local v5e compile; PERF.md, PR 30)
+    at six shapes a kernel — D 512..4096, blocks (256, 1024)..(1024, 2048),
+    bf16 and f32, both cells' among them: the sum reads 1.03-1.22 of Mosaic's
+    number forward and 1.05-1.15 backward (1.22 / 1.05 at gpt2s-train's
+    shape, 1.08 / 1.05 at kanana2-ep16-train's)."""
+    f32, sr = 4, _sub_rows(br)
+    col = tile_bytes(br, 1, f32)  # a [br, 1] column pads to 128 lanes
+    h, w = tile_bytes(br, D, isz), tile_bytes(D, bv, isz)
+    z = tile_bytes(sr, bv, f32)
+    if kernel == "fwd":
+        blocks = 2 * (h + w + col) + 2 * 4 * col
+        return blocks + 5 * tile_bytes(br, 128, f32) + z
+    blocks = 2 * (h + w + 2 * col) + 2 * tile_bytes(D, bv, f32)
+    scratch = tile_bytes(br, bv, isz) + tile_bytes(br, D, f32)  # dz, dh
+    return blocks + scratch + z + tile_bytes(sr, D, f32) + h
+
+
+def _blocks(kernel: str, N: int, D: int, V: int, isz: int, interpret: bool):
+    """(br, bv) of a kernel: the caps, halved until what the kernel holds
+    fits RESIDENT_VMEM_BUDGET. The forward keeps its rows while the
+    vocabulary sweeps and re-reads W once per row block: its columns go
+    first (to 512); the backward moves a float32 dh through HBM once per
+    vocabulary block: its rows go first (to one z tile). None where even 128
+    lanes by 16 rows do not fit (a very wide D), or V is no multiple of 128
+    lanes on real TPU."""
+    if interpret:  # any block runs; lane-aligned where the vocabulary is
+        return (_row_block(N, ROW_BLOCK, True),
+                _v_block(V, V_BLOCK, 1 if V % 128 else 128))
+    if V % 128:
         return None
-    return bv
+    rows, cols = ROW_BLOCK, V_BLOCK
+    while True:
+        br, bv = _row_block(N, rows, False), _v_block(V, cols, 128)
+        if _held_vmem_bytes(kernel, br, bv, D, isz) <= RESIDENT_VMEM_BUDGET:
+            return br, bv
+        rows_first = br > SUB_ROWS if kernel == "bwd" else bv <= 512
+        if br > 16 and (rows_first or bv == 128):
+            rows = max(16, br // 32 * 16)  # halved, whole bf16 tiles
+        elif bv > 128:
+            cols = max(128, bv // 2)
+        else:
+            return None
 
 
-def _dh_price(D: int, br: int, in_size: int) -> dict:
-    """dh-kernel _budget_v_block terms: a dz block [br, bv] in the compute
-    dtype per lane, plus the bv-independent f32 [br, D] accumulator and
-    double-buffered [br, D] out block. One home for the formulas shared by
-    the feasibility gate, the kernel launch, and tests/test_vmem_budget.py."""
-    return dict(per_bv=br * in_size, fixed=br * D * (4 + 2 * in_size))
-
-
-def _dw_price(D: int, br: int, in_size: int) -> dict:
-    """dW-kernel terms: the dz block plus an f32 [D, bv] scratch accumulator
-    and a double-buffered f32 [D, bv] out block (3 * D * 4 bytes per lane)."""
-    return dict(per_bv=br * in_size + 3 * D * 4)
-
-
-def _row_block(n: int, interpret: bool) -> int:
-    """Row (sublane) block: ROW_BLOCK, shrunk for small n but kept a multiple
-    of 8 on real TPU (rows are padded up to a block multiple either way)."""
-    if n >= ROW_BLOCK:
-        return ROW_BLOCK
-    return n if interpret else -(-n // 8) * 8
+def _lane_columns(nc: int, tail_c: int, body, carry):
+    """``carry = body(c, cut, carry)`` over a tile's nc lane columns: the
+    columns under ``tail_c``, which lie inside V in every vocabulary block,
+    then the rest, which the last block cuts (``cut``: mask by the column
+    index). Two loops that Mosaic unrolls whole — straight-line code, which
+    is what lets it run a column's vector work under the next one's matmul
+    (rolled: forward 12.6 against 7.3 ms at gpt2s-train's shape; PERF.md, PR
+    30) — while jax traces each body once, whatever bv."""
+    carry = jax.lax.fori_loop(0, tail_c, lambda c, x: body(c, False, x),
+                              carry, unroll=True)
+    return jax.lax.fori_loop(tail_c, nc, lambda c, x: body(c, True, x),
+                             carry, unroll=True)
 
 
 def _fx_fwd_kernel(h_ref, w_ref, lab_ref, lse_ref, gold_ref, zsum_ref,
-                   amax_ref, m_sc, l_sc, gold_sc, zsum_sc, av_sc, ai_sc, *,
-                   bv: int, nv: int):
+                   amax_ref, m_sc, l_sc, gold_sc, zsum_sc, ai_sc, z_sc, *,
+                   bv: int, nv: int, V: int, smoothing: float):
     j = pl.program_id(1)
+    br, (sr, _), lanes = h_ref.shape[0], z_sc.shape, _lanes(bv)
+    nc = bv // lanes
+    tail = V - (nv - 1) * bv  # columns of the last block that are in V
+    f32 = jnp.float32
 
     @pl.when(j == 0)
     def _init():
-        m_sc[:] = jnp.full(m_sc.shape, NEG_INF, jnp.float32)
-        l_sc[:] = jnp.zeros(l_sc.shape, jnp.float32)
-        gold_sc[:] = jnp.zeros(gold_sc.shape, jnp.float32)
-        zsum_sc[:] = jnp.zeros(zsum_sc.shape, jnp.float32)
-        av_sc[:] = jnp.full(av_sc.shape, NEG_INF, jnp.float32)
+        m_sc[:] = jnp.full(m_sc.shape, NEG_INF, f32)
+        l_sc[:] = jnp.zeros(l_sc.shape, f32)
+        gold_sc[:] = jnp.zeros(gold_sc.shape, f32)
+        zsum_sc[:] = jnp.zeros(zsum_sc.shape, f32)
         ai_sc[:] = jnp.zeros(ai_sc.shape, jnp.int32)
 
-    z = jax.lax.dot_general(
-        h_ref[:], w_ref[:], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )  # [br, bv]
-    lab = lab_ref[:]  # [br, 1]
-    col = j * bv + jax.lax.broadcasted_iota(jnp.int32, (1, bv), 1)
-    match = col == lab
-    gold_sc[:] += jnp.sum(jnp.where(match, z, 0.0), axis=1, keepdims=True)
-    zsum_sc[:] += jnp.sum(z, axis=1, keepdims=True)
-    bm = jnp.max(z, axis=1, keepdims=True)
-    bi = j * bv + jnp.argmax(z, axis=1).astype(jnp.int32)[:, None]
-    upd = bm > av_sc[:]
-    ai_sc[:] = jnp.where(upd, bi, ai_sc[:])
-    av_sc[:] = jnp.where(upd, bm, av_sc[:])
-    m_prev = m_sc[:]
-    m_new = jnp.maximum(m_prev, bm)
-    l_sc[:] = (l_sc[:] * jnp.exp(m_prev - m_new)
-               + jnp.sum(jnp.exp(z - m_new), axis=1, keepdims=True))
-    m_sc[:] = m_new
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, lanes), 1)
+    limit = jnp.where(j == nv - 1, tail, bv)
+
+    def column(c, cut: bool, fill):
+        """The z tile's c-th lane column, ``fill`` past V where ``cut``."""
+        zc = z_sc[:, pl.ds(pl.multiple_of(c * lanes, lanes), lanes)]
+        return jnp.where(lane + c * lanes < limit, zc, fill) if cut else zc
+
+    def tile(r, carry):
+        rows = pl.ds(pl.multiple_of(r * sr, sr), sr)
+        z_sc[:] = jax.lax.dot_general(
+            h_ref[rows, :], w_ref[:], (((1,), (0,)), ((), ())),
+            preferred_element_type=f32)  # [sr, bv]
+        lab = jnp.broadcast_to(lab_ref[rows, :] - j * bv, (sr, lanes))
+
+        def stats(c, cut, carry):
+            m, ai, gold, zsum = carry
+            if smoothing:
+                zsum = zsum + column(c, cut, 0.0)
+            zc = column(c, cut, NEG_INF)
+            gold = jnp.where(lane + c * lanes == lab, zc, gold)
+            # strictly greater: the lowest column of a lane keeps a tie
+            ai = jnp.where(zc > m, j * nc + c, ai)
+            return jnp.maximum(m, zc), ai, gold, zsum
+
+        m_old = m_sc[rows, :]
+        m, ai, gold, zsum = _lane_columns(
+            nc, tail // lanes, stats,
+            (m_old, ai_sc[rows, :], gold_sc[rows, :], zsum_sc[rows, :]))
+        l = _lane_columns(
+            nc, tail // lanes,
+            lambda c, cut, l: l + jnp.exp(column(c, cut, NEG_INF) - m),
+            l_sc[rows, :] * jnp.exp(m_old - m))
+        m_sc[rows, :], ai_sc[rows, :], l_sc[rows, :] = m, ai, l
+        gold_sc[rows, :] = gold
+        if smoothing:
+            zsum_sc[rows, :] = zsum
+        return carry
+
+    jax.lax.fori_loop(0, br // sr, tile, 0)
 
     @pl.when(j == nv - 1)
     def _fini():
-        l_safe = jnp.maximum(l_sc[:], 1e-20)
-        lse_ref[:] = m_sc[:] + jnp.log(l_safe)
-        gold_ref[:] = gold_sc[:]
-        zsum_ref[:] = zsum_sc[:]
-        amax_ref[:] = ai_sc[:]
-
-
-NEG_INF = -1e30
+        m = m_sc[:]
+        top = jnp.max(m, axis=1, keepdims=True)
+        l = jnp.sum(l_sc[:] * jnp.exp(m - top), axis=1, keepdims=True)
+        lse_ref[:] = top + jnp.log(jnp.maximum(l, 1e-20))
+        gold_ref[:] = jnp.sum(gold_sc[:], axis=1, keepdims=True)
+        zsum_ref[:] = jnp.sum(zsum_sc[:], axis=1, keepdims=True)
+        # of the lanes that hold the maximum, the lowest column
+        col = ai_sc[:] * lanes + lane
+        amax_ref[:] = jnp.min(jnp.where(m == top, col, V), axis=1,
+                              keepdims=True)
 
 
 def _fxent_fwd_pallas(h, w, labels, smoothing: float, interpret: bool):
@@ -407,46 +483,51 @@ def _fxent_fwd_pallas(h, w, labels, smoothing: float, interpret: bool):
 
     N, D = h.shape
     V = w.shape[1]
-    br = _row_block(N, interpret)
+    isz = max(h.dtype.itemsize, w.dtype.itemsize)
+    br, bv = _blocks("fwd", N, D, V, isz, interpret)
     # pad rows to a block multiple with masked labels
-    hp, lp, _ = _pad_rows(h, labels, br)
+    hp, lp, nr = _pad_rows(h, labels, br)
     Np = hp.shape[0]
-    nr = Np // br
-    bv = _budget_v_block(V, D, br,
-                         max(h.dtype.itemsize, w.dtype.itemsize), interpret)
-    nv = V // bv
+    nv = pl.cdiv(V, bv)
     lab2 = lp[:, None].astype(jnp.int32)
 
     f32 = jnp.float32
+    col = pl.BlockSpec((br, 1), lambda i, j: (i, 0))
+    stat = pltpu.VMEM((br, _lanes(bv)), f32)
     lse, gold, zsum, amax = pl.pallas_call(
-        functools.partial(_fx_fwd_kernel, bv=bv, nv=nv),
+        functools.partial(_fx_fwd_kernel, bv=bv, nv=nv, V=V,
+                          smoothing=smoothing),
         grid=(nr, nv),
         in_specs=[
             pl.BlockSpec((br, D), lambda i, j: (i, 0)),
             pl.BlockSpec((D, bv), lambda i, j: (0, j)),
-            pl.BlockSpec((br, 1), lambda i, j: (i, 0)),
+            col,
         ],
-        out_specs=[pl.BlockSpec((br, 1), lambda i, j: (i, 0))] * 4,
+        out_specs=[col] * 4,
         out_shape=[
             _pl_out((Np, 1), f32, hp, w, lab2),
             _pl_out((Np, 1), f32, hp, w, lab2),
             _pl_out((Np, 1), f32, hp, w, lab2),
             _pl_out((Np, 1), jnp.int32, hp, w, lab2),
         ],
-        scratch_shapes=[pltpu.VMEM((br, 1), f32)] * 5
-        + [pltpu.VMEM((br, 1), jnp.int32)],
+        scratch_shapes=[stat] * 4
+        + [pltpu.VMEM((br, _lanes(bv)), jnp.int32),
+           pltpu.VMEM((_sub_rows(br), bv), f32)],
         interpret=interpret,
         name="fused_xent_fwd",
+        **grid_params(
+            interpret, "parallel", "arbitrary",
+            vmem_limit_bytes=vmem_limit_bytes(
+                _held_vmem_bytes("fwd", br, bv, D, isz))),
     )(hp, w, lab2)
 
     lse = lse[:N, 0]
     gold = gold[:N, 0]
-    zsum = zsum[:N, 0]
     amax = amax[:N, 0]
     mask = labels >= 0
     nll = lse - gold
     if smoothing:
-        obj = lse - (1.0 - smoothing) * gold - smoothing * (zsum / V)
+        obj = lse - (1.0 - smoothing) * gold - smoothing * (zsum[:N, 0] / V)
     else:
         obj = nll
     obj_s = jnp.sum(jnp.where(mask, obj, 0.0))
@@ -455,62 +536,96 @@ def _fxent_fwd_pallas(h, w, labels, smoothing: float, interpret: bool):
     return (obj_s, ce_s, corr), (h, w, labels, lse)
 
 
-def _fx_dz(z, lab, lse_col, coef, bv: int, j, dtype):
-    """dz block [br, bv] from recomputed logits (shared by dh/dw kernels)."""
-    p = jnp.exp(z - lse_col)
-    col = j * bv + jax.lax.broadcasted_iota(jnp.int32, (1, bv), 1)
-    match = (col == lab).astype(jnp.float32)
-    c_p, c_oh, c_sm = coef[0, 0], coef[0, 1], coef[0, 2]
-    dz = c_p * p - c_oh * match - c_sm
-    maskf = (lab >= 0).astype(jnp.float32)
-    return (dz * maskf).astype(dtype)
+def _fx_dh_dw_kernel(h_ref, w_ref, lab_ref, lse_ref, coef_ref,
+                     dh_hbm, dw_ref, z_sc, dz_sc, dh_sc, sem, *, bv: int,
+                     nv: int, V: int, smoothing: float):
+    from jax.experimental.pallas import tpu as pltpu
 
+    j, i = pl.program_id(0), pl.program_id(1)
+    br, (sr, _), lanes = h_ref.shape[0], z_sc.shape, _lanes(bv)
+    tail = V - (nv - 1) * bv  # columns of the last block that are in V
+    f32 = jnp.float32
 
-def _fx_dh_kernel(h_ref, w_ref, lab_ref, lse_ref, coef_ref, dh_ref, acc_sc, *,
-                  bv: int, nv: int):
-    j = pl.program_id(1)
+    def tile_rows(r):
+        return pl.ds(pl.multiple_of(r * sr, sr), sr)
 
-    @pl.when(j == 0)
-    def _init():
-        acc_sc[:] = jnp.zeros(acc_sc.shape, jnp.float32)
+    def dh_copy(r, fetch: bool):
+        """The DMA of tile r's dh rows: HBM -> dh_sc, or back."""
+        there = dh_hbm.at[pl.ds(pl.multiple_of(i * br + r * sr, sr), sr), :]
+        here = dh_sc.at[tile_rows(r), :]
+        if fetch:
+            return pltpu.make_async_copy(there, here, sem.at[0, r])
+        return pltpu.make_async_copy(here, there, sem.at[1, r])
 
-    z = jax.lax.dot_general(
-        h_ref[:], w_ref[:], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    dz = _fx_dz(z, lab_ref[:], lse_ref[:], coef_ref[:], bv, j, h_ref.dtype)
-    acc_sc[:] += jax.lax.dot_general(
-        dz, w_ref[:], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    def each_tile(f):
+        def body(r, carry):
+            f(r)
+            return carry
 
-    @pl.when(j == nv - 1)
-    def _fini():
-        dh_ref[:] = acc_sc[:].astype(dh_ref.dtype)
+        jax.lax.fori_loop(0, br // sr, body, 0)
 
+    @pl.when(j > 0)  # what the earlier vocabulary blocks summed
+    def _fetch_dh():
+        each_tile(lambda r: dh_copy(r, True).start())
 
-def _fx_dw_kernel(h_ref, w_ref, lab_ref, lse_ref, coef_ref, dw_ref, acc_sc, *,
-                  bv: int, nr: int):
-    i = pl.program_id(1)
-    j = pl.program_id(0)
+    if tail < bv:
+        @pl.when(j == nv - 1)
+        def _zero_w_past_v():  # dz is 0 there, and 0 * NaN is NaN
+            w_ref[:, tail:] = jnp.zeros((w_ref.shape[0], bv - tail),
+                                        w_ref.dtype)
+
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, lanes), 1)
+    limit = jnp.where(j == nv - 1, tail, bv)
+    c_p, c_oh, c_sm = coef_ref[0, 0], coef_ref[0, 1], coef_ref[0, 2]
+
+    def tile(r):
+        rows = tile_rows(r)
+        z_sc[:] = jax.lax.dot_general(
+            h_ref[rows, :], w_ref[:], (((1,), (0,)), ((), ())),
+            preferred_element_type=f32)  # [sr, bv]
+        lab = lab_ref[rows, :]
+        keep = (lab >= 0).astype(f32)  # masked rows: dz = 0
+        lab = jnp.broadcast_to(lab - j * bv, (sr, lanes))
+        lse = jnp.broadcast_to(lse_ref[rows, :], (sr, lanes))
+        k_p = jnp.broadcast_to(c_p * keep, (sr, lanes))
+        k_oh = jnp.broadcast_to(c_oh * keep, (sr, lanes))
+        if smoothing:
+            k_sm = jnp.broadcast_to(c_sm * keep, (sr, lanes))
+
+        def dz_column(c, cut, carry):
+            cols = pl.ds(pl.multiple_of(c * lanes, lanes), lanes)
+            dz = k_p * jnp.exp(z_sc[:, cols] - lse) - jnp.where(
+                lane + c * lanes == lab, k_oh, 0.0)
+            if smoothing:
+                dz = dz - k_sm
+            if cut:
+                dz = jnp.where(lane + c * lanes < limit, dz, 0.0)
+            dz_sc[rows, cols] = dz.astype(dz_sc.dtype)
+            return carry
+
+        _lane_columns(bv // lanes, tail // lanes, dz_column, 0)
+        dh = jax.lax.dot_general(
+            dz_sc[rows, :], w_ref[:], (((1,), (1,)), ((), ())),
+            preferred_element_type=f32)  # [sr, D]
+
+        @pl.when(j > 0)
+        def _wait_dh():
+            dh_copy(r, True).wait()
+
+        dh_sc[rows, :] = jnp.where(j > 0, dh_sc[rows, :], 0.0) + dh
+        dh_copy(r, False).start()
+
+    each_tile(tile)
 
     @pl.when(i == 0)
-    def _init():
-        acc_sc[:] = jnp.zeros(acc_sc.shape, jnp.float32)
+    def _init_dw():
+        dw_ref[:] = jnp.zeros(dw_ref.shape, f32)
 
-    z = jax.lax.dot_general(
-        h_ref[:], w_ref[:], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    dz = _fx_dz(z, lab_ref[:], lse_ref[:], coef_ref[:], bv, j, h_ref.dtype)
-    acc_sc[:] += jax.lax.dot_general(
-        h_ref[:], dz, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-
-    @pl.when(i == nr - 1)
-    def _fini():
-        dw_ref[:] = acc_sc[:].astype(dw_ref.dtype)
+    dw_ref[:] += jax.lax.dot_general(
+        h_ref[:], dz_sc[:], (((0,), (0,)), ((), ())),
+        preferred_element_type=f32)
+    # dh_sc is the next step's too: its rows have to be in HBM by then
+    each_tile(lambda r: dh_copy(r, False).wait())
 
 
 def _fxent_bwd_pallas(h, w, labels, lses, go, gce, smoothing: float,
@@ -519,60 +634,46 @@ def _fxent_bwd_pallas(h, w, labels, lses, go, gce, smoothing: float,
 
     N, D = h.shape
     V = w.shape[1]
-    br = _row_block(N, interpret)
-    hp, lp, _ = _pad_rows(h, labels, br)
-    Np = hp.shape[0]
-    nr = Np // br
-    # dh's accumulator + double-buffered out block are [br, D]
-    # (bv-independent); dW carries an f32 [D, bv] scratch plus a
-    # double-buffered f32 [D, bv] out block, so its lane block must shrink
-    # when D is wide (VMEM_BUDGET note above; formulas in _dh/_dw_price).
     isz = max(h.dtype.itemsize, w.dtype.itemsize)
-    bv = _budget_v_block(V, D, br, isz, interpret, **_dh_price(D, br, isz))
-    nv = V // bv
-    bv_dw = _budget_v_block(V, D, br, isz, interpret,
-                            **_dw_price(D, br, isz))
-    nv_dw = V // bv_dw
+    br, bv = _blocks("bwd", N, D, V, isz, interpret)
+    hp, lp, nr = _pad_rows(h, labels, br)
+    Np = hp.shape[0]
+    nv = pl.cdiv(V, bv)
+    sr = _sub_rows(br)
     lab2 = lp[:, None].astype(jnp.int32)
     # padded rows: lse=0 with z=0 gives p=1 — masked to 0 by the label test
     lse2 = jnp.pad(lses, (0, Np - N))[:, None]
     s = smoothing
     coef = jnp.stack([go + gce, go * (1.0 - s) + gce,
                       go * (s / V), jnp.float32(0.0)])[None, :]
+    operands = (hp, w, lab2, lse2, coef)
 
     f32 = jnp.float32
-    dh = pl.pallas_call(
-        functools.partial(_fx_dh_kernel, bv=bv, nv=nv),
-        grid=(nr, nv),
-        in_specs=[
-            pl.BlockSpec((br, D), lambda i, j: (i, 0)),
-            pl.BlockSpec((D, bv), lambda i, j: (0, j)),
-            pl.BlockSpec((br, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((br, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, 4), lambda i, j: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((br, D), lambda i, j: (i, 0)),
-        out_shape=_pl_out((Np, D), h.dtype, hp, w, lab2, lse2, coef),
-        scratch_shapes=[pltpu.VMEM((br, D), f32)],
-        interpret=interpret,
-        name="fused_xent_dh",
-    )(hp, w, lab2, lse2, coef)
-
-    dw = pl.pallas_call(
-        functools.partial(_fx_dw_kernel, bv=bv_dw, nr=nr),
-        grid=(nv_dw, nr),
+    col = pl.BlockSpec((br, 1), lambda j, i: (i, 0))
+    dh, dw = pl.pallas_call(
+        functools.partial(_fx_dh_dw_kernel, bv=bv, nv=nv, V=V,
+                          smoothing=smoothing),
+        grid=(nv, nr),
         in_specs=[
             pl.BlockSpec((br, D), lambda j, i: (i, 0)),
-            pl.BlockSpec((D, bv_dw), lambda j, i: (0, j)),
-            pl.BlockSpec((br, 1), lambda j, i: (i, 0)),
-            pl.BlockSpec((br, 1), lambda j, i: (i, 0)),
+            pl.BlockSpec((D, bv), lambda j, i: (0, j)),
+            col,
+            col,
             pl.BlockSpec((1, 4), lambda j, i: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((D, bv_dw), lambda j, i: (0, j)),
-        out_shape=_pl_out((D, V), f32, hp, w, lab2, lse2, coef),
-        scratch_shapes=[pltpu.VMEM((D, bv_dw), f32)],
+        out_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                   pl.BlockSpec((D, bv), lambda j, i: (0, j))],
+        out_shape=[_pl_out((Np, D), f32, *operands),
+                   _pl_out((D, V), f32, *operands)],
+        scratch_shapes=[pltpu.VMEM((sr, bv), f32),
+                        pltpu.VMEM((br, bv), h.dtype),
+                        pltpu.VMEM((br, D), f32),
+                        pltpu.SemaphoreType.DMA((2, br // sr))],
         interpret=interpret,
-        name="fused_xent_dw",
-    )(hp, w, lab2, lse2, coef)
-
+        name="fused_xent_dh_dw",
+        **grid_params(
+            interpret, "arbitrary", "arbitrary",
+            vmem_limit_bytes=vmem_limit_bytes(
+                _held_vmem_bytes("bwd", br, bv, D, isz))),
+    )(*operands)
     return dh[:N], dw

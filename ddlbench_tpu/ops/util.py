@@ -63,6 +63,43 @@ def takes_pallas(backend: str, forced: str, *operands) -> bool:
     return is_tpu_backend() and pallas_partitions_safely(*operands)
 
 
+# What a kernel may plan to hold in a v5e TensorCore's 128 MiB of VMEM, as its
+# own accounting sums it (flash_attention._resident_vmem_bytes,
+# fused_xent._held_vmem_bytes): half. The other half takes the kernel's margin
+# (vmem_limit_bytes: a limit of up to 80 MiB) and what XLA keeps in VMEM across
+# the call. Set by room, not by a measured loss: no flash shape that compiles
+# ran slower resident (PERF.md, PR 28: 61.2 MiB at T 32768 dh 64 and 58.1 MiB
+# at T 16384 192/128 beat streaming 1.9x forward and 2.0-2.4x backward).
+RESIDENT_VMEM_BUDGET = (128 << 20) // 2
+
+
+def tile_bytes(rows: int, cols: int, itemsize: int) -> int:
+    """Bytes of a [rows, cols] array in VMEM: the lanes padded to 128, the
+    sublanes to a whole tile (8 rows of 32 bits: 16 of bf16)."""
+    up = lambda n, m: -(-n // m) * m
+    return up(rows, 8 * max(1, 4 // itemsize)) * up(cols, 128) * itemsize
+
+
+def vmem_limit_bytes(held: int) -> int:
+    """The ``vmem_limit_bytes`` of a kernel that holds ``held`` by its own
+    accounting: a quarter more, for Mosaic's own scratch and for the shapes
+    at which the accounting reads under Mosaic's report."""
+    return held + held // 4
+
+
+def grid_params(interpret: bool, *semantics: str, vmem_limit_bytes=None):
+    """Mosaic grid hints: "parallel" grid axes are independent, an
+    "arbitrary" one is sequential — it carries an accumulator across its
+    steps (a streamed inner dimension; the one-pass backwards' outer axis,
+    across which dQ / dh accumulate). No-op under interpret (CPU tests)."""
+    if interpret:
+        return {}
+    from jax.experimental.pallas import tpu as pltpu
+
+    return {"compiler_params": pltpu.CompilerParams(
+        dimension_semantics=semantics, vmem_limit_bytes=vmem_limit_bytes)}
+
+
 def pick_block(t: int, preferred: int, unit: int = 1):
     """Largest divisor of ``t`` that is <= preferred and a multiple of
     ``unit`` (block shapes must tile the dimension). Returns None when t is

@@ -113,35 +113,114 @@ def test_pallas_kernels_match_reference(smoothing):
             np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
 
 
-def test_pallas_multiblock_v():
-    """V spanning several v-blocks: online-logsumexp across the sweep."""
+def _caps(monkeypatch, rows, cols, sub):
+    """The kernels' block caps, small enough that a test's shape spans
+    several row blocks, vocabulary blocks and z tiles."""
     from ddlbench_tpu.ops import fused_xent as fx
 
-    old = fx.V_BLOCK, fx.ROW_BLOCK
-    fx.V_BLOCK, fx.ROW_BLOCK = 32, 16
-    try:
-        k = jax.random.key(4)
-        kh, kw, kl = jax.random.split(k, 3)
-        n, D, V = 33, 8, 160  # 5 v-blocks, 3 row blocks (padded)
-        h = jax.random.normal(kh, (n, D), jnp.float32)
-        w = jax.random.normal(kw, (D, V), jnp.float32) * 0.5
-        labels = jax.random.randint(kl, (n,), 0, V).at[5].set(-1)
-        obj, ce, corr = fused_linear_xent(h, w, labels, 0.1, 512,
-                                          "pallas", True)
-        obj_r, ce_r, corr_r = _ref(h, w, labels, 0.1)
-        np.testing.assert_allclose(obj, obj_r, rtol=1e-5)
-        np.testing.assert_allclose(ce, ce_r, rtol=1e-5)
-        assert int(corr) == int(corr_r)
-        gp = jax.grad(
-            lambda h, w: fused_linear_xent(h, w, labels, 0.1, 512,
-                                           "pallas", True)[0],
-            argnums=(0, 1))(h, w)
-        gr = jax.grad(lambda h, w: _ref(h, w, labels, 0.1)[0],
-                      argnums=(0, 1))(h, w)
-        for a, b in zip(gp, gr):
-            np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
-    finally:
-        fx.V_BLOCK, fx.ROW_BLOCK = old
+    monkeypatch.setattr(fx, "ROW_BLOCK", rows)
+    monkeypatch.setattr(fx, "V_BLOCK", cols)
+    monkeypatch.setattr(fx, "SUB_ROWS", sub)
+    return fx
+
+
+def _pallas_parity(h, w, labels, smoothing, tol=1e-5):
+    """Interpret-mode kernels against the dense reference (values, top-1)
+    and against the chunked-XLA scan (both cotangents, alone and mixed)."""
+    f32 = jnp.float32
+
+    def f_pl(h, w):
+        return fused_linear_xent(h, w, labels, smoothing, 512, "pallas", True)
+
+    def f_xla(h, w):
+        return fused_linear_xent(h, w, labels, smoothing, 8, "xla")
+
+    obj, ce, corr = f_pl(h, w)
+    obj_r, ce_r, corr_r = _ref(h.astype(f32), w.astype(f32), labels, smoothing)
+    np.testing.assert_allclose(obj, obj_r, rtol=tol)
+    np.testing.assert_allclose(ce, ce_r, rtol=tol)
+    assert int(corr) == int(corr_r)
+    for go, gce in ((1.0, 0.0), (0.0, 1.0), (0.7, 0.3)):
+        def mixed(f):
+            return lambda h, w: go * f(h, w)[0] + gce * f(h, w)[1]
+
+        gp, gx = (jax.grad(mixed(f), argnums=(0, 1))(h, w)
+                  for f in (f_pl, f_xla))
+        for a, b in zip(gp, gx):
+            a, b = np.asarray(a, f32), np.asarray(b, f32)
+            assert not np.isnan(a).any()
+            np.testing.assert_allclose(a, b, rtol=20 * tol, atol=10 * tol)
+
+
+def _head(n, D, V, dtype=jnp.float32, seed=4):
+    kh, kw, kl = jax.random.split(jax.random.key(seed), 3)
+    h = jax.random.normal(kh, (n, D), jnp.float32).astype(dtype)
+    w = (jax.random.normal(kw, (D, V), jnp.float32) * 0.5).astype(dtype)
+    labels = jax.random.randint(kl, (n,), 0, V).at[5::7].set(-1)
+    return h, w, labels
+
+
+def test_pallas_multiblock_v(monkeypatch):
+    """V spanning several v-blocks, rows several row blocks (padded): the
+    per-lane running statistics across the sweep, dh summed across the
+    vocabulary blocks, dW across the row blocks."""
+    fx = _caps(monkeypatch, 16, 32, 256)
+    n, D, V = 33, 8, 160  # 5 v-blocks, 3 row blocks (padded)
+    assert fx._blocks("fwd", n, D, V, 4, True) == (16, 32)
+    assert fx._blocks("bwd", n, D, V, 4, True) == (16, 32)
+    _pallas_parity(*_head(n, D, V), 0.1)
+
+
+@pytest.mark.parametrize("smoothing", [0.0, 0.1])
+@pytest.mark.parametrize("V,cols,blocks,dtype", [
+    (7 * 128, 256, (4, 256), jnp.float32),   # the last block holds 128 of 256
+    (7 * 128, 256, (4, 256), jnp.bfloat16),
+    (5 * 128, 384, (2, 384), jnp.float32),   # ... 256 of 384
+    (3 * 131, 16, (25, 16), jnp.float32),    # 50304 = 3 * 131 * 128 in small
+    (3 * 131, 48, (9, 44), jnp.float32),     # evened out: 9 x 44, the last 41
+])
+def test_pallas_last_vocabulary_block_is_cut(monkeypatch, smoothing, V, cols,
+                                             blocks, dtype):
+    """The vocabulary block need not divide V. What Pallas pads the cut
+    block with is NaN in interpret mode: a statistic, a dz or a W column
+    past V that leaked would show in every number compared here."""
+    fx = _caps(monkeypatch, 16, cols, 8)  # two z tiles a row block
+    n, D = 40, 16  # 3 row blocks, the last one padded
+    bv = fx._blocks("bwd", n, D, V, 4, True)[1]
+    assert (-(-V // bv), bv) == blocks and V % bv
+    _pallas_parity(*_head(n, D, V, dtype), smoothing,
+                   tol=2e-2 if dtype == jnp.bfloat16 else 1e-5)
+
+
+@pytest.mark.parametrize("row_blocks", [1, 2, 3])
+@pytest.mark.parametrize("sub", [8, 256])
+def test_pallas_backward_row_block_extents(monkeypatch, row_blocks, sub):
+    """dh goes through HBM once per vocabulary block, by the kernel's own
+    DMAs: inner extents 1, 2 and 3 (two steps apart is where a pipelined,
+    aliased accumulator would race on the chip; on the chip the comparison
+    is PERF.md's, PR 30), one z tile a block and two."""
+    _caps(monkeypatch, 16, 128, sub)
+    _pallas_parity(*_head(16 * row_blocks - 3, 8, 3 * 128), 0.1)
+
+
+@pytest.mark.parametrize("cols", [128, 256, 1024])
+def test_pallas_argmax_ties_go_to_the_lowest_column(monkeypatch, cols):
+    """Equal maxima in two lanes of one lane column, in two lane columns of
+    one block (the lower column in the HIGHER lane), and in two vocabulary
+    blocks: ``correct`` counts the label at the lowest column alone, as
+    jnp.argmax does."""
+    _caps(monkeypatch, 16, cols, 8)
+    n, D, V = 16, 8, 1024
+    h = jnp.abs(jax.random.normal(jax.random.key(7), (n, D))) + 0.1
+    tied = [(3, 9), (200, 300), (70, 70 + 512), (130, 131)]
+    for rows, (lo, hi) in zip(range(0, n, 4), tied):
+        w = jnp.zeros((D, V), jnp.float32).at[:, jnp.array([lo, hi])].set(1.)
+        labels = jnp.full((n,), hi, jnp.int32)
+        labels = labels.at[rows:rows + 4].set(lo).at[rows].set(-1)
+        z = h @ w
+        assert bool(jnp.all(z[:, lo] == z[:, hi]))
+        corr = fused_linear_xent(h, w, labels, 0.0, 512, "pallas", True)[2]
+        assert int(corr) == int(_ref(h, w, labels, 0.0)[2]) == 3
 
 
 def test_eval_fusion_matches_reference():
