@@ -7,6 +7,20 @@ window's own call and feed (recording what the comparison needs), settles a
 few more and hands the same object to the window. The rate is all samples of
 all steps completed in the window over the window's seconds, the clock closed
 on ``block_until_ready`` of the last step.
+
+What the check holds on the chip, in float32 copies of the parameters (P):
+before the window closes, NOTHING. The check steps send the optimizer's first
+moment after step 1 and the parameters after step 3 to the host and put
+nothing on the chip, so the run's ``memory_peak_bytes`` is the program's
+alone, at seeding too (the strategy's own initial values are dropped before
+the seed's are made). After the window, with the program freed: ``flat`` is
+made again from the seed, the program's first gradient and change are formed
+from the two host copies and ``flat`` (3 P), then the reference flow holds
+``flat`` (the caller's), ``p``, ``m``, ``v`` while ``loss_and_grads``
+computes — 4 P and the reference's own working set — and the gradient with
+them at the optimizer call, which donates ``p``, ``m``, ``v``: 5 P (SGD keeps
+no ``v``: 3 and 4 P). Both first gradients wait on the host and meet one leaf
+at a time.
 """
 
 from __future__ import annotations
@@ -58,36 +72,42 @@ def seeded_state(strategy, seed: int, rules: Dict):
 
     names = [l.name for l in strategy.model.layers]
     ts = strategy.init(jax.random.key(0))
-    specs = weights.flat_specs(ts.params, names)
+    like = ts.params
+    specs = weights.flat_specs(like, names)
+    shardings = jax.tree.map(lambda a: a.sharding, like)
+    like = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+                        like)
+    # the strategy's own initial values go before the seed's are made: the
+    # two are never on the chip together
+    ts = ts._replace(params=None)
     flat = weights.make_weights(seed, specs, rules)
-    params = weights.unflatten(flat, ts.params, names)
-    params = jax.device_put(
-        params, jax.tree.map(lambda a: a.sharding, ts.params))
+    params = jax.device_put(weights.unflatten(flat, like, names), shardings)
     return ts._replace(params=params), specs, names
 
 
-def _leaf_norms(tree, names) -> Dict[str, float]:
+def _leaf_norms(flat) -> Dict[str, float]:
     import jax
     import jax.numpy as jnp
 
-    n = jax.jit(lambda t: jax.tree.map(
-        lambda a: jnp.sqrt(jnp.sum(jnp.square(a.astype(jnp.float32)))), t))(
-            tree)
-    return {k: float(v) for k, v in
-            weights.flat_leaves(jax.device_get(n), names).items()}
+    n = jax.jit(lambda t: {
+        k: jnp.sqrt(jnp.sum(jnp.square(a.astype(jnp.float32))))
+        for k, a in t.items()})(flat)
+    return {k: float(v) for k, v in jax.device_get(n).items()}
 
 
-def first_gradient(opt, p0, hp):
+def first_gradient(moment, initial, hp):
     """The gradient the optimizer got at step 1, from its state after it:
-    SGD's momentum buffer is g + wd*p0, Adam's first moment (1-b1)*(g+wd*p0)."""
-    import jax
-
+    SGD's momentum buffer is g + wd*p0, Adam's first moment (1-b1)*(g+wd*p0).
+    ``moment`` and ``initial`` (p0, read only where the weight decay is not
+    0: x - 0*p0 is x) are flat dicts on the chip."""
+    wd = hp["weight_decay"]
     if hp["optimizer"] == "sgd":
-        return jax.tree.map(lambda m, p: m - hp["weight_decay"] * p,
-                            opt["m"], p0)
-    return jax.tree.map(
-        lambda m, p: m / (1.0 - hp["beta1"]) - hp["weight_decay"] * p,
-        opt["m"], p0)
+        first = lambda m: m
+    else:
+        first = lambda m: m / (1.0 - hp["beta1"])
+    if not wd:
+        return {k: first(m) for k, m in moment.items()}
+    return {k: first(m) - wd * initial[k] for k, m in moment.items()}
 
 
 def reference_numbers(reference, config: Dict, hp: Dict, flat, batches,
@@ -107,22 +127,32 @@ def reference_numbers(reference, config: Dict, hp: Dict, flat, batches,
         full = lg
         lg = lambda P, x, y: full(P, x[rows], y[rows])
     lg = jax.jit(lg)
+    sgd = hp["optimizer"] == "sgd"
+    # p, m and v are this flow's own and are donated: each step's take their
+    # buffers. ``flat`` is the caller's, who reads it again, and never is.
     opt = jax.jit(lambda p, g, m, v, t: (
-        common.sgd_momentum(p, g, m, hp) + (v,) if hp["optimizer"] == "sgd"
-        else common.adam(p, g, m, v, t, hp)))
+        common.sgd_momentum(p, g, m, hp) + (v,) if sgd
+        else common.adam(p, g, m, v, t, hp)), donate_argnums=(0, 2, 3))
     norms = jax.jit(lambda t: {k: jnp.sqrt(jnp.sum(jnp.square(a)))
                                for k, a in t.items()})
-    p = flat
-    m = v = {k: jnp.zeros_like(a) for k, a in flat.items()}
+    p = {k: a.copy() for k, a in flat.items()}
+    m = {k: jnp.zeros_like(a) for k, a in flat.items()}
+    v = {} if sgd else {k: jnp.zeros_like(a) for k, a in flat.items()}
     losses, gnorm, g1, stats1 = [], None, None, None
     for t, (x, y) in enumerate(batches, start=1):
         loss, g, stats = lg(p, x, y)
         losses.append(float(loss))
         if gnorm is None:
             gnorm = {k: float(a) for k, a in jax.device_get(norms(g)).items()}
-            g1, stats1 = g, jax.device_get(stats)
+            stats1 = jax.device_get(stats)
         p, m, v = opt(p, g, m, v, jnp.float32(t))
+        if g1 is None:  # waits on the host, as the program's does
+            g1 = jax.device_get(g)
+        # the next gradient's buffer is taken when its call is made: this
+        # one's is free only once the optimizer has ended
+        jax.block_until_ready(p)
         del g
+    del m, v
     delta = norms({k: p[k] - flat[k] for k in flat})
     return {"losses": losses, "grad_norm": gnorm, "grad": g1,
             "norm_var": stats1,
@@ -132,45 +162,57 @@ def reference_numbers(reference, config: Dict, hp: Dict, flat, batches,
 
 
 def gradient_differences(prog_grad, ref_grad) -> Dict[str, float]:
-    """Per leaf, the norm of (program's first gradient - reference's)."""
+    """Per leaf, the norm of (program's first gradient - reference's). Both
+    wait on the host; one leaf of each is on the chip at a time."""
     import jax
     import jax.numpy as jnp
 
-    ref_grad = dict(ref_grad)
-    like = {k: jax.device_put(jnp.asarray(prog_grad[k]), ref_grad[k].sharding)
-            for k in ref_grad}
-    d = jax.jit(lambda a, b: {k: jnp.sqrt(jnp.sum(jnp.square(a[k] - b[k])))
-                              for k in b})(like, ref_grad)
-    return {k: float(v) for k, v in jax.device_get(d).items()}
+    diff = jax.jit(lambda a, b: jnp.sqrt(jnp.sum(jnp.square(a - b))))
+    return {k: float(diff(prog_grad[k], ref_grad[k])) for k in ref_grad}
 
 
 def first_steps(step_fn, stream, ts, hp, names, lr, mark=lambda _: None):
     """Drive ``ts`` through its first ``CHECK_STEPS`` steps on the window's
-    own call and feed; returns what the comparison reads and the state."""
+    own call and feed; returns what ``check_numbers`` reads, all of it on the
+    host, and the state. The check puts nothing on the chip here: the
+    optimizer's first moment after step 1 and the parameters after the last
+    step are read off the state, and nothing waits but for those steps."""
     import jax
 
-    p0 = jax.tree.map(lambda a: a.copy(), ts.params)
-    losses, grad_norm = [], None
+    losses = []
     for i in range(CHECK_STEPS):
         ts, m = step_fn(ts, *next(stream).batch, lr)
         losses.append(m["loss"])
         if i == 0:
-            g1 = first_gradient(ts.opt, p0, hp)
-            grad_norm = _leaf_norms(g1, names)
-            # the gradient itself waits on the host for the reference's, so
-            # that the window's device memory is the program's alone
-            grad = weights.flat_leaves(jax.device_get(g1), names)
-            del g1
+            moment = weights.flat_leaves(jax.device_get(ts.opt["m"]), names)
             # running variances of the normalization layers after one step
             norm_var = {k: v for k, v in weights.flat_leaves(
                 jax.device_get(ts.model_state), names).items()
                 if k.endswith("/var")}
             mark("first step (compile or cache load)")
-    delta_norm = _leaf_norms(
-        jax.tree.map(lambda a, b: a - b, ts.params, p0), names)
-    return {"losses": [float(x) for x in losses], "grad_norm": grad_norm,
-            "delta_norm": delta_norm, "grad": grad,
+    return {"losses": [float(x) for x in losses], "moment": moment,
+            "params": weights.flat_leaves(jax.device_get(ts.params), names),
             "norm_var": norm_var}, ts
+
+
+def check_numbers(held, flat, hp) -> Dict:
+    """What the comparison reads of the program's first steps, formed once
+    the program is freed from what ``first_steps`` held on the host and
+    ``flat``, the parameters it started from (made again from the seed, laid
+    out as the reference reads them): the first gradient, on the host again,
+    its norm by leaf and the norm of each leaf's change."""
+    import jax
+
+    on_chip = lambda t: {k: jax.device_put(a, flat[k].sharding)
+                         for k, a in t.items()}
+    g1 = first_gradient(on_chip(held["moment"]), flat, hp)
+    grad_norm, grad = _leaf_norms(g1), jax.device_get(g1)
+    del g1
+    delta_norm = _leaf_norms({k: a - flat[k]
+                              for k, a in on_chip(held["params"]).items()})
+    return {"losses": held["losses"], "grad_norm": grad_norm,
+            "delta_norm": delta_norm, "grad": grad,
+            "norm_var": held["norm_var"]}
 
 
 def run(rc) -> Dict:
@@ -201,7 +243,7 @@ def run(rc) -> Dict:
     stream = Prefetcher(data, strategy.shard_batch,
                         depth=cfg.prefetch_depth).stream(epoch=0)
 
-    prog, ts = first_steps(step_fn, stream, ts, hp, names, lr, rc.mark)
+    held, ts = first_steps(step_fn, stream, ts, hp, names, lr, rc.mark)
     for _ in range(SETTLE_STEPS):
         ts, m = step_fn(ts, *next(stream).batch, lr)
     jax.block_until_ready(ts)
@@ -248,6 +290,8 @@ def run(rc) -> Dict:
     if rc.chips > 1:
         batches, flat = rc.spread(batches, flat)
     t_ref = time.perf_counter()
+    prog = check_numbers(held, flat, hp)
+    del held
     ref = reference_numbers(rc.reference, config, hp, flat, batches,
                             "float32")
     print(f"train: {steps} steps in {window_s:.3f}s, input stall "

@@ -20,6 +20,21 @@ verdict (``correct``) and the numbers that ``failed`` it. ``--out FILE`` also
 appends each line there with every leaf's own gaps (``leaves``: leaf ->
 [gradient gap, change gap, is a matrix]), for choosing a number that
 separates the readings.
+
+What is on the chip when (P: one float32 copy of the parameters; the train
+driver's docstring has the rule). A seed's program: its state (16 bytes a
+parameter under Adam) and its activations, nothing of the check beside them:
+the check steps leave the first moment after step 1 and the parameters after
+step 3 on the host. Then the state is dropped, ``flat`` is made from the
+seed, the program's gradient and change are formed from it and the two host
+copies (3 P), and each side follows in turn with 4 P while ``loss_and_grads``
+computes (``flat``, which all sides of a seed share, and the side's own
+``p``, ``m``, ``v``) plus the reference's working set, and 5 P at the
+optimizer call. A side leaves nothing on the chip: its numbers and its first
+gradient (``["grad"]``) are on the host, so the seeds and sides that fit one
+process are bounded by time and by host memory alone (4 bytes a parameter
+for the program's gradient, the sound reference's and the side's being
+judged: 6.8 GB at 560 M parameters), not by the chip.
 """
 
 from __future__ import annotations
@@ -94,7 +109,7 @@ def train(rc, args):
         ts, specs, names = td.seeded_state(strategy, seed, config["weights"])
         stream = Prefetcher(data, strategy.shard_batch,
                             depth=cfg.prefetch_depth).stream(epoch=0)
-        prog, ts = td.first_steps(strategy.train_step, stream, ts, hp, names,
+        held, ts = td.first_steps(strategy.train_step, stream, ts, hp, names,
                                   jnp.float32(hp["lr"]))
         stream.close()
         del ts
@@ -102,6 +117,9 @@ def train(rc, args):
         batches = [data.batch(0, k) for k in range(td.CHECK_STEPS)]
         if rc.chips > 1:
             batches, flat = rc.spread(batches, flat)
+        prog = td.check_numbers(held, flat, hp)
+        del held
+
         def reference(rounding, rows=None):
             return td.reference_numbers(rc.reference, config, hp, flat,
                                         batches, rounding, rows)

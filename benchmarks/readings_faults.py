@@ -14,10 +14,16 @@ with the configuration's limits: the reference at the configuration's
 control precision, the reference over half the batch, and the reference
 with each of its own planted faults. One JSON line a reading, with each
 number, the verdict and the numbers that failed it (every one of these has
-to fail a limit). The sound reference's gradient waits on the host while
-the other side is computed: ``readings.py`` keeps it on the device, which
-is a tenth float32 copy of the parameters where ``reference_numbers``
-already holds nine (kanana-2-30b-a3b: 10 x 1.70 GB do not fit 16.9).
+to fail a limit). No program is built here, so the chip holds one side's
+reference flow at a time and nothing else: ``flat`` and the side's ``p``,
+``m``, ``v`` while ``loss_and_grads`` computes, the gradient with them at
+the optimizer call (``readings.py``'s docstring), every side's numbers and
+first gradient on the host. On a TPU the last line is the process's peak on
+the chip (``what: process_peak``: in use + reserved scratch as ``run_cell``
+reads them, and bytes a parameter), which is what a one-chip training cut is
+sized by; ``--sides none`` takes the sound reference alone, for the peak of
+one reference flow. A process's peak never falls, so it is the peak of the
+largest side taken. Off a TPU there is no such counter and no such line.
 ``--sides`` names which of them to take (default: all of the above);
 ``rounding:<r>`` is a side reading and no fault: the reference itself at
 another compute precision, e.g. ``rounding:bfloat16`` for how far the
@@ -37,6 +43,7 @@ the first batch.
 from __future__ import annotations
 
 import argparse
+import math
 import os
 import sys
 import types
@@ -55,8 +62,8 @@ def main() -> int:
     ap.add_argument("--first-seed", type=int, default=1000)
     ap.add_argument("--sides", default=None,
                     help="comma list of control, half_batch, the reference's "
-                         "FAULTS, rounding:<name>, choices:<name>; default "
-                         "the control, half_batch and the FAULTS")
+                         "FAULTS, rounding:<name>, choices:<name>, or none; "
+                         "default the control, half_batch and the FAULTS")
     ap.add_argument("--steps", type=int, default=None)
     ap.add_argument("--flips", default=None, metavar="ROUNDING")
     ap.add_argument("--out", default=None)
@@ -89,8 +96,8 @@ def main() -> int:
     control = (config["precision"].get("train")
                or config["precision"])["control"]
     faults = tuple(getattr(rc.reference, "FAULTS", ()))
-    wanted = (args.sides.split(",") if args.sides
-              else ["control", "half_batch", *faults])
+    wanted = ([] if args.sides == "none" else args.sides.split(",")
+              if args.sides else ["control", "half_batch", *faults])
     half = slice(0, cfg.global_batch() // 2)
     sides = []
     for side in wanted:
@@ -117,7 +124,6 @@ def main() -> int:
         batches = [data.batch(0, k) for k in range(steps)]
         ref = td.reference_numbers(rc.reference, config, hp, flat, batches,
                                    "float32")
-        ref["grad"] = jax.device_get(ref["grad"])  # off the device meanwhile
         for what, conf, rounding, rows in sides:
             if what.startswith("choices:"):
                 own = jax.jit(lambda P, t: rc.reference.choices(P, t, config))
@@ -125,14 +131,13 @@ def main() -> int:
                     [own(flat, t) for t in batches[0][0]]))
             side = td.reference_numbers(rc.reference, conf, hp, flat,
                                         batches, rounding, rows)
-            against = dict(ref, grad=jax.device_put(ref["grad"]))
-            by = readings.judged(side, against, config["limits"])
+            by = readings.judged(side, ref, config["limits"])
             by = {k: v for k, v in by.items() if not k.startswith(later)}
             by["failed"] = [n for n in by["failed"]
                             if not n.startswith(later)]
             by["correct"] = not by["failed"]
             readings.emit(cell=cell, seed=seed, what=what, steps=steps, **by)
-            del side, against
+            del side
         if args.flips and hasattr(rc.reference, "choices"):
             tokens = batches[0][0][0]
             pick = lambda r: jax.jit(lambda P, t: rc.reference.choices(
@@ -146,6 +151,13 @@ def main() -> int:
                           flipped_share_by_layer=[
                               float(x) for x in 1.0 - jnp.mean(kept, (1, 2))])
         del flat, batches, ref
+    if jax.default_backend() == "tpu":
+        peak, n = rc.read_memory_peak(), sum(
+            math.prod(shape) for shape in specs.values())
+        readings.emit(cell=cell, what="process_peak",
+                      device_kind=rc.device_kind, parameters=n,
+                      peak_bytes=peak, bytes_per_parameter=peak / n,
+                      sides=[side[0] for side in sides])
     return 0
 
 
