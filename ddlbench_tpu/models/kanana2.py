@@ -21,19 +21,16 @@ Per block, with ``h = RMSNorm(x)``:
 ``w`` over all ``top_k`` chosen, and computes the part of the result its own
 experts give; what the absent experts would add is left out (on one chip
 there is no exchange, and no code stands in for one). The routed part is
-dropless: token-slots are sorted by expert, those of held experts gathered
-into a row buffer and multiplied group by group (``grouped_dot``), then
-scattered back weighted. The buffer has room for ``ROW_SLACK`` x the
-balanced number of held slots; the slots of a step whose router sends more
-go through a second buffer, with room for all the rest, in the taken branch
-of a ``lax.cond`` (its other branch hands the sum through), so no token is
-ever dropped and the common step pays for the small buffer only.
+dropless, and everything after the router's choice — sort by expert, gather
+the held slots into a row buffer, the grouped products, the weighted
+scatter, the counters — is ``models/dropless.py``, which ``models/zaya.py``
+shares: this file keeps ``route`` and the tiling of its 768-wide experts.
 
 The arch string carries the share: ``kanana2_30b_a3b`` is the whole model,
 ``kanana2_30b_a3b-l5-e8`` its first 5 layers with experts 0..7 of each
 expert layer held, ``-e8r3`` the eighth-wide share of rank 3 (experts
-24..31). ``parse_arch`` is the one reader of that syntax and ``FAMILY`` the
-one home of the published sizes.
+24..31). ``dropless.parse_share`` is the one reader of that syntax and
+``FAMILY`` the one home of the published sizes.
 
 Serving (a latent paged cache) is not written: the blocks leave
 ``decode``/``paged``/``serve`` unset and ``serve/engine.py`` refuses them.
@@ -42,14 +39,13 @@ Serving (a latent paged cache) is not written: the blocks leave
 from __future__ import annotations
 
 import dataclasses
-import math
-import re
 from typing import List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ddlbench_tpu.models import dropless
 from ddlbench_tpu.models.layers import Layer, LayerModel
 from ddlbench_tpu.models.transformer import _dense_init, causal_attention
 from ddlbench_tpu.telemetry import scopes
@@ -83,39 +79,22 @@ FAMILY = {
         n_shared=2, top_k=6, route_scale=2.448, n_layers=48),
 }
 
-# rows of the grouped products' buffer, over the balanced count of held slots
-ROW_SLACK = 2.0
-ROW_ALIGN = 512
-# (rows, contraction, columns) tile of the Pallas grouped product
+# (rows, contraction, columns) tile of the Pallas grouped product for this
+# family's 768-wide experts
 GMM_TILING = (512, 768, 768)
 
-_ARCH = re.compile(r"^(?P<base>[a-z0-9_]+?)(?:-l(?P<layers>\d+))?"
-                   r"(?:-e(?P<held>\d+)(?:r(?P<rank>\d+))?)?$")
-
-
 def is_family(arch: str) -> bool:
-    m = _ARCH.match(arch)
-    return m is not None and m["base"] in FAMILY
+    return dropless.arch_base(arch) in FAMILY
 
 
 def parse_arch(arch: str) -> Optional[Tuple[Dims, int, Tuple[int, int]]]:
     """``(dims, layers kept, (first held expert, experts held))`` of an arch
-    string of this family, None for any other."""
+    string of this family (``dropless.parse_share`` reads the syntax), None
+    for any other."""
     if not is_family(arch):
         return None
-    m = _ARCH.match(arch)
-    dims = FAMILY[m["base"]]
-    layers = int(m["layers"] or dims.n_layers)
-    count = int(m["held"] or dims.n_experts)
-    rank = int(m["rank"] or 0)
-    if not dims.first_dense <= layers <= dims.n_layers:
-        raise ValueError(f"{arch}: keeps {layers} layers of {dims.n_layers}")
-    if count < 1 or dims.n_experts % count or \
-            (rank + 1) * count > dims.n_experts:
-        raise ValueError(
-            f"{arch}: a share holds n_experts / chips experts "
-            f"({dims.n_experts} experts, {count} asked for, rank {rank})")
-    return dims, layers, (rank * count, count)
+    return dropless.parse_share(
+        arch, FAMILY, FAMILY[dropless.arch_base(arch)].first_dense)
 
 
 # ---------------------------------------------------------------------------
@@ -233,104 +212,12 @@ def route(p, h, dims: Dims):
     return idx, w
 
 
-def buffer_rows(slots: int, dims: Dims, held: int) -> int:
-    """Rows of the common step's buffer: ROW_SLACK x the balanced count of
-    held slots, aligned, and never more than every slot."""
-    rows = ROW_SLACK * slots * held / dims.n_experts
-    rows = int(math.ceil(rows / ROW_ALIGN) * ROW_ALIGN)
-    return min(rows, slots)
-
-
-def grouped_dot(a, w, sizes, interpret: bool = False):
-    """``a[rows of group g] @ w[g]`` for runs of rows ``sizes`` [G] long:
-    a [M, k], w [G, k, n] -> [M, n]; rows past ``sum(sizes)`` are left
-    undefined. On TPU the Pallas grouped product of
-    ``jax.experimental.pallas.ops.tpu.megablox`` (``interpret``: the same
-    kernel off the chip, for tests), elsewhere XLA's ``lax.ragged_dot``.
-    Why not ``ragged_dot`` on the chip too: XLA:TPU rewrites it into a
-    custom call (``ragged-dot-none``) that drops the scope it was traced
-    under, so its device time would read as unscoped; and on one v5e, 8
-    experts x [2048, 768], 12,288 rows of which 6,144 held, forward +
-    backward of the SwiGLU, a host clock (about a millisecond of dispatch
-    in both) read 2.34 ms for this kernel against 3.63 (PERF.md, PR 27)."""
-    from ddlbench_tpu.distributed import is_tpu_backend
-
-    if interpret or is_tpu_backend():
-        from jax.experimental.pallas.ops.tpu.megablox import gmm
-
-        return gmm(a, w, sizes, a.dtype, GMM_TILING, interpret=interpret)
-    return lax.ragged_dot(a, w, sizes, preferred_element_type=a.dtype)
-
-
-def _grouped_swiglu(pe, rows, sizes):
-    """Each held expert's SwiGLU over its run of ``rows`` [M, d]
-    (``sizes``: rows per expert). The grouped products stop at
-    ``sum(sizes)``: what they leave in the rows past it is undefined, so
-    those rows come out nought here, and go in nought so that no gradient
-    comes back through them."""
-    with scopes.scope(scopes.EXPERTS):
-        live = (jnp.arange(rows.shape[0]) < jnp.sum(sizes))[:, None]
-        rows = jnp.where(live, rows, 0)
-        dot = lambda a, w: grouped_dot(a, w.astype(a.dtype), sizes)
-        g = dot(rows, pe["w_gate"])
-        u = dot(rows, pe["w_up"])
-        return jnp.where(live, dot(jax.nn.silu(g) * u, pe["w_down"]), 0)
-
-
 def routed_experts(p, h, dims: Dims, held: Tuple[int, int]):
-    """The held experts' part of ``sum_k w_k E_idx_k(h)`` for h [S, d], and
-    the layer's counters. Deterministic (a stable sort): a rematerialized
-    forward routes as the first one did."""
-    S, d = h.shape
-    k = dims.top_k
-    first, count = held
+    """The held experts' part of ``sum_k w_k E_idx_k(h)`` for h [S, d] by
+    this family's router, and the layer's counters (models/dropless.py)."""
     idx, w = route(p, h, dims)
-    local = idx.reshape(-1) - first  # [S * k]
-    mine = (local >= 0) & (local < count)
-    key = jnp.where(mine, local, count)  # absent experts sort to the end
-    order = jnp.argsort(key, stable=True)  # held slots first, by expert
-    sizes = jnp.sum(key[:, None] == jnp.arange(count)[None, :], axis=0,
-                    dtype=jnp.int32)
-    ends = jnp.cumsum(sizes)
-    n_held = ends[-1]
-    w_flat = jnp.where(mine, w.reshape(-1), 0.0)
-
-    def through(lo: int, rows: int):
-        """acc + the weighted outputs of sorted slots [lo, lo + rows)."""
-        def f(acc, h, w_flat, pe):
-            slot = order[lo:lo + rows]
-            token = slot // k
-            # each expert's run, cut to this window of the sorted order
-            cut = lambda x: jnp.clip(x, lo, lo + rows)
-            y = _grouped_swiglu(pe, jnp.take(h, token, axis=0),
-                                cut(ends) - cut(ends - sizes))
-            y = y.astype(jnp.float32) * jnp.take(w_flat, slot)[:, None]
-            return acc.at[token].add(y)
-        return f
-
-    # the common buffer always; the slots past it, if a step has any, in the
-    # second branch of a cond that otherwise hands the sum through (all the
-    # hot work stays outside the conditional, under its own names)
-    small = buffer_rows(S * k, dims, count)
-    acc = through(0, small)(jnp.zeros((S, d), jnp.float32), h, w_flat,
-                            p["experts"])
-    if small < S * k:
-        # rematerialized in the backward pass: a cond hands every residual
-        # of either branch out of both, so the other branch would fill the
-        # large buffers' residuals with zeros on every step (measured: 3.3
-        # ms a layer, PERF.md PR 27)
-        acc = lax.cond(n_held > small,
-                       jax.checkpoint(through(small, S * k - small)),
-                       lambda acc, *_: acc, acc, h, w_flat, p["experts"])
-    y = acc.astype(h.dtype)
-    # load over ALL experts, as the router sees it (the held ones are a
-    # sample of it): the fullest expert's slots over the mean expert's
-    load = jnp.sum(jax.nn.one_hot(idx.reshape(-1), dims.n_experts,
-                                  dtype=jnp.float32), axis=0)
-    counters = {"held_slots": n_held.astype(jnp.float32),
-                "load_max_over_mean": jnp.max(load) * dims.n_experts
-                / (S * k)}
-    return y, counters
+    return dropless.routed_experts(p["experts"], h, idx, w, held,
+                                   dims.n_experts, GMM_TILING)
 
 
 # ---------------------------------------------------------------------------

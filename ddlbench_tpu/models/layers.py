@@ -254,6 +254,19 @@ class ServeOps:
 
 
 @dataclasses.dataclass(frozen=True)
+class Tie:
+    """A leaf that a layer reads and another owns: ``layers[layer]`` computes
+    with ``params[owner][owner_key]`` under its own top-level key ``key`` and
+    holds no such leaf itself (a tied output head reads the embedding).
+    Indices are list indices (-1: the last layer)."""
+
+    layer: int
+    key: str
+    owner: int
+    owner_key: str
+
+
+@dataclasses.dataclass(frozen=True)
 class LayerModel:
     """A named flat stack of layers plus metadata the strategies need."""
 
@@ -270,6 +283,25 @@ class LayerModel:
     # the strategies the model is brought up on (RunConfig.validate refuses
     # any other); None: every strategy its input kind allows.
     strategies: Tuple[str, ...] | None = None
+    # leaves shared by two layers. The params tree holds each ONCE, at its
+    # owner; a loss function puts it at its reader too INSIDE what it
+    # differentiates (resolve_ties), so autodiff sums both uses into the one
+    # leaf and the optimizer keeps one slot for it. Strategies that cut the
+    # per-layer list into stages do not resolve ties: a model with ties
+    # names the strategies that do in ``strategies``.
+    ties: Tuple[Tie, ...] = ()
+
+
+def resolve_ties(ties: Sequence[Tie], params):
+    """``params`` (a per-layer list, cast or not) with every tied leaf also
+    where its reader looks for it; ``params`` itself when nothing is tied."""
+    if not ties:
+        return params
+    out = list(params)
+    for t in ties:
+        out[t.layer] = dict(out[t.layer],
+                            **{t.key: params[t.owner][t.owner_key]})
+    return out
 
 
 def init_model(model: LayerModel, key: jax.Array):
@@ -277,7 +309,12 @@ def init_model(model: LayerModel, key: jax.Array):
 
     ``shapes[i]`` is the per-example input shape of layer i; ``shapes[-1]`` is
     the final output shape. These boundary shapes drive pipeline activation
-    buffers and the profiler's activation_size fields.
+    buffers and the profiler's activation_size fields. A boundary that
+    carries more than one array (models/zaya.py: the residual stream and the
+    router's state) is a TUPLE of shapes here, as it is a tuple of arrays
+    in ``apply_slice``; the pipeline strategies and the profiler take one
+    array a boundary, so such a model names ``strategies`` that do not read
+    these (``single``) and ``profiler.profile_model`` refuses it.
     """
     params, states, shapes = [], [], [model.in_shape]
     shape = model.in_shape
@@ -670,15 +707,20 @@ def inverted_residual(name: str, out_ch: int, stride: int, expand: int) -> Layer
 def routing_counters(model_state):
     """The step's routing counters out of a model state, {} for a model
     without expert layers (whose state holds them under ``"moe"``,
-    models/kanana2.expert_block): held slots summed over the layers, the
-    load ratio of the most uneven layer."""
+    models/kanana2.expert_block, models/zaya.hybrid_block): held slots
+    summed over the layers, the load ratio of the most uneven layer and,
+    where the layers count it, the mean weight of the one expert chosen."""
     found = [s["moe"] for s in model_state
              if isinstance(s, dict) and "moe" in s]
     if not found:
         return {}
-    return {"moe_held_slots": sum(c["held_slots"] for c in found),
-            "moe_load_max_over_mean": jnp.max(jnp.stack(
-                [c["load_max_over_mean"] for c in found]))}
+    out = {"moe_held_slots": sum(c["held_slots"] for c in found),
+           "moe_load_max_over_mean": jnp.max(jnp.stack(
+               [c["load_max_over_mean"] for c in found]))}
+    top1 = [c["top1_weight_mean"] for c in found if "top1_weight_mean" in c]
+    if top1:
+        out["moe_top1_weight_mean"] = sum(top1) / len(top1)
+    return out
 
 
 def param_count(params) -> int:

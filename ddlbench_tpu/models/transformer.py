@@ -194,8 +194,10 @@ def causal_attention(q, k, v, q_offset: int = 0, k_offset: int = 0,
                      prefix_len: int = 0, backend: str = "auto"):
     """Masked attention for blocks of a causal (or prefix-LM) sequence.
 
-    q: [B, H, Tq, Dh]; k: [B, H, Tk, Dh]; v: [B, H, Tk, Dv] (Dv = Dh but
-    for latent attention, models/kanana2.py: 192 and 128); the scale is
+    q: [B, H, Tq, Dh]; k: [B, K, Tk, Dh]; v: [B, K, Tk, Dv] (Dv = Dh but
+    for latent attention, models/kanana2.py: 192 and 128; K = H but for
+    grouped queries, models/zaya.py: query head j attends to key/value head
+    j // (H / K)); the scale is
     1/sqrt(Dh) and the output is Dv wide on both paths. Offsets give each block's absolute
     position so the same primitive serves full attention (offsets 0) and ring
     attention over sequence shards (parallel/sp.py). ``prefix_len`` > 0 adds
@@ -214,6 +216,9 @@ def causal_attention(q, k, v, q_offset: int = 0, k_offset: int = 0,
         return flash_attention(q, k, v, q_offset, k_offset, prefix_len,
                                interpret=interpret)
     dh = q.shape[-1]
+    if k.shape[1] != q.shape[1]:  # grouped queries: a group's heads share
+        k, v = (jnp.repeat(t, q.shape[1] // t.shape[1], axis=1)
+                for t in (k, v))
     scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) / math.sqrt(dh)
     q_pos = q_offset + jnp.arange(q.shape[2])[:, None]
     k_pos = k_offset + jnp.arange(k.shape[2])[None, :]

@@ -18,21 +18,31 @@ MODEL_NAMES = ("resnet18", "resnet50", "resnet152", "vgg11", "vgg16",
                "densenet121", "inception", "nasnet", "transformer_t",
                "transformer_s",
                "transformer_m", "transformer_moe_s", "seq2seq_s", "seq2seq_m",
-               "seq2seq_lstm_s", "kanana2_30b_a3b")
+               "seq2seq_lstm_s", "kanana2_30b_a3b", "zaya1_8b")
 
-ARCH_HELP = ("one of MODEL_NAMES; kanana2_30b_a3b may carry the share one "
-             "chip holds: -l<layers kept>, -e<experts held>[r<rank>], e.g. "
-             "kanana2_30b_a3b-l5-e8 (models/kanana2.py)")
+ARCH_HELP = ("one of MODEL_NAMES; kanana2_30b_a3b and zaya1_8b may carry the "
+             "share one chip holds: -l<layers kept>, -e<experts held>"
+             "[r<rank>], e.g. kanana2_30b_a3b-l5-e8, zaya1_8b-l5-e8r1 "
+             "(models/kanana2.py, models/zaya.py: each routes its own way, "
+             "models/dropless.py computes the held experts' part for both)")
+
+
+def _share_family(arch: str):
+    """The module of the family whose arch strings carry a chip's share
+    (``is_family``, ``parse_arch``, ``build``), None for any other arch."""
+    from ddlbench_tpu.models import kanana2, zaya
+
+    return next((m for m in (kanana2, zaya) if m.is_family(arch)), None)
 
 
 def arch_name(arch: str) -> str:
     """``arch`` if this registry can build it (the argparse ``type`` of
     every ``--model``/``--arch`` option), else a ValueError naming what it
     can."""
-    from ddlbench_tpu.models import kanana2
-
-    if arch in MODEL_NAMES or kanana2.is_family(arch):
-        kanana2.parse_arch(arch)  # a share the family cannot cut raises
+    family = _share_family(arch)
+    if family is not None:
+        family.parse_arch(arch)  # a share the family cannot cut raises
+    if arch in MODEL_NAMES or family is not None:
         return arch
     raise ValueError(f"unknown arch {arch!r}; known: {MODEL_NAMES}")
 
@@ -42,8 +52,8 @@ def collects_aux_loss(arch: str) -> bool:
     capacity and load-balance loss are statistics of the whole routed batch,
     collected through a trace-time sink — what a checkpointed layer cannot
     let out and a shard_map over the batch would make per-shard. (The
-    dropless sigmoid router of models/kanana2.py routes token by token and
-    collects nothing.)"""
+    dropless routers of models/kanana2.py and models/zaya.py route token by
+    token and collect nothing.)"""
     from ddlbench_tpu.models.moe import _VARIANTS
 
     return arch in _VARIANTS
@@ -70,13 +80,12 @@ def get_model(arch: str, dataset: str | DatasetSpec,
 
         return build_seq2seq(arch, spec.image_size, spec.num_classes,
                              spec.src_len, attention_backend)
-    from ddlbench_tpu.models import kanana2
-
-    if kanana2.is_family(arch):
+    family = _share_family(arch)
+    if family is not None:
         if spec.kind != "tokens":
             raise ValueError(f"{arch} requires a token dataset, got {spec.name}")
-        return kanana2.build(arch, spec.image_size, spec.num_classes,
-                             attention_backend)
+        return family.build(arch, spec.image_size, spec.num_classes,
+                            attention_backend)
     if arch.startswith("transformer"):
         if spec.kind != "tokens":
             raise ValueError(f"{arch} requires a token dataset, got {spec.name}")

@@ -532,6 +532,23 @@ def _bh(x):
     return x.reshape(B * H, T, dh)
 
 
+def _kv_row(q, k, v):
+    """Row of the folded [B * K, T, .] keys and values that row ``b`` of the
+    folded [B * H, T, .] queries reads: query head j attends to key/value
+    head j // (H / K). With as many key/value heads as query heads it is
+    ``b`` itself, and the kernels' index maps are what they were."""
+    H, K = q.shape[1], k.shape[1]
+    if v.shape[1] != K or H % K:
+        raise ValueError(
+            f"flash_attention: {H} query heads over {K} key and "
+            f"{v.shape[1]} value heads; k and v share a head count that "
+            f"divides q's")
+    if K == H:
+        return lambda b: b
+    G = H // K
+    return lambda b: b // H * K + b % H // G
+
+
 @functools.partial(
     jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9)
 )
@@ -540,6 +557,15 @@ def flash_attention(q, k, v, q_offset=0, k_offset=0, prefix_len=0,
     """Causal / prefix-LM attention, [B, H, T, dh] -> [B, H, Tq, dh], fused.
     q and k share a width (which sets the scale, 1/sqrt of it); v and the
     output may have another (latent attention: q/k 192 wide, v 128).
+    **Head counts:** q is [B, H, Tq, dh]; k and v are [B, K, Tk, .] with ONE
+    count K that divides H, and query head j attends to key/value head
+    j // (H / K) (grouped queries; K == H is plain multi-head attention and
+    lowers to the same kernels as before K could differ). Nothing is
+    repeated in HBM: the kernels' index maps send the H / K query heads of
+    a group to the same K, V blocks (the resident forward fetches a group's
+    K, V once, its block index standing still over the group's rows); the
+    backward writes dK, dV per QUERY head and XLA sums each group's, in
+    float32, into the [B, K, Tk, .] gradients.
 
     Semantics match models/transformer.py causal_attention (including the
     q_offset/k_offset absolute-position convention and the prefix-LM rule:
@@ -573,6 +599,7 @@ def _flash_fwd_impl(q, k, v, q_offset, k_offset, prefix_len, block_q, block_k,
     num_q, num_k = Tq // bq, Tk // bk
     scale = 1.0 / math.sqrt(dh)
     qr, kr, vr = _bh(q), _bh(k), _bh(v)
+    kv = _kv_row(q, k, v)
     BH = B * H
     isz = q.dtype.itemsize
     streaming = _use_streaming(Tk, dh, isz, bq, bk, stream,
@@ -586,8 +613,8 @@ def _flash_fwd_impl(q, k, v, q_offset, k_offset, prefix_len, block_q, block_k,
         grid = (BH, num_q, num_k)
         in_specs = [
             pl.BlockSpec((1, bq, dh), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bk, dh), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bk, dv), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, bk, dh), lambda b, i, j: (kv(b), j, 0)),
+            pl.BlockSpec((1, bk, dv), lambda b, i, j: (kv(b), j, 0)),
         ]
         out_specs = [
             pl.BlockSpec((1, bq, dv), lambda b, i, j: (b, i, 0)),
@@ -604,8 +631,8 @@ def _flash_fwd_impl(q, k, v, q_offset, k_offset, prefix_len, block_q, block_k,
         grid = (BH, num_q)
         in_specs = [
             pl.BlockSpec((1, bq, dh), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, Tk, dh), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, Tk, dv), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((1, Tk, dh), lambda b, i: (kv(b), 0, 0)),
+            pl.BlockSpec((1, Tk, dv), lambda b, i: (kv(b), 0, 0)),
         ]
         out_specs = [
             pl.BlockSpec((1, bq, dv), lambda b, i: (b, i, 0)),
@@ -668,17 +695,32 @@ def _flash_bwd_core(q_offset, k_offset, prefix_len, block_q, block_k,
     if g_lse is not None:
         delta = delta - g_lse.astype(jnp.float32)
     qr, kr, vr, gr = _bh(q), _bh(k), _bh(v), _bh(g)
+    kv = _kv_row(q, k, v)
+    K = k.shape[1]
     f32 = jnp.float32
     shape4 = lambda x, T: x.reshape(B, H, T, x.shape[-1])
-    grad_of = lambda x: _out_struct(x.shape, x.dtype, qr, kr, vr, gr)
+    # dK and dV leave the kernels per QUERY head ([B * H, Tk, .]); each
+    # group's H / K are summed here, in float32 (nothing to sum at K == H)
+    grad_of = lambda x: _out_struct((BH,) + x.shape[1:], x.dtype, qr, kr, vr,
+                                    gr)
+
+    def kv_grad(x):
+        x = shape4(x, Tk)
+        if K == H:
+            return x
+        return jnp.sum(x.reshape(B, K, H // K, Tk, -1).astype(f32),
+                       axis=2).astype(x.dtype)
+
     kw = dict(scale=1.0 / math.sqrt(dh), q_offset=q_offset,
               k_offset=k_offset, prefix_len=prefix_len)
 
     # the one-pass kernel keeps the Q side resident: Q, dO, lse, delta, dQ
     if not _use_streaming(Tq, dh, isz, bq, bk, stream, backward=True,
                           interpret=interpret, dv=dv):
-        k_blk = pl.BlockSpec((1, bk, dh), lambda b, j: (b, j, 0))
-        v_blk = pl.BlockSpec((1, bk, dv), lambda b, j: (b, j, 0))
+        k_blk = pl.BlockSpec((1, bk, dh), lambda b, j: (kv(b), j, 0))
+        v_blk = pl.BlockSpec((1, bk, dv), lambda b, j: (kv(b), j, 0))
+        dk_blk = pl.BlockSpec((1, bk, dh), lambda b, j: (b, j, 0))
+        dv_blk = pl.BlockSpec((1, bk, dv), lambda b, j: (b, j, 0))
         q_all = pl.BlockSpec((1, Tq, dh), lambda b, j: (b, 0, 0))
         do_all = pl.BlockSpec((1, Tq, dv), lambda b, j: (b, 0, 0))
         row = pl.BlockSpec((1, 1, Tq), lambda b, j: (b, 0, 0))
@@ -687,7 +729,7 @@ def _flash_bwd_core(q_offset, k_offset, prefix_len, block_q, block_k,
                               num_k=num_k, **kw),
             grid=(BH, num_k),
             in_specs=[k_blk, v_blk, q_all, do_all, row, row],
-            out_specs=[q_all, k_blk, v_blk],
+            out_specs=[q_all, dk_blk, dv_blk],
             out_shape=[grad_of(qr), grad_of(kr), grad_of(vr)],
             scratch_shapes=[pltpu.VMEM((dh, Tq), f32)],
             interpret=interpret,
@@ -699,7 +741,7 @@ def _flash_bwd_core(q_offset, k_offset, prefix_len, block_q, block_k,
                 vmem_limit_bytes=_vmem_limit_bytes(_resident_vmem_bytes(
                     Tq, dh, dv, isz, bq, bk, backward=True))),
         )(kr, vr, qr, gr, lse, delta.reshape(BH, 1, Tq))
-        return shape4(dq, Tq), shape4(dk, Tk), shape4(dv, Tk)
+        return shape4(dq, Tq), kv_grad(dk), kv_grad(dv)
 
     # the streaming pair reads lse and delta as columns, blockwise
     lse_c, delta_c = lse.reshape(BH, Tq, 1), delta.reshape(BH, Tq, 1)
@@ -707,8 +749,8 @@ def _flash_bwd_core(q_offset, k_offset, prefix_len, block_q, block_k,
     q_blk = pl.BlockSpec((1, bq, dh), lambda b, i, j: (b, i, 0))
     do_blk = pl.BlockSpec((1, bq, dv), lambda b, i, j: (b, i, 0))
     q_col = pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0))
-    k_blk = pl.BlockSpec((1, bk, dh), lambda b, i, j: (b, j, 0))
-    v_blk = pl.BlockSpec((1, bk, dv), lambda b, i, j: (b, j, 0))
+    k_blk = pl.BlockSpec((1, bk, dh), lambda b, i, j: (kv(b), j, 0))
+    v_blk = pl.BlockSpec((1, bk, dv), lambda b, i, j: (kv(b), j, 0))
     dq = pl.pallas_call(
         functools.partial(_dq_kernel_stream, block_k=bk, num_k=num_k, **kw),
         grid=(BH, num_q, num_k),
@@ -722,8 +764,10 @@ def _flash_bwd_core(q_offset, k_offset, prefix_len, block_q, block_k,
     )(qr, kr, vr, gr, lse_c, delta_c)
 
     # the dkv kernel streams Q-side operands: Q, dO, lse, delta
-    k_blk = pl.BlockSpec((1, bk, dh), lambda b, j, i: (b, j, 0))
-    v_blk = pl.BlockSpec((1, bk, dv), lambda b, j, i: (b, j, 0))
+    k_blk = pl.BlockSpec((1, bk, dh), lambda b, j, i: (kv(b), j, 0))
+    v_blk = pl.BlockSpec((1, bk, dv), lambda b, j, i: (kv(b), j, 0))
+    dk_blk = pl.BlockSpec((1, bk, dh), lambda b, j, i: (b, j, 0))
+    dv_blk = pl.BlockSpec((1, bk, dv), lambda b, j, i: (b, j, 0))
     q_blk = pl.BlockSpec((1, bq, dh), lambda b, j, i: (b, i, 0))
     do_blk = pl.BlockSpec((1, bq, dv), lambda b, j, i: (b, i, 0))
     q_col = pl.BlockSpec((1, bq, 1), lambda b, j, i: (b, i, 0))
@@ -731,14 +775,14 @@ def _flash_bwd_core(q_offset, k_offset, prefix_len, block_q, block_k,
         functools.partial(_dkv_kernel_stream, block_q=bq, num_q=num_q, **kw),
         grid=(BH, num_k, num_q),
         in_specs=[k_blk, v_blk, q_blk, do_blk, q_col, q_col],
-        out_specs=[k_blk, v_blk],
+        out_specs=[dk_blk, dv_blk],
         out_shape=[grad_of(kr), grad_of(vr)],
         scratch_shapes=[pltpu.VMEM((bk, dh), f32), pltpu.VMEM((bk, dv), f32)],
         interpret=interpret,
         name="flash_attn_dkv",
         **_grid_params(interpret, *semantics),
     )(kr, vr, qr, gr, lse_c, delta_c)
-    return shape4(dq, Tq), shape4(dk, Tk), shape4(dv, Tk)
+    return shape4(dq, Tq), kv_grad(dk), kv_grad(dv)
 
 
 flash_attention.defvjp(_flash_fwd, _flash_bwd)

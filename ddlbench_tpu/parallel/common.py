@@ -755,7 +755,10 @@ def eval_metrics(model, cfg, params, model_state, x, y, compute_dtype):
     """Shared eval step core for single/dp/tp/fsdp: returns the metric dict
     {loss, correct, correct5, count}. Uses the fused head path (no [N, V]
     logits) when available and enabled."""
-    p = cast_params(params, compute_dtype, model.layers)
+    from ddlbench_tpu.models.layers import apply_model, resolve_ties
+
+    p = resolve_ties(model.ties, cast_params(params, compute_dtype,
+                                             model.layers))
     xc = cast_input(x, compute_dtype)
     if cfg.fused_head_loss and model.layers[-1].fused_eval is not None:
         ce_sum, correct, correct5, count = fused_head_eval_sums(
@@ -763,8 +766,6 @@ def eval_metrics(model, cfg, params, model_state, x, y, compute_dtype):
         loss = ce_sum / jnp.maximum(1.0, count.astype(jnp.float32))
         return {"loss": loss, "correct": correct, "correct5": correct5,
                 "count": count}
-    from ddlbench_tpu.models.layers import apply_model
-
     logits, _ = apply_model(model, p, model_state, xc, False)
     correct, count = correct_and_count(logits, y)
     return {
@@ -791,10 +792,13 @@ def loss_with_moe_aux(model, params, model_state, x, y, train, compute_dtype,
     (single/dp/tp/fsdp); sp/ep inline the same pattern because their aux terms
     need a psum over the shard_map axis first.
     """
-    from ddlbench_tpu.models.layers import apply_model
+    from ddlbench_tpu.models.layers import apply_model, resolve_ties
     from ddlbench_tpu.models.moe import collect_aux_losses
 
-    p = cast_params(params, compute_dtype, model.layers)
+    # a tied leaf goes to its reader HERE, inside what the caller
+    # differentiates: both uses' gradients meet in the one leaf
+    p = resolve_ties(model.ties, cast_params(params, compute_dtype,
+                                             model.layers))
     xc = cast_input(x, compute_dtype)
     aux: list = []
     if fused and train and head_fusable(model):

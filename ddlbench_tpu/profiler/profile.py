@@ -115,6 +115,12 @@ def profile_model(
     key = jax.random.key(seed + 1)
     for idx, layer in enumerate(model.layers):
         in_shape, out_shape = shapes[idx], shapes[idx + 1]
+        if any(isinstance(n, tuple) for n in (*in_shape, *out_shape)):
+            raise ValueError(
+                f"profile_model: {model.name}'s layer {layer.name!r} takes "
+                f"or hands on more than one array a boundary "
+                f"({in_shape} -> {out_shape}); a node of the profiled graph "
+                f"carries one array")
         p, s = params_list[idx], state_list[idx]
         key, sub = jax.random.split(key)
         if idx == 0 and model.input_kind == "tokens":

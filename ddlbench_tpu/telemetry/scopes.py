@@ -46,9 +46,20 @@ KINDS = (CONV, BN, POOL, FC, EMBED, LN, ATTN, MLP, HEAD, LOSS)
 # (benchmarks/metrics/readers/scope_part_ms.py): the innermost token of
 # KINDS + PARTS on an instruction's path decides what it counts as.
 LATENT = "latent"    # kanana2: kv_a, the latent's norm, kv_b, k and v assembled
-ROUTE = "route"      # kanana2: router, top-k, sort, gather, weighted scatter
-EXPERTS = "experts"  # kanana2: the grouped products over the experts held
+ROUTE = "route"      # router, top-k, sort, gather, weighted scatter
+EXPERTS = "experts"  # the grouped products over the experts held (dropless)
 PARTS = (LATENT, ROUTE, EXPERTS)
+# two parts INSIDE a kind or a part above. They are not in PARTS (an accepted
+# test of tests/benchmark/ holds PARTS to the three that the accepted part
+# metrics list): a metric whose file lists them reads the innermost, one
+# whose file lists the three counts them as the scope around them (cca_mix
+# as attn, router as route).
+# zaya, inside attn: value shift, q-k mean, both causal convolutions, the L2
+# normalisation (the projections, RoPE, the flash kernels, W_o stay attn)
+CCA_MIX = "cca_mix"
+# zaya, inside route: W_d, the carried state, the MLP, softmax, argmax (sort,
+# gather and weighted scatter stay route)
+ROUTER = "router"
 
 # step phases outside the differentiated model
 OPTIMIZER = "optimizer"  # common.make_optimizer's update

@@ -30,7 +30,7 @@ jax.config.update("jax_platforms", "cpu")
 
 import pytest  # noqa: E402
 
-from ddlbench_tpu.models import kanana2  # noqa: E402
+from ddlbench_tpu.models import kanana2, zaya  # noqa: E402
 
 # The test size of the kanana2 family (tests/test_kanana2.py, the rehearsal
 # configuration under tests/benchmark/data/kanana2): every code path, 1-core
@@ -39,6 +39,13 @@ kanana2.FAMILY["kanana2_t"] = kanana2.Dims(
     d_model=64, n_heads=4, qk_nope=16, qk_rope=8, v_head=16, kv_latent=32,
     dense_ff=96, expert_ff=24, n_experts=16, n_shared=2, top_k=3,
     route_scale=2.448, n_layers=3)
+
+# The test size of the zaya family (tests/test_zaya.py, the rehearsal
+# configuration under tests/benchmark/data/zaya): 4 query heads over 2
+# key/value heads, both convolutions, the carried router state, 8 experts.
+zaya.FAMILY["zaya_t"] = zaya.Dims(
+    d_model=64, n_heads=4, n_kv_heads=2, head_dim=16, conv_taps=(2, 2),
+    rotary=8, router_dim=32, expert_ff=48, n_experts=8, n_layers=3)
 
 
 def pytest_addoption(parser):

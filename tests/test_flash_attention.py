@@ -627,3 +627,127 @@ def test_auto_rule_gradients_on_each_side_of_the_budget(T, bwd_streams,
         for a, b, name in zip(got_vjp(g), ref_vjp(g), "qkv"):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        atol=5e-5, err_msg=f"d{name}")
+
+
+# ---------------------------------------------------------------------------
+# fewer key/value heads than query heads (grouped queries: models/zaya.py)
+# ---------------------------------------------------------------------------
+
+GROUPED = dict(B=2, H=8, K=2, T=128, d=16)
+
+
+def _grouped_qkv(seed=0, H=GROUPED["H"], K=GROUPED["K"]):
+    s = GROUPED
+    ks = jax.random.split(jax.random.key(seed), 4)
+    q = _rand((s["B"], H, s["T"], s["d"]), ks[0])
+    k = _rand((s["B"], K, s["T"], s["d"]), ks[1])
+    v = _rand((s["B"], K, s["T"], s["d"]), ks[2])
+    g = _rand((s["B"], H, s["T"], s["d"]), ks[3])
+    return q, k, v, g
+
+
+def _grouped_einsum(q, k, v):
+    """Query head j on key/value head j // (H / K), the groups written out."""
+    G = q.shape[1] // k.shape[1]
+    return _einsum_attention(q, jnp.repeat(k, G, axis=1),
+                             jnp.repeat(v, G, axis=1))
+
+
+@pytest.mark.parametrize("stream", [False, True],
+                         ids=["resident", "streaming"])
+@pytest.mark.parametrize("H,K", [(8, 2), (4, 1), (6, 3)])
+def test_grouped_queries_forward_and_gradients(H, K, stream):
+    """8 query heads over 2 key/value heads (and 4 over 1, 6 over 3): the
+    forward and all three gradients of both grid designs against the
+    einsum with the groups written out; dK and dV come back K-headed, each
+    the sum over its group's query heads."""
+    q, k, v, g = _grouped_qkv(H + K, H, K)
+    with jax.default_matmul_precision("highest"):
+        ref, ref_vjp = jax.vjp(_grouped_einsum, q, k, v)
+        got, got_vjp = jax.vjp(
+            lambda q, k, v: flash_attention(q, k, v, 0, 0, 0, 32, 32, True,
+                                            stream), q, k, v)
+        assert got.shape == q.shape
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   atol=2e-5)
+        for a, b, name in zip(got_vjp(g), ref_vjp(g), "qkv"):
+            assert a.shape == b.shape
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=1e-4, err_msg=f"d{name}")
+
+
+def test_grouped_queries_through_the_dispatch():
+    """causal_attention's XLA path takes the two head counts too, and the
+    forced kernel agrees with it, forward and gradients."""
+    q, k, v, g = _grouped_qkv(1)
+    with jax.default_matmul_precision("highest"):
+        xla, xla_vjp = jax.vjp(causal_attention, q, k, v)
+        np.testing.assert_allclose(np.asarray(xla),
+                                   np.asarray(_grouped_einsum(q, k, v)),
+                                   atol=2e-5)
+        got, got_vjp = jax.vjp(functools.partial(
+            transformer.causal_attention, backend="flash"), q, k, v)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(xla),
+                                   atol=2e-5)
+        for a, b, name in zip(got_vjp(g), xla_vjp(g), "qkv"):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=1e-4, err_msg=f"d{name}")
+
+
+def test_grouped_queries_in_bfloat16_sum_each_group_in_float32():
+    """The per-query-head dK, dV leave the kernel in bfloat16; the group's
+    sum is taken in float32 and rounded once."""
+    q, k, v, g = (t.astype(jnp.bfloat16) for t in _grouped_qkv(2))
+    f = lambda q, k, v: flash_attention(q, k, v, 0, 0, 0, 32, 32, True)
+    dk = jax.grad(lambda *a: jnp.sum(f(*a).astype(jnp.float32) * g),
+                  argnums=1)(q, k, v)
+    wide = jax.grad(lambda *a: jnp.sum(f(*a).astype(jnp.float32) * g),
+                    argnums=1)(q, jnp.repeat(k, 4, axis=1),
+                               jnp.repeat(v, 4, axis=1))
+    want = jnp.sum(wide.reshape(2, 2, 4, 128, 16).astype(jnp.float32),
+                   axis=2).astype(jnp.bfloat16)
+    assert dk.dtype == jnp.bfloat16 and dk.shape == k.shape
+    np.testing.assert_array_equal(np.asarray(dk.astype(jnp.float32)),
+                                  np.asarray(want.astype(jnp.float32)))
+
+
+@pytest.mark.parametrize("k_heads,v_heads", [(3, 3), (2, 4), (16, 16)])
+def test_head_counts_that_do_not_group_are_refused(k_heads, v_heads):
+    s = GROUPED
+    q = jnp.zeros((1, 8, s["T"], s["d"]))
+    k = jnp.zeros((1, k_heads, s["T"], s["d"]))
+    v = jnp.zeros((1, v_heads, s["T"], s["d"]))
+    with pytest.raises(ValueError, match="head"):
+        flash_attention(q, k, v, 0, 0, 0, 32, 32, True)
+
+
+# sha256 of the lowered text (no locations) of flash attention's forward and
+# backward in interpret mode with as many key/value heads as query heads, AT
+# THE PARENT of the PR that gave the kernels a second head count (e009dd1):
+# K == H lowers to the kernels it lowered to before.
+EQUAL_HEADS_AT_PARENT = {
+    (64, 64, False):
+        "4835ecfacc56497ec27d1ff4dbb201226f341e66c0ee7cc31e7371a26c0711cc",
+    (64, 64, True):
+        "68d266501f3e02a1d2edc3c6bb20c071327b6a492aa5b96ecaf9150a6bb21a53",
+    (48, 32, False):
+        "670b6e436e8664c6954f06612528cf95dee8f4e86edc2fab17453be57f5d77ad",
+    (48, 32, True):
+        "4abffb0dd6f1b5894a55c7f5d7b222501d6be49fd17b212a8a973b4827edace8",
+}
+
+
+@pytest.mark.parametrize("case", sorted(EQUAL_HEADS_AT_PARENT),
+                         ids=lambda c: f"qk{c[0]}-v{c[1]}-"
+                                       f"{'streaming' if c[2] else 'resident'}")
+def test_equal_head_counts_lower_to_the_kernels_they_did(case):
+    import hashlib
+
+    dh, dv, stream = case
+    q = jax.ShapeDtypeStruct((2, 4, 256, dh), jnp.float32)
+    v = jax.ShapeDtypeStruct((2, 4, 256, dv), jnp.float32)
+    f = lambda q, k, v: jnp.sum(flash_attention(q, k, v, 0, 0, 0, 128, 128,
+                                                True, stream))
+    text = jax.jit(jax.grad(f, argnums=(0, 1, 2))).lower(q, q, v).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == \
+        EQUAL_HEADS_AT_PARENT[case]

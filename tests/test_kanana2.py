@@ -14,7 +14,7 @@ import numpy as np
 import pytest
 
 from ddlbench_tpu import config as pcfg
-from ddlbench_tpu.models import kanana2
+from ddlbench_tpu.models import dropless, kanana2
 from ddlbench_tpu.models.layers import init_model, param_count
 from ddlbench_tpu.models.zoo import arch_name, collects_aux_loss, get_model
 from ddlbench_tpu.parallel import make_strategy
@@ -252,7 +252,8 @@ def test_no_token_is_dropped_under_a_skewed_router():
         jax.random.key(7), (2048, DIMS.d_model))  # 2048 tokens
     p = _experts_params(p, 0)
     S = h.shape[0]
-    assert kanana2.buffer_rows(S * DIMS.top_k, DIMS, 4) < S * DIMS.top_k
+    assert dropless.buffer_rows(S * DIMS.top_k, DIMS.n_experts, 4) < \
+        S * DIMS.top_k
     y, counters = jax.jit(lambda p, h: kanana2.routed_experts(
         p, h, DIMS, (0, 4)))(p, h)
     assert float(counters["held_slots"]) == S * DIMS.top_k
@@ -396,26 +397,21 @@ def test_the_pallas_grouped_product_is_ragged_dot():
     w = jax.random.normal(ks[1], (G, k, n), jnp.float32) * 0.1
     sizes = jnp.array([100, 0, 130, 70], jnp.int32)  # 300 of 512 rows
     valid = int(sizes.sum())
-    old = kanana2.GMM_TILING
-    kanana2.GMM_TILING = (128, 128, 128)
-    try:
-        with jax.default_matmul_precision("highest"):
-            f = lambda interpret: lambda a, w: jnp.sum(jnp.sin(
-                kanana2.grouped_dot(a, w, sizes, interpret)[:valid]))
-            for got, want in zip(jax.grad(f(True), (0, 1))(a, w),
-                                 jax.grad(f(False), (0, 1))(a, w)):
-                np.testing.assert_allclose(np.asarray(got[:valid]),
-                                           np.asarray(want[:valid]),
-                                           atol=1e-4)
-            np.testing.assert_allclose(
-                np.asarray(kanana2.grouped_dot(a, w, sizes, True)[:valid]),
-                np.asarray(kanana2.grouped_dot(a, w, sizes)[:valid]),
-                atol=1e-4)
-    finally:
-        kanana2.GMM_TILING = old
+    dot = lambda a, w, interpret=False: dropless.grouped_dot(
+        a, w, sizes, (128, 128, 128), interpret)
+    with jax.default_matmul_precision("highest"):
+        f = lambda interpret: lambda a, w: jnp.sum(jnp.sin(
+            dot(a, w, interpret)[:valid]))
+        for got, want in zip(jax.grad(f(True), (0, 1))(a, w),
+                             jax.grad(f(False), (0, 1))(a, w)):
+            np.testing.assert_allclose(np.asarray(got[:valid]),
+                                       np.asarray(want[:valid]), atol=1e-4)
+        np.testing.assert_allclose(np.asarray(dot(a, w, True)[:valid]),
+                                   np.asarray(dot(a, w)[:valid]), atol=1e-4)
     pe = {"w_gate": w, "w_up": w + 0.05,
           "w_down": jnp.swapaxes(w, 1, 2) * 0.5}
-    y, vjp = jax.vjp(lambda a: kanana2._grouped_swiglu(pe, a, sizes), a)
+    y, vjp = jax.vjp(lambda a: dropless._grouped_swiglu(
+        pe, a, sizes, kanana2.GMM_TILING), a)
     assert not np.any(np.asarray(y[valid:]))
     assert not np.any(np.asarray(vjp(jnp.ones_like(y))[0][valid:]))
 
@@ -433,22 +429,22 @@ def test_the_grouped_swiglu_keeps_out_what_the_products_leave_past_the_runs(
           "w_up": jax.random.normal(ks[2], (G, d, f)) * 0.2,
           "w_down": jax.random.normal(ks[3], (G, f, d)) * 0.2}
     sizes = jnp.array([10, 0, 17, 9], jnp.int32)  # 36 of 64 rows
-    clean = kanana2.grouped_dot
+    clean = dropless.grouped_dot
 
-    def stops_at_the_sum(a, w, sizes, interpret=False):
+    def stops_at_the_sum(a, w, sizes, tiling, interpret=False):
         live = (jnp.arange(a.shape[0]) < jnp.sum(sizes))[:, None]
         dirty = lambda x: jnp.where(live, x, jnp.nan)
 
         @jax.custom_vjp
         def dot(a, w):
-            return dirty(clean(jnp.where(live, a, 0), w, sizes))
+            return dirty(clean(jnp.where(live, a, 0), w, sizes, tiling))
 
         def fwd(a, w):
             return dot(a, w), (a, w)
 
         def bwd(res, ct):  # reads the live rows, leaves the others undefined
             a, w = res
-            da, dw = jax.vjp(lambda a, w: clean(a, w, sizes),
+            da, dw = jax.vjp(lambda a, w: clean(a, w, sizes, tiling),
                              jnp.where(live, a, 0), w)[1](
                                  jnp.where(live, ct, 0))
             return dirty(da), dw
@@ -456,13 +452,14 @@ def test_the_grouped_swiglu_keeps_out_what_the_products_leave_past_the_runs(
         dot.defvjp(fwd, bwd)
         return dot(a, w)
 
-    loss = lambda pe, rows: jnp.sum(jnp.sin(
-        kanana2._grouped_swiglu(pe, rows, sizes)))
+    swiglu = lambda pe, rows: dropless._grouped_swiglu(
+        pe, rows, sizes, kanana2.GMM_TILING)
+    loss = lambda pe, rows: jnp.sum(jnp.sin(swiglu(pe, rows)))
     want = jax.value_and_grad(loss, (0, 1))(pe, rows)
-    monkeypatch.setattr(kanana2, "grouped_dot", stops_at_the_sum)
+    monkeypatch.setattr(dropless, "grouped_dot", stops_at_the_sum)
     got = jax.value_and_grad(loss, (0, 1))(pe, rows)
     for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         assert np.all(np.isfinite(np.asarray(g)))
         np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=1e-6)
-    y = kanana2._grouped_swiglu(pe, rows, sizes)
+    y = swiglu(pe, rows)
     assert not np.any(np.asarray(y[int(sizes.sum()):]))

@@ -269,3 +269,57 @@ def test_the_latent_attention_block_compiles_with_named_kernels(one_chip,
     assert all(any(e in n for e in EVENTS) for n in products), products
     assert all("/route/" in found[n] and "/experts/" in found[n]
                for n in products)
+
+
+def test_the_compressed_attention_block_compiles_with_named_kernels(
+        one_chip, as_on_tpu):
+    """zaya1-8b's hybrid block at its published widths and the cell's batch
+    (2 x 8192): 8 query heads over 2 key/value heads in the flash kernels —
+    the forward resident, the backward the one-pass kernel, both under the
+    block's ``attn`` and neither under ``cca_mix`` —; the grouped products'
+    Pallas kernels under ``route`` / ``experts`` at this family's tiling,
+    with names the benchmark's moe_gmm.EVENTS find."""
+    from ddlbench_tpu.models import zaya
+    from ddlbench_tpu.models.layers import apply_slice
+
+    dims = zaya.FAMILY["zaya1_8b"]
+    block = zaya.hybrid_block("block2", dims, (0, 8), "auto", first=False,
+                              last=False)
+    T = 8192
+    params, state = jax.eval_shape(
+        lambda k: block.init(k, ((T, dims.d_model), (T, dims.router_dim)))[:2],
+        jax.random.key(0))
+    # the block reads its state (the selection bias): values, not shapes
+    state = jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), state)
+    router, rest = params["router"], {k: v for k, v in params.items()
+                                      if k != "router"}
+    leaves, tree = jax.tree.flatten(rest)
+    r_leaves, r_tree = jax.tree.flatten(router)
+
+    def loss(x, r, *flat):
+        p = dict(jax.tree.unflatten(tree, flat[:len(leaves)]),
+                 router=jax.tree.unflatten(r_tree, flat[len(leaves):]))
+        (y, r2), _ = apply_slice([block], [p], [state], (x, r), True)
+        return y.astype(jnp.float32).sum() + r2.sum()
+
+    found = _mosaic_calls(
+        jax.grad(loss), one_chip, ((2, T, dims.d_model), jnp.bfloat16),
+        ((2, T, dims.router_dim), jnp.float32),
+        *[(a.shape, jnp.bfloat16) for a in leaves],
+        *[(a.shape, jnp.float32) for a in r_leaves])
+    flash = sorted(n.split(".")[0] for n in found if "flash" in n)
+    assert flash == ["flash_attn_dq_dkv", "flash_attn_fwd"]
+    for n, op in found.items():
+        if "flash" in n:
+            assert "(block2)" in op and "/attn/" in op, op
+            assert "cca_mix" not in op, op
+    from benchmarks.kernels.moe_gmm import EVENTS
+
+    products = [n for n in found if "flash" not in n]
+    # the common buffer has room for every slot at 8 of 16 experts held
+    # (2 x the balanced 8,192 = all 16,384): one branch, 3 forward + 3 for
+    # the rows' gradient
+    assert len(products) == 6
+    assert all(any(e in n for e in EVENTS) for n in products), products
+    assert all("/route/" in found[n] and "/experts/" in found[n]
+               and "/router/" not in found[n] for n in products)
