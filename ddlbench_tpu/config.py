@@ -391,7 +391,10 @@ class RunConfig:
     remat_stages: bool = True
     # jax.checkpoint each LAYER in the one-apply strategies (single/dp/tp/
     # fsdp): the backward recomputes layers instead of saving interiors,
-    # capping live activations at one layer's working set. Off by default
+    # capping live activations at one layer's working set. Kept besides a
+    # layer's input: what its kernels name for keeping (ops/flash_attention.
+    # REMAT_KEPT_NAMES: the flash forward's output and row logsumexp, H*dv/d
+    # of the input), so the forward kernel is not run again. Off by default
     # (XLA's fusion usually wins); required for XLA-attention long-context
     # training on one chip, where each layer otherwise keeps a [B, H, T, T]
     # score matrix alive into the backward. Incompatible with the Switch

@@ -335,7 +335,19 @@ def apply_slice(layers: Sequence[Layer], params, states, x, train: bool,
     at 8k context the XLA-attention score matrix is 2 GB/layer, so without
     this every layer's matrix is resident at once and a single v5e chip
     OOMs (perf_runs, round 3). FLOPs-for-HBM, the jax.checkpoint analog of
-    the pipeline strategies' per-(microbatch, stage) cfg.remat_stages."""
+    the pipeline strategies' per-(microbatch, stage) cfg.remat_stages.
+
+    What a rematerialized layer KEEPS besides its input: the values its
+    kernels name for keeping (ops/flash_attention.REMAT_KEPT_NAMES: the
+    flash forward's output and row logsumexp, H * dv / d of the layer's
+    input), so the backward reads what the forward pass wrote and does not
+    run the forward kernel a second time. A layer that traces no such kernel
+    has no value of those names and lowers as under a bare jax.checkpoint."""
+    if remat:
+        from ddlbench_tpu.ops.flash_attention import REMAT_KEPT_NAMES
+
+        keep = jax.checkpoint_policies.save_only_these_names(
+            *REMAT_KEPT_NAMES)
     new_states = []
     for layer, p, s in zip(layers, params, states):
         # the layer instance's scope: every device op of this layer carries
@@ -343,7 +355,8 @@ def apply_slice(layers: Sequence[Layer], params, states, x, train: bool,
         with scopes.scope(layer.name):
             if remat:
                 x, s2 = jax.checkpoint(
-                    functools.partial(layer.apply, train=train))(p, s, x)
+                    functools.partial(layer.apply, train=train),
+                    policy=keep)(p, s, x)
             else:
                 x, s2 = layer.apply(p, s, x, train)
         new_states.append(s2)

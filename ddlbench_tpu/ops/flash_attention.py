@@ -663,8 +663,8 @@ def _flash_fwd_impl(q, k, v, q_offset, k_offset, prefix_len, block_q, block_k,
 
 def _flash_fwd(q, k, v, q_offset, k_offset, prefix_len, block_q, block_k,
                interpret, stream):
-    o, lse = _flash_fwd_impl(q, k, v, q_offset, k_offset, prefix_len, block_q,
-                             block_k, interpret, stream)
+    o, lse = _kept(*_flash_fwd_impl(q, k, v, q_offset, k_offset, prefix_len,
+                                    block_q, block_k, interpret, stream))
     return o, (q, k, v, o, lse)
 
 
@@ -788,6 +788,25 @@ def _flash_bwd_core(q_offset, k_offset, prefix_len, block_q, block_k,
 flash_attention.defvjp(_flash_fwd, _flash_bwd)
 
 
+# What a rematerialized layer keeps of this module (models/layers.apply_slice
+# saves the values of these names through its jax.checkpoint): the backward
+# kernels read (q, k, v, o, lse), and o and lse are all the forward kernel
+# computes, so with both kept the rematerialized computation has no reader
+# left for flash_attn_fwd and drops it. They are H * dv / d of the layer's
+# input, which the checkpoint keeps anyway. Outside a checkpoint with such a
+# policy a name is an identity and lowers to no operation.
+REMAT_KEPT_NAMES = ("flash_attn_o", "flash_attn_lse")
+
+
+def _kept(o, lse):
+    """The forward kernel's outputs under their names. A forward rule hands
+    THESE on, as its primal output and in its residuals alike."""
+    from jax.ad_checkpoint import checkpoint_name
+
+    name_o, name_lse = REMAT_KEPT_NAMES
+    return checkpoint_name(o, name_o), checkpoint_name(lse, name_lse)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
 def flash_attention_lse(q, k, v, q_offset=0, k_offset=0, prefix_len=0,
                         block_q=512, block_k=512, interpret=False,
@@ -810,8 +829,8 @@ def flash_attention_lse(q, k, v, q_offset=0, k_offset=0, prefix_len=0,
 
 def _flash_lse_fwd(q, k, v, q_offset, k_offset, prefix_len, block_q, block_k,
                    interpret, stream):
-    o, lse = _flash_fwd_impl(q, k, v, q_offset, k_offset, prefix_len, block_q,
-                             block_k, interpret, stream)
+    o, lse = _kept(*_flash_fwd_impl(q, k, v, q_offset, k_offset, prefix_len,
+                                    block_q, block_k, interpret, stream))
     B, H, Tq, _ = q.shape
     return (o, lse.reshape(B, H, Tq)), (q, k, v, o, lse)
 

@@ -236,10 +236,23 @@ def test_lse_cotangent_flows(qoff, bq, bk, stream):
                                    rtol=2e-4, atol=2e-5)
 
 
-def _kernel_names(fn, *xs):
-    import re
+def pallas_calls(jaxpr, found=None):
+    """{kernel name: calls} over a jaxpr and every jaxpr inside it."""
+    found = {} if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found[eqn.params["name"]] = found.get(eqn.params["name"], 0) + 1
+            continue
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    pallas_calls(sub, found)
+    return found
 
-    return set(re.findall(r"flash_attn_\w+", str(jax.make_jaxpr(fn)(*xs))))
+
+def _kernel_names(fn, *xs):
+    return set(pallas_calls(jax.make_jaxpr(fn)(*xs).jaxpr))
 
 
 @pytest.mark.parametrize("pfx,qoff,bq,bk", [
@@ -455,17 +468,15 @@ def test_benchmark_shapes_take_the_one_pass_backward(shape, dv):
     compiled path's rule picks the resident forward and ONE backward kernel,
     whose name holds the substrings benchmarks/kernels/flash_attn.py finds
     its events by."""
-    import re
-
     qk = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
     v = jax.ShapeDtypeStruct(shape[:3] + (dv,), jnp.bfloat16)
-    jaxpr = str(jax.make_jaxpr(jax.grad(
+    jaxpr = jax.make_jaxpr(jax.grad(
         lambda q, k, v: flash_attention(q, k, v).astype(jnp.float32).sum(),
-        argnums=(0, 1, 2)))(qk, qk, v))
-    assert sorted(re.findall(r"name=(flash_attn_\w+)", jaxpr)) == [
-        "flash_attn_dq_dkv", "flash_attn_fwd"]
+        argnums=(0, 1, 2)))(qk, qk, v)
+    assert pallas_calls(jaxpr.jaxpr) == {"flash_attn_dq_dkv": 1,
+                                         "flash_attn_fwd": 1}
     # the forward is the resident one: grid (B*H, T / 512), no inner axis
-    assert f"grid=({shape[0] * shape[1]}, {shape[2] // 512})" in jaxpr
+    assert f"grid=({shape[0] * shape[1]}, {shape[2] // 512})" in str(jaxpr)
 
 
 @pytest.mark.parametrize("extra", [
@@ -724,17 +735,32 @@ def test_head_counts_that_do_not_group_are_refused(k_heads, v_heads):
 # sha256 of the lowered text (no locations) of flash attention's forward and
 # backward in interpret mode with as many key/value heads as query heads, AT
 # THE PARENT of the PR that gave the kernels a second head count (e009dd1):
-# K == H lowers to the kernels it lowered to before.
+# K == H lowers to the kernels it lowered to before. The text is hashed with
+# the NUMBERS of jax's private helper functions taken out (``@clip_55`` ->
+# ``@clip``): jax numbers them by how many functions the trace has made, and
+# naming the forward's outputs for a rematerialized layer to keep (``_kept``)
+# makes one more and no operation — the four texts differ from that parent's
+# in 8 / 11 / 8 / 11 lines, each one such a number (96a420c against the tree
+# that named them: the hashes of the whole text moved 4835ecfa -> 283f57c4,
+# 68d26650 -> bc547810, 670b6e43 -> 6aef66da, 4abffb0d -> 173d0331, these
+# did not).
 EQUAL_HEADS_AT_PARENT = {
     (64, 64, False):
-        "4835ecfacc56497ec27d1ff4dbb201226f341e66c0ee7cc31e7371a26c0711cc",
+        "f0922f3545d5be4a8c3e4cb1f1ad6804b217bcdd48b866206a3718eeec1cd35f",
     (64, 64, True):
-        "68d266501f3e02a1d2edc3c6bb20c071327b6a492aa5b96ecaf9150a6bb21a53",
+        "cdffb2bf8029ab859ad6087b5666ce8af4bc25cd142f4f5d606f217479522b28",
     (48, 32, False):
-        "670b6e436e8664c6954f06612528cf95dee8f4e86edc2fab17453be57f5d77ad",
+        "ecec28c52895e9ddafb2c06a48a17fc75c4b4d68814c2ab2de77f29b70937aa3",
     (48, 32, True):
-        "4abffb0dd6f1b5894a55c7f5d7b222501d6be49fd17b212a8a973b4827edace8",
+        "ead3d8271538d6f8de9881f4876b9d67045b3b569825424397cfb912b949bd2c",
 }
+
+
+def _unnumbered(text):
+    """``text`` with the numbers of private functions' names taken out."""
+    import re
+
+    return re.sub(r"(@[A-Za-z_]\w*?)_\d+\b", r"\1", text)
 
 
 @pytest.mark.parametrize("case", sorted(EQUAL_HEADS_AT_PARENT),
@@ -749,5 +775,5 @@ def test_equal_head_counts_lower_to_the_kernels_they_did(case):
     f = lambda q, k, v: jnp.sum(flash_attention(q, k, v, 0, 0, 0, 128, 128,
                                                 True, stream))
     text = jax.jit(jax.grad(f, argnums=(0, 1, 2))).lower(q, q, v).as_text()
-    assert hashlib.sha256(text.encode()).hexdigest() == \
+    assert hashlib.sha256(_unnumbered(text).encode()).hexdigest() == \
         EQUAL_HEADS_AT_PARENT[case]

@@ -77,8 +77,9 @@ def _assert_kernels(found, instance, kind, kernels):
                 in found[names[0]])
 
 
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
 def test_flash_attention_keeps_its_names_inside_the_scopes(one_chip,
-                                                           as_on_tpu):
+                                                           as_on_tpu, remat):
     from ddlbench_tpu.models.layers import apply_slice
     from ddlbench_tpu.models.transformer import transformer_block
 
@@ -89,18 +90,21 @@ def test_flash_attention_keeps_its_names_inside_the_scopes(one_chip,
 
     def loss(x, *flat):
         p = jax.tree.unflatten(tree, flat)
-        y, _ = apply_slice([block], [p], [{}], x, True)
+        y, _ = apply_slice([block], [p], [{}], x, True, remat)
         return y.astype(jnp.float32).sum()
 
     found = _mosaic_calls(
         jax.grad(loss), one_chip, ((B, T, D), jnp.bfloat16),
         *[(a.shape, jnp.bfloat16) for a in leaves])
-    # the sublayer holds exactly the forward and the one-pass backward
+    # the sublayer holds exactly the forward and the one-pass backward: a
+    # rematerialized layer keeps the forward's o and lse (apply_slice) and
+    # compiles to no second forward kernel
     assert sorted(n.split(".")[0] for n in found) == [
         "flash_attn_dq_dkv", "flash_attn_fwd"], sorted(found)
     _assert_kernels(found, "block3", "attn", (
         ("flash_attn_fwd", "jvp({})"),
-        ("flash_attn_dq_dkv", "transpose(jvp({}))")))
+        ("flash_attn_dq_dkv", "transpose(jvp({0}))/jvp({0})/checkpoint"
+         if remat else "transpose(jvp({}))")))
     # benchmarks/kernels/flash_attn.py finds a kernel's events by substring:
     # each name has to hold one of its three, or its time leaves the roofline
     from benchmarks.kernels.flash_attn import EVENTS
