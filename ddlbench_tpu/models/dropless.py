@@ -1,10 +1,12 @@
 """The dropless expert layer of one chip's share: token-slots that a router
 has already given an expert (``idx``) and a weight (``w``) are sorted by
 expert, those of the experts held here gathered into a row buffer,
-multiplied group by group (``grouped_dot``) through each expert's SwiGLU and
-scattered back weighted. The families that route their own way
-(models/kanana2.py: sigmoid top-6 by one matmul; models/zaya.py: top-1 by an
-MLP router) share everything after the choice.
+multiplied group by group (``grouped_dot``) through each expert's gated MLP
+(``act``: SiLU unless the family says otherwise) and scattered back weighted.
+The families that route their own way (models/kanana2.py: sigmoid top-6 by
+one matmul; models/zaya.py: top-1 by an MLP router; models/smallthinker.py:
+softmax top-6 by one matmul on the layer's input, ReLU-gated experts) share
+everything after the choice.
 
 The buffer has room for ``ROW_SLACK`` x the balanced number of held slots;
 the slots of a step whose router sends more go through a second buffer, with
@@ -96,28 +98,29 @@ def grouped_dot(a, w, sizes, tiling: Tuple[int, int, int],
     return lax.ragged_dot(a, w, sizes, preferred_element_type=a.dtype)
 
 
-def _grouped_swiglu(pe, rows, sizes, tiling):
-    """Each held expert's SwiGLU over its run of ``rows`` [M, d]
-    (``sizes``: rows per expert). The grouped products stop at
-    ``sum(sizes)``: what they leave in the rows past it is undefined, so
-    those rows come out nought here, and go in nought so that no gradient
-    comes back through them."""
+def _grouped_glu(pe, rows, sizes, tiling, act):
+    """Each held expert's gated MLP, W_down(act(W_gate h) * W_up h), over
+    its run of ``rows`` [M, d] (``sizes``: rows per expert). The grouped
+    products stop at ``sum(sizes)``: what they leave in the rows past it is
+    undefined, so those rows come out nought here, and go in nought so that
+    no gradient comes back through them."""
     with scopes.scope(scopes.EXPERTS):
         live = (jnp.arange(rows.shape[0]) < jnp.sum(sizes))[:, None]
         rows = jnp.where(live, rows, 0)
         dot = lambda a, w: grouped_dot(a, w.astype(a.dtype), sizes, tiling)
         g = dot(rows, pe["w_gate"])
         u = dot(rows, pe["w_up"])
-        return jnp.where(live, dot(jax.nn.silu(g) * u, pe["w_down"]), 0)
+        return jnp.where(live, dot(act(g) * u, pe["w_down"]), 0)
 
 
 def routed_experts(pe, h, idx, w, held: Tuple[int, int], n_experts: int,
-                   tiling: Tuple[int, int, int]):
+                   tiling: Tuple[int, int, int], act=jax.nn.silu):
     """The held experts' part of ``sum_k w_k E_idx_k(h)`` for h [S, d],
     ``idx`` [S, k] int32 and ``w`` [S, k] float32 as the family's router
-    gave them, ``pe`` the held experts' stacked SwiGLU weights, and the
-    layer's counters. Deterministic (a stable sort): a rematerialized
-    forward routes as the first one did."""
+    gave them, ``pe`` the held experts' stacked gate, up and down weights,
+    ``act`` the gate's activation, and the layer's counters. Deterministic
+    (a stable sort): a rematerialized forward routes as the first one
+    did."""
     S, d = h.shape
     k = idx.shape[1]
     first, count = held
@@ -138,8 +141,8 @@ def routed_experts(pe, h, idx, w, held: Tuple[int, int], n_experts: int,
             token = slot // k
             # each expert's run, cut to this window of the sorted order
             cut = lambda x: jnp.clip(x, lo, lo + rows)
-            y = _grouped_swiglu(pe, jnp.take(h, token, axis=0),
-                                cut(ends) - cut(ends - sizes), tiling)
+            y = _grouped_glu(pe, jnp.take(h, token, axis=0),
+                             cut(ends) - cut(ends - sizes), tiling, act)
             y = y.astype(jnp.float32) * jnp.take(w_flat, slot)[:, None]
             return acc.at[token].add(y)
         return f

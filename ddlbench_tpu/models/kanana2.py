@@ -24,7 +24,8 @@ there is no exchange, and no code stands in for one). The routed part is
 dropless, and everything after the router's choice — sort by expert, gather
 the held slots into a row buffer, the grouped products, the weighted
 scatter, the counters — is ``models/dropless.py``, which ``models/zaya.py``
-shares: this file keeps ``route`` and the tiling of its 768-wide experts.
+and ``models/smallthinker.py`` share: this file keeps ``route`` and the
+tiling of its 768-wide experts.
 
 The arch string carries the share: ``kanana2_30b_a3b`` is the whole model,
 ``kanana2_30b_a3b-l5-e8`` its first 5 layers with experts 0..7 of each

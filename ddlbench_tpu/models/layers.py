@@ -290,6 +290,11 @@ class LayerModel:
     # per-layer list into stages do not resolve ties: a model with ties
     # names the strategies that do in ``strategies``.
     ties: Tuple[Tie, ...] = ()
+    # the model is built for inputs at which a layer's interior activations
+    # do not fit beside the train state (models/smallthinker.py: one
+    # 16,384-token sequence): the one-apply strategies checkpoint every layer
+    # as under RunConfig.remat_layers, whatever that says.
+    remat_layers: bool = False
 
 
 def resolve_ties(ties: Sequence[Tie], params):

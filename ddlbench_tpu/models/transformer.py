@@ -191,7 +191,8 @@ def embed(name: str, vocab: int, d_model: int, max_len: int) -> Layer:
 
 
 def causal_attention(q, k, v, q_offset: int = 0, k_offset: int = 0,
-                     prefix_len: int = 0, backend: str = "auto"):
+                     prefix_len: int = 0, backend: str = "auto",
+                     window: int = 0):
     """Masked attention for blocks of a causal (or prefix-LM) sequence.
 
     q: [B, H, Tq, Dh]; k: [B, K, Tk, Dh]; v: [B, K, Tk, Dv] (Dv = Dh but
@@ -202,7 +203,9 @@ def causal_attention(q, k, v, q_offset: int = 0, k_offset: int = 0,
     position so the same primitive serves full attention (offsets 0) and ring
     attention over sequence shards (parallel/sp.py). ``prefix_len`` > 0 adds
     the prefix-LM rule: key positions < prefix_len are visible to every query
-    (the seq2seq source segment, models/seq2seq.py). ``backend``
+    (the seq2seq source segment, models/seq2seq.py). ``window`` > 0 adds a
+    sliding window: the query at position i sees the keys j with
+    i - window < j <= i (models/smallthinker.py; 0: none). ``backend``
     ("auto" | "flash" | "xla", config.ATTENTION_BACKENDS) goes to
     ops/flash_attention.flash_dispatch, which says whether this call takes
     the fused Pallas kernel — same prefix rule, with block-level skipping —
@@ -211,10 +214,13 @@ def causal_attention(q, k, v, q_offset: int = 0, k_offset: int = 0,
     from ddlbench_tpu.ops.flash_attention import (flash_attention,
                                                   flash_dispatch)
 
+    if window and prefix_len:
+        raise ValueError("causal_attention: a window with a prefix is not "
+                         "written")
     use_flash, interpret = flash_dispatch(backend, q, k, v, prefix_len)
     if use_flash:
         return flash_attention(q, k, v, q_offset, k_offset, prefix_len,
-                               interpret=interpret)
+                               interpret=interpret, window=window)
     dh = q.shape[-1]
     if k.shape[1] != q.shape[1]:  # grouped queries: a group's heads share
         k, v = (jnp.repeat(t, q.shape[1] // t.shape[1], axis=1)
@@ -225,6 +231,8 @@ def causal_attention(q, k, v, q_offset: int = 0, k_offset: int = 0,
     ok = q_pos >= k_pos
     if prefix_len:
         ok = ok | (k_pos < prefix_len)
+    if window:
+        ok = ok & (k_pos > q_pos - window)
     scores = jnp.where(ok, scores, -jnp.inf)
     # numerically safe softmax that tolerates fully-masked rows
     m = jnp.max(scores, axis=-1, keepdims=True)

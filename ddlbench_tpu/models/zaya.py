@@ -41,7 +41,9 @@ function puts the leaf there inside what it differentiates
 (``layers.resolve_ties``): one leaf, one gradient (the sum of both uses), one
 optimizer slot.
 
-**The share a chip holds** is ``models/kanana2.py``'s: a block is told which
+**The share a chip holds** is ``models/kanana2.py``'s (and
+``models/smallthinker.py``'s: the three families route their own way and
+share ``models/dropless.py`` after the choice): a block is told which
 experts it holds, routes over all ``n_experts`` and computes its own experts'
 part; a token whose expert is absent gets nought. The arch string carries it:
 ``zaya1_8b`` is the whole model, ``zaya1_8b-l5-e8`` its first 5 layers with

@@ -18,21 +18,25 @@ MODEL_NAMES = ("resnet18", "resnet50", "resnet152", "vgg11", "vgg16",
                "densenet121", "inception", "nasnet", "transformer_t",
                "transformer_s",
                "transformer_m", "transformer_moe_s", "seq2seq_s", "seq2seq_m",
-               "seq2seq_lstm_s", "kanana2_30b_a3b", "zaya1_8b")
+               "seq2seq_lstm_s", "kanana2_30b_a3b", "zaya1_8b",
+               "smallthinker_21b_a3b")
 
-ARCH_HELP = ("one of MODEL_NAMES; kanana2_30b_a3b and zaya1_8b may carry the "
-             "share one chip holds: -l<layers kept>, -e<experts held>"
-             "[r<rank>], e.g. kanana2_30b_a3b-l5-e8, zaya1_8b-l5-e8r1 "
-             "(models/kanana2.py, models/zaya.py: each routes its own way, "
-             "models/dropless.py computes the held experts' part for both)")
+ARCH_HELP = ("one of MODEL_NAMES; kanana2_30b_a3b, zaya1_8b and "
+             "smallthinker_21b_a3b may carry the share one chip holds: "
+             "-l<layers kept>, -e<experts held>[r<rank>], e.g. "
+             "kanana2_30b_a3b-l5-e8, zaya1_8b-l5-e8r1, "
+             "smallthinker_21b_a3b-l4-e16 (models/kanana2.py, models/zaya.py, "
+             "models/smallthinker.py: each routes its own way, "
+             "models/dropless.py computes the held experts' part for all)")
 
 
 def _share_family(arch: str):
     """The module of the family whose arch strings carry a chip's share
     (``is_family``, ``parse_arch``, ``build``), None for any other arch."""
-    from ddlbench_tpu.models import kanana2, zaya
+    from ddlbench_tpu.models import kanana2, smallthinker, zaya
 
-    return next((m for m in (kanana2, zaya) if m.is_family(arch)), None)
+    return next((m for m in (kanana2, zaya, smallthinker)
+                 if m.is_family(arch)), None)
 
 
 def arch_name(arch: str) -> str:
@@ -52,8 +56,8 @@ def collects_aux_loss(arch: str) -> bool:
     capacity and load-balance loss are statistics of the whole routed batch,
     collected through a trace-time sink — what a checkpointed layer cannot
     let out and a shard_map over the batch would make per-shard. (The
-    dropless routers of models/kanana2.py and models/zaya.py route token by
-    token and collect nothing.)"""
+    dropless routers of models/kanana2.py, models/zaya.py and
+    models/smallthinker.py route token by token and collect nothing.)"""
     from ddlbench_tpu.models.moe import _VARIANTS
 
     return arch in _VARIANTS

@@ -41,7 +41,9 @@ kernel's ``vmem_limit_bytes``. The two benchmark shapes (bf16, 512x512),
 forward / one-pass backward: T=1024 dh=64 (gpt2s-train) 4.1 / 6.5 MiB;
 T=4096 q/k 192 v 128 (kanana2-ep16-train) 9.5 / 18.9 MiB. Block-level causal
 skipping in both designs: resident bounds its fori, streaming skips dead
-cells' compute under @pl.when.
+cells' compute under @pl.when. A sliding ``window`` bounds the sweeps from
+the other side (_window_kv_start, _window_q_end): at T 16384, window 4096
+and 512x512 tiles a head visits 252 of its 528 causal tiles.
 
 Measured on one v5e, bf16, device ms per call (PERF.md: PR 25 for dh=64,
 PR 28 for the rest), forward / backward, resident against streaming:
@@ -248,11 +250,37 @@ def _first_q_block(k_lo, q_offset: int, block_q: int, num_q: int,
     return start
 
 
-def _block_mask(q_pos, k_pos, prefix_len: int):
+def _block_mask(q_pos, k_pos, prefix_len: int, window: int = 0):
     mask = q_pos >= k_pos
     if prefix_len:
         mask = mask | (k_pos < prefix_len)
+    if window:  # the query's own key and the window - 1 before it
+        mask = mask & (k_pos > q_pos - window)
     return mask
+
+
+def _window_kv_start(qi, block_q: int, q_offset: int, k_offset: int,
+                     block_k: int, num_k: int, window: int):
+    """First K block that holds a key the Q block ``qi`` sees under a
+    window: the one with the key ``window - 1`` before the block's first
+    query. The lower end of the sweep whose upper end is _causal_kv_bound;
+    without a window (0) it is block 0 and nothing is traced."""
+    if not window:
+        return 0
+    first_key = q_offset + qi * block_q - window + 1
+    return jnp.clip((first_key - k_offset) // block_k, 0, num_k)
+
+
+def _window_q_end(kj, block_k: int, k_offset: int, q_offset: int,
+                  block_q: int, num_q: int, window: int):
+    """One past the last Q block that still sees the K block ``kj`` under a
+    window: the one with the query ``window - 1`` after the block's last
+    key. The upper end of the sweep that starts at _first_q_block; without
+    a window (0) it is num_q and nothing is traced."""
+    if not window:
+        return num_q
+    last_query = k_offset + (kj + 1) * block_k - 1 + window - 1
+    return jnp.clip((last_query - q_offset) // block_q + 1, 0, num_q)
 
 
 # ---------------------------------------------------------------------------
@@ -261,13 +289,13 @@ def _block_mask(q_pos, k_pos, prefix_len: int):
 
 
 def _fwd_block_step(q, k_blk, v_blk, m, l, acc, q_pos, k_pos, scale,
-                    prefix_len: int):
+                    prefix_len: int, window: int = 0):
     """One online-softmax update of (m, l, acc) against a K/V block."""
     s = jax.lax.dot_general(
         q, k_blk, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     ) * scale
-    mask = _block_mask(q_pos, k_pos, prefix_len)
+    mask = _block_mask(q_pos, k_pos, prefix_len, window)
     s = jnp.where(mask, s, NEG_INF)
     m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
     p = jnp.exp(s - m_new) * mask.astype(jnp.float32)
@@ -282,13 +310,13 @@ def _fwd_block_step(q, k_blk, v_blk, m, l, acc, q_pos, k_pos, scale,
 
 
 def _dq_block_step(q, do, lse, delta, k_blk, v_blk, q_pos, k_pos, scale,
-                   prefix_len: int):
+                   prefix_len: int, window: int = 0):
     """This q block's dq contribution from one K/V block."""
     s = jax.lax.dot_general(
         q, k_blk, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     ) * scale
-    mask = _block_mask(q_pos, k_pos, prefix_len)
+    mask = _block_mask(q_pos, k_pos, prefix_len, window)
     # where() BEFORE the multiply: fully-masked rows have lse ~ -1e30 and
     # exp(s - lse) overflows to inf; inf * 0 would poison dq with NaN.
     p = jnp.where(mask, jnp.exp(s - lse), 0.0)
@@ -304,13 +332,13 @@ def _dq_block_step(q, do, lse, delta, k_blk, v_blk, q_pos, k_pos, scale,
 
 
 def _dkv_block_step(k, v, q_blk, do_blk, lse_blk, delta_blk, q_pos, k_pos,
-                    scale, prefix_len: int):
+                    scale, prefix_len: int, window: int = 0):
     """This k block's (dk, dv) contributions from one Q/dO block."""
     s = jax.lax.dot_general(
         q_blk, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     ) * scale
-    mask = _block_mask(q_pos, k_pos, prefix_len)
+    mask = _block_mask(q_pos, k_pos, prefix_len, window)
     # see _dq_block_step: mask inside where() keeps inf out of the matmuls
     p = jnp.where(mask, jnp.exp(s - lse_blk), 0.0)  # [bq, bk]
     dv_add = jax.lax.dot_general(
@@ -345,7 +373,7 @@ def _dot(a, b, dims):
 
 
 def _fwd_kernel_res(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, block_k,
-                    q_offset, k_offset, num_k, prefix_len):
+                    q_offset, k_offset, num_k, prefix_len, window=0):
     bq = q_ref.shape[1]
     dv = v_ref.shape[2]
     q = q_ref[0]  # [bq, dh] native dtype; MXU accumulates f32 below
@@ -362,7 +390,7 @@ def _fwd_kernel_res(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, block_k,
         k_pos = (k_offset + j * block_k
                  + jax.lax.broadcasted_iota(jnp.int32, (block_k, 1), 0))
         s = _dot(k_blk, q, _NT) * scale  # [bk, bq]
-        mask = _block_mask(q_pos, k_pos, prefix_len)
+        mask = _block_mask(q_pos, k_pos, prefix_len, window)
         s = jnp.where(mask, s, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
         p = jnp.exp(s - m_new) * mask.astype(jnp.float32)
@@ -375,7 +403,9 @@ def _fwd_kernel_res(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, block_k,
     m0 = jnp.full((1, bq), NEG_INF, jnp.float32)
     l0 = jnp.zeros((1, bq), jnp.float32)
     acc0 = jnp.zeros((dv, bq), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(0, bound, body, (m0, l0, acc0))
+    first = _window_kv_start(qi, bq, q_offset, k_offset, block_k, num_k,
+                             window)
+    m, l, acc = jax.lax.fori_loop(first, bound, body, (m0, l0, acc0))
     l_safe = jnp.maximum(l, 1e-20)
     o_ref[0] = (acc / l_safe).T.astype(o_ref.dtype)
     # LSE of fully-masked rows stays NEG_INF-ish; backward p=exp(s-lse) uses
@@ -385,7 +415,8 @@ def _fwd_kernel_res(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, block_k,
 
 def _dq_dkv_kernel_res(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
                        dq_ref, dk_ref, dv_ref, dq_sc, *, scale, block_q,
-                       q_offset, k_offset, num_q, num_k, prefix_len):
+                       q_offset, k_offset, num_q, num_k, prefix_len,
+                       window=0):
     """One-pass backward: per (K block, Q block) S, P, dP, dS once, and from
     them dV += P dO, dK += dS Q (the fori carry) and dQ^T += K^T dS, in the
     [dh, Tq] f32 scratch that stays put while the K-block grid axis sweeps
@@ -398,6 +429,7 @@ def _dq_dkv_kernel_res(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
     k_lo = k_offset + kj * bk
     k_pos = k_lo + jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0)
     start = _first_q_block(k_lo, q_offset, block_q, num_q, prefix_len)
+    end = _window_q_end(kj, bk, k_offset, q_offset, block_q, num_q, window)
 
     @pl.when(kj == 0)
     def _init():
@@ -414,7 +446,7 @@ def _dq_dkv_kernel_res(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
         # where() on the exp, not a multiply: fully-masked rows have lse ~
         # -1e30, exp(s - lse) overflows to inf and inf * 0 would poison the
         # gradients with NaN
-        p = jnp.where(_block_mask(q_pos, k_pos, prefix_len),
+        p = jnp.where(_block_mask(q_pos, k_pos, prefix_len, window),
                       jnp.exp(s - lse_ref[0, :, rows]), 0.0)
         dp = _dot(v, do_blk, _NT)
         ds = (p * (dp - delta_ref[0, :, rows]) * scale).astype(k.dtype)
@@ -423,7 +455,7 @@ def _dq_dkv_kernel_res(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
                 dv + _dot(p.astype(do_blk.dtype), do_blk, _NN))
 
     dk, dv = jax.lax.fori_loop(
-        start, num_q, body,
+        start, end, body,
         (jnp.zeros(k.shape, jnp.float32), jnp.zeros(v.shape, jnp.float32)))
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
@@ -441,12 +473,14 @@ def _dq_dkv_kernel_res(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
 
 def _fwd_kernel_stream(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc,
                        acc_sc, *, scale, block_k, q_offset, k_offset, num_k,
-                       prefix_len):
+                       prefix_len, window=0):
     bq = q_ref.shape[1]
     qi, j = pl.program_id(1), pl.program_id(2)
     q_pos = q_offset + qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
     bound = _causal_kv_bound(q_offset + (qi + 1) * bq - 1, k_offset, block_k,
                              num_k, prefix_len)
+    first = _window_kv_start(qi, bq, q_offset, k_offset, block_k, num_k,
+                             window)
 
     @pl.when(j == 0)
     def _init():
@@ -454,13 +488,13 @@ def _fwd_kernel_stream(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc,
         l_sc[:] = jnp.zeros(l_sc.shape, jnp.float32)
         acc_sc[:] = jnp.zeros(acc_sc.shape, jnp.float32)
 
-    @pl.when(j < bound)
+    @pl.when((j >= first) & (j < bound) if window else j < bound)
     def _step():
         k_pos = (k_offset + j * block_k
                  + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1))
         m, l, acc = _fwd_block_step(
             q_ref[0], k_ref[0], v_ref[0], m_sc[:], l_sc[:], acc_sc[:],
-            q_pos, k_pos, scale, prefix_len)
+            q_pos, k_pos, scale, prefix_len, window)
         m_sc[:], l_sc[:], acc_sc[:] = m, l, acc
 
     @pl.when(j == num_k - 1)
@@ -472,24 +506,26 @@ def _fwd_kernel_stream(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc,
 
 def _dq_kernel_stream(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                       acc_sc, *, scale, block_k, q_offset, k_offset, num_k,
-                      prefix_len):
+                      prefix_len, window=0):
     bq = q_ref.shape[1]
     qi, j = pl.program_id(1), pl.program_id(2)
     q_pos = q_offset + qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
     bound = _causal_kv_bound(q_offset + (qi + 1) * bq - 1, k_offset, block_k,
                              num_k, prefix_len)
+    first = _window_kv_start(qi, bq, q_offset, k_offset, block_k, num_k,
+                             window)
 
     @pl.when(j == 0)
     def _init():
         acc_sc[:] = jnp.zeros(acc_sc.shape, jnp.float32)
 
-    @pl.when(j < bound)
+    @pl.when((j >= first) & (j < bound) if window else j < bound)
     def _step():
         k_pos = (k_offset + j * block_k
                  + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1))
         acc_sc[:] += _dq_block_step(
             q_ref[0], do_ref[0], lse_ref[0], delta_ref[0], k_ref[0], v_ref[0],
-            q_pos, k_pos, scale, prefix_len)
+            q_pos, k_pos, scale, prefix_len, window)
 
     @pl.when(j == num_k - 1)
     def _fini():
@@ -498,26 +534,27 @@ def _dq_kernel_stream(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 def _dkv_kernel_stream(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
                        dk_ref, dv_ref, dk_sc, dv_sc, *, scale, block_q,
-                       q_offset, k_offset, num_q, prefix_len):
+                       q_offset, k_offset, num_q, prefix_len, window=0):
     bk = k_ref.shape[1]
     kj, i = pl.program_id(1), pl.program_id(2)
     k_pos = (k_offset + kj * bk
              + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1))
     start = _first_q_block(k_offset + kj * bk, q_offset, block_q, num_q,
                            prefix_len)
+    end = _window_q_end(kj, bk, k_offset, q_offset, block_q, num_q, window)
 
     @pl.when(i == 0)
     def _init():
         dk_sc[:] = jnp.zeros(dk_sc.shape, jnp.float32)
         dv_sc[:] = jnp.zeros(dv_sc.shape, jnp.float32)
 
-    @pl.when(i >= start)
+    @pl.when((i >= start) & (i < end) if window else i >= start)
     def _step():
         q_pos = (q_offset + i * block_q
                  + jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0))
         dk_add, dv_add = _dkv_block_step(
             k_ref[0], v_ref[0], q_ref[0], do_ref[0], lse_ref[0], delta_ref[0],
-            q_pos, k_pos, scale, prefix_len)
+            q_pos, k_pos, scale, prefix_len, window)
         dk_sc[:] += dk_add
         dv_sc[:] += dv_add
 
@@ -550,10 +587,11 @@ def _kv_row(q, k, v):
 
 
 @functools.partial(
-    jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9)
+    jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10)
 )
 def flash_attention(q, k, v, q_offset=0, k_offset=0, prefix_len=0,
-                    block_q=512, block_k=512, interpret=False, stream=None):
+                    block_q=512, block_k=512, interpret=False, stream=None,
+                    window=0):
     """Causal / prefix-LM attention, [B, H, T, dh] -> [B, H, Tq, dh], fused.
     q and k share a width (which sets the scale, 1/sqrt of it); v and the
     output may have another (latent attention: q/k 192 wide, v 128).
@@ -579,14 +617,42 @@ def flash_attention(q, k, v, q_offset=0, k_offset=0, prefix_len=0,
     design; None picks per kernel, resident while what the kernel would hold
     in VMEM fits the budget (_use_streaming; module docstring for the
     accounted bytes and the measured ms of both designs).
+
+    ``window`` > 0 is a sliding window on top of the causal rule: the query
+    at absolute position i sees the keys j with i - window < j <= i,
+    ``window`` keys with its own among them (models/smallthinker.py). Every
+    kernel takes it in its tile mask and in its sweep: the forward and dq
+    sweeps start at the first key block that holds a visible key, the dK/dV
+    sweeps end at the last query block that still sees the key block, so a
+    tile wholly outside the band costs nothing in the resident kernels and
+    its fetch alone in the streaming ones. A window that reaches every key
+    anyway (``_effective_window``) and ``window`` 0 are the same call as
+    without the argument, down to the lowered text; a window with a prefix
+    raises (no model has both).
     """
     o, _ = _flash_fwd_impl(q, k, v, q_offset, k_offset, prefix_len, block_q,
-                           block_k, interpret, stream)
+                           block_k, interpret, stream, window)
     return o
 
 
+def _effective_window(window: int, q, k, q_offset: int, k_offset: int,
+                      prefix_len: int) -> int:
+    """``window`` as the kernels get it: 0 where it is 0 or masks nothing
+    (the last query still sees the first key), so that such a call traces
+    the kernels it traced before the argument existed."""
+    if window < 0:
+        raise ValueError(f"flash_attention: window {window} is negative")
+    if window and prefix_len:
+        raise ValueError(
+            "flash_attention: a window with a prefix is not written (the "
+            "prefix's keys are visible to every query, the window's are not)")
+    if q_offset + q.shape[2] - 1 - k_offset < window:
+        return 0
+    return window
+
+
 def _flash_fwd_impl(q, k, v, q_offset, k_offset, prefix_len, block_q, block_k,
-                    interpret, stream):
+                    interpret, stream, window=0):
     """-> (o [B, H, Tq, dh], lse [B*H, 1, Tq] f32). The lse is kept as ROWS:
     dense in HBM (a [.., Tq, 1] f32 array is tiled (8, 128) on its last two
     dimensions, 128 times its size) and what the resident kernels read."""
@@ -608,6 +674,9 @@ def _flash_fwd_impl(q, k, v, q_offset, k_offset, prefix_len, block_q, block_k,
 
     kw = dict(scale=scale, block_k=bk, q_offset=q_offset, k_offset=k_offset,
               num_k=num_k, prefix_len=prefix_len)
+    window = _effective_window(window, q, k, q_offset, k_offset, prefix_len)
+    if window:
+        kw["window"] = window
     if streaming:
         kern = functools.partial(_fwd_kernel_stream, **kw)
         grid = (BH, num_q, num_k)
@@ -662,20 +731,21 @@ def _flash_fwd_impl(q, k, v, q_offset, k_offset, prefix_len, block_q, block_k,
 
 
 def _flash_fwd(q, k, v, q_offset, k_offset, prefix_len, block_q, block_k,
-               interpret, stream):
+               interpret, stream, window):
     o, lse = _kept(*_flash_fwd_impl(q, k, v, q_offset, k_offset, prefix_len,
-                                    block_q, block_k, interpret, stream))
+                                    block_q, block_k, interpret, stream,
+                                    window))
     return o, (q, k, v, o, lse)
 
 
 def _flash_bwd(q_offset, k_offset, prefix_len, block_q, block_k, interpret,
-               stream, res, g):
+               stream, window, res, g):
     return _flash_bwd_core(q_offset, k_offset, prefix_len, block_q, block_k,
-                           interpret, stream, res, g, None)
+                           interpret, stream, res, g, None, window)
 
 
 def _flash_bwd_core(q_offset, k_offset, prefix_len, block_q, block_k,
-                    interpret, stream, res, g, g_lse):
+                    interpret, stream, res, g, g_lse, window=0):
     from jax.experimental.pallas import tpu as pltpu
 
     q, k, v, o, lse = res
@@ -713,6 +783,9 @@ def _flash_bwd_core(q_offset, k_offset, prefix_len, block_q, block_k,
 
     kw = dict(scale=1.0 / math.sqrt(dh), q_offset=q_offset,
               k_offset=k_offset, prefix_len=prefix_len)
+    window = _effective_window(window, q, k, q_offset, k_offset, prefix_len)
+    if window:
+        kw["window"] = window
 
     # the one-pass kernel keeps the Q side resident: Q, dO, lse, delta, dQ
     if not _use_streaming(Tq, dh, isz, bq, bk, stream, backward=True,
@@ -807,10 +880,11 @@ def _kept(o, lse):
     return checkpoint_name(o, name_o), checkpoint_name(lse, name_lse)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
 def flash_attention_lse(q, k, v, q_offset=0, k_offset=0, prefix_len=0,
                         block_q=512, block_k=512, interpret=False,
-                        stream=None):
+                        stream=None, window=0):
     """flash_attention that ALSO returns the per-row logsumexp: (o, lse) with
     lse [B, H, Tq] f32.
 
@@ -820,26 +894,28 @@ def flash_attention_lse(q, k, v, q_offset=0, k_offset=0, prefix_len=0,
     lse_tot = logaddexp_i(lse_i) (models/transformer.py ring_attention).
     Both outputs are differentiable: d lse/d scores = p, which folds into the
     existing backward kernels as a delta shift (ds = p∘(dp - (delta - lse_bar))),
-    so the backward kernels are reused unchanged.
+    so the backward kernels are reused unchanged. ``window``: as
+    flash_attention's.
     """
     out, _ = _flash_lse_fwd(q, k, v, q_offset, k_offset, prefix_len, block_q,
-                            block_k, interpret, stream)
+                            block_k, interpret, stream, window)
     return out
 
 
 def _flash_lse_fwd(q, k, v, q_offset, k_offset, prefix_len, block_q, block_k,
-                   interpret, stream):
+                   interpret, stream, window):
     o, lse = _kept(*_flash_fwd_impl(q, k, v, q_offset, k_offset, prefix_len,
-                                    block_q, block_k, interpret, stream))
+                                    block_q, block_k, interpret, stream,
+                                    window))
     B, H, Tq, _ = q.shape
     return (o, lse.reshape(B, H, Tq)), (q, k, v, o, lse)
 
 
 def _flash_lse_bwd(q_offset, k_offset, prefix_len, block_q, block_k,
-                   interpret, stream, res, cots):
+                   interpret, stream, window, res, cots):
     g_o, g_lse = cots
     return _flash_bwd_core(q_offset, k_offset, prefix_len, block_q, block_k,
-                           interpret, stream, res, g_o, g_lse)
+                           interpret, stream, res, g_o, g_lse, window)
 
 
 flash_attention_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)

@@ -795,6 +795,7 @@ def loss_with_moe_aux(model, params, model_state, x, y, train, compute_dtype,
     from ddlbench_tpu.models.layers import apply_model, resolve_ties
     from ddlbench_tpu.models.moe import collect_aux_losses
 
+    remat = remat or model.remat_layers
     # a tied leaf goes to its reader HERE, inside what the caller
     # differentiates: both uses' gradients meet in the one leaf
     p = resolve_ties(model.ties, cast_params(params, compute_dtype,

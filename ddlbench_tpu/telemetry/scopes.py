@@ -49,7 +49,7 @@ LATENT = "latent"    # kanana2: kv_a, the latent's norm, kv_b, k and v assembled
 ROUTE = "route"      # router, top-k, sort, gather, weighted scatter
 EXPERTS = "experts"  # the grouped products over the experts held (dropless)
 PARTS = (LATENT, ROUTE, EXPERTS)
-# two parts INSIDE a kind or a part above. They are not in PARTS (an accepted
+# three parts INSIDE a kind or a part above. They are not in PARTS (an accepted
 # test of tests/benchmark/ holds PARTS to the three that the accepted part
 # metrics list): a metric whose file lists them reads the innermost, one
 # whose file lists the three counts them as the scope around them (cca_mix
@@ -57,9 +57,15 @@ PARTS = (LATENT, ROUTE, EXPERTS)
 # zaya, inside attn: value shift, q-k mean, both causal convolutions, the L2
 # normalisation (the projections, RoPE, the flash kernels, W_o stay attn)
 CCA_MIX = "cca_mix"
-# zaya, inside route: W_d, the carried state, the MLP, softmax, argmax (sort,
-# gather and weighted scatter stay route)
+# the family's router, inside route — zaya: W_d, the carried state, the MLP,
+# softmax, argmax; smallthinker: the logits' matmul, top-6 and the softmax over
+# the chosen, where they run (before attention) — sort, gather and weighted
+# scatter stay route
 ROUTER = "router"
+# smallthinker, inside attn: a WINDOW layer's attention core, RoPE and the
+# flash kernels under their window (the projections and W_o stay attn; a
+# global layer's core is attn alone)
+WINDOW = "window"
 
 # step phases outside the differentiated model
 OPTIMIZER = "optimizer"  # common.make_optimizer's update

@@ -30,7 +30,7 @@ jax.config.update("jax_platforms", "cpu")
 
 import pytest  # noqa: E402
 
-from ddlbench_tpu.models import kanana2, zaya  # noqa: E402
+from ddlbench_tpu.models import kanana2, smallthinker, zaya  # noqa: E402
 
 # The test size of the kanana2 family (tests/test_kanana2.py, the rehearsal
 # configuration under tests/benchmark/data/kanana2): every code path, 1-core
@@ -46,6 +46,15 @@ kanana2.FAMILY["kanana2_t"] = kanana2.Dims(
 zaya.FAMILY["zaya_t"] = zaya.Dims(
     d_model=64, n_heads=4, n_kv_heads=2, head_dim=16, conv_taps=(2, 2),
     rotary=8, router_dim=32, expert_ff=48, n_experts=8, n_layers=3)
+
+# The test size of the smallthinker family (tests/test_smallthinker.py, the
+# rehearsal configuration under tests/benchmark/data/smallthinker): two
+# periods of the layout (global, window, window, window), a window shorter
+# than the tests' 64 tokens, 4 query heads over 2 key/value heads, 8 experts,
+# 3 a token.
+smallthinker.FAMILY["smallthinker_t"] = smallthinker.Dims(
+    d_model=64, n_heads=4, n_kv_heads=2, head_dim=16, expert_ff=24,
+    n_experts=8, top_k=3, window=24, layout=(0, 1, 1, 1) * 2)
 
 
 def pytest_addoption(parser):

@@ -777,3 +777,163 @@ def test_equal_head_counts_lower_to_the_kernels_they_did(case):
     text = jax.jit(jax.grad(f, argnums=(0, 1, 2))).lower(q, q, v).as_text()
     assert hashlib.sha256(_unnumbered(text).encode()).hexdigest() == \
         EQUAL_HEADS_AT_PARENT[case]
+
+
+# ---------------------------------------------------------------------------
+# a sliding window on top of the causal rule (models/smallthinker.py)
+# ---------------------------------------------------------------------------
+
+
+def _banded_einsum(q, k, v, window, q_offset=0, k_offset=0):
+    """The plain thing: query i on the keys j with i - window < j <= i
+    (absolute positions), the groups of a grouped call written out."""
+    G = q.shape[1] // k.shape[1]
+    k, v = jnp.repeat(k, G, axis=1), jnp.repeat(v, G, axis=1)
+    qp = q_offset + jnp.arange(q.shape[2])[:, None]
+    kp = k_offset + jnp.arange(k.shape[2])[None, :]
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    s = jnp.where((kp <= qp) & (kp > qp - window), s, -jnp.inf)
+    return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), v)
+
+
+# (window, Tq, Tk, q_offset, k_offset) at 32 x 32 tiles, 14 query heads over
+# 2 key/value heads
+WINDOW_CASES = {
+    "inside_a_block": (8, 128, 128, 0, 0),
+    "two_blocks": (64, 128, 128, 0, 0),
+    "cuts_a_block": (40, 128, 128, 0, 0),
+    "one_key": (1, 128, 128, 0, 0),
+    "ring_block": (40, 64, 128, 64, 0),
+    "reaches_every_key": (128, 128, 128, 0, 0),
+    "past_the_sequence": (1000, 128, 128, 0, 0),
+}
+
+
+@pytest.mark.parametrize("design", ["resident", "streaming"])
+@pytest.mark.parametrize("case", WINDOW_CASES)
+def test_window_forward_and_gradients(case, design, monkeypatch):
+    """The windowed kernels of both grid designs — picked by the rule's own
+    accounting, the budget put out of reach on either side — against the
+    banded einsum: the forward and all three gradients, 14 query heads over
+    2 key/value heads. causal_attention's einsum path is that banded einsum
+    too. A window that reaches every key is the plain call: bit-equal, and
+    the same lowered text."""
+    window, Tq, Tk, qoff, koff = WINDOW_CASES[case]
+    monkeypatch.setattr(fa, "RESIDENT_VMEM_BUDGET",
+                        0 if design == "streaming" else 1 << 40)
+    ks = jax.random.split(jax.random.key(window), 4)
+    q, g = _rand((1, 14, Tq, 16), ks[0]), _rand((1, 14, Tq, 16), ks[3])
+    k, v = _rand((1, 2, Tk, 16), ks[1]), _rand((1, 2, Tk, 16), ks[2])
+    flash = lambda w: lambda q, k, v: flash_attention(
+        q, k, v, qoff, koff, 0, 32, 32, True, None, w)
+    names = _kernel_names(jax.grad(lambda *a: jnp.sum(flash(window)(*a) * g),
+                                   argnums=(0, 1, 2)), q, k, v)
+    assert names == ({"flash_attn_fwd", "flash_attn_dq", "flash_attn_dkv"}
+                     if design == "streaming" else
+                     {"flash_attn_fwd", "flash_attn_dq_dkv"})
+    with jax.default_matmul_precision("highest"):
+        ref, ref_vjp = jax.vjp(functools.partial(
+            _banded_einsum, window=window, q_offset=qoff, k_offset=koff),
+            q, k, v)
+        xla = causal_attention(q, k, v, qoff, koff, window=window)
+        np.testing.assert_allclose(np.asarray(xla), np.asarray(ref),
+                                   atol=2e-5)
+        got, got_vjp = jax.vjp(flash(window), q, k, v)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   atol=2e-5)
+        for a, b, name in zip(got_vjp(g), ref_vjp(g), "qkv"):
+            assert a.shape == b.shape
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=1e-4, err_msg=f"d{name}")
+        if window >= Tk:
+            plain, plain_vjp = jax.vjp(flash(0), q, k, v)
+            assert np.array_equal(np.asarray(got), np.asarray(plain))
+            for a, b in zip(got_vjp(g), plain_vjp(g)):
+                assert np.array_equal(np.asarray(a), np.asarray(b))
+    if window >= Tk:
+        text = lambda w: jax.jit(jax.grad(
+            lambda *a: jnp.sum(flash(w)(*a) * g), argnums=(0, 1, 2))).lower(
+                q, k, v).as_text()
+        assert text(window) == text(0)
+    else:
+        assert not np.allclose(np.asarray(got), np.asarray(
+            _banded_einsum(q, k, v, Tq + Tk + qoff, qoff, koff)), atol=1e-3)
+
+
+def test_window_through_the_dispatch_and_the_lse():
+    """The forced kernel takes causal_attention's window; flash_attention_lse
+    takes it too, and its lse is the banded scores' logsumexp; a window with
+    a prefix is refused on both paths."""
+    ks = jax.random.split(jax.random.key(7), 3)
+    q = _rand((1, 4, 64, 16), ks[0])
+    k, v = _rand((1, 2, 64, 16), ks[1]), _rand((1, 2, 64, 16), ks[2])
+    with jax.default_matmul_precision("highest"):
+        want = _banded_einsum(q, k, v, 24)
+        got = transformer.causal_attention(q, k, v, backend="flash",
+                                           window=24)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=2e-5)
+        o, lse = flash_attention_lse(q, k, v, 0, 0, 0, 16, 16, True, None, 24)
+        np.testing.assert_allclose(np.asarray(o), np.asarray(want),
+                                   atol=2e-5)
+        pos = jnp.arange(64)
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, jnp.repeat(k, 2, axis=1)) / 4.0
+        seen = (pos[None, :] <= pos[:, None]) & \
+            (pos[None, :] > pos[:, None] - 24)
+        np.testing.assert_allclose(
+            np.asarray(lse), np.asarray(jax.nn.logsumexp(
+                jnp.where(seen, s, -jnp.inf), axis=-1)), atol=2e-5)
+    for attend in (functools.partial(transformer.causal_attention,
+                                     backend="flash"), causal_attention):
+        with pytest.raises(ValueError, match="prefix"):
+            attend(q, k, v, prefix_len=8, window=24)
+    with pytest.raises(ValueError, match="negative"):
+        flash_attention(q, k, v, 0, 0, 0, 16, 16, True, None, -1)
+
+
+@pytest.mark.parametrize("T,bq,bk,window,qoff,koff,tiles", [
+    (16384, 512, 512, 4096, 0, 0, (252, 528)),  # smallthinker-t16k-train
+    (1024, 256, 256, 256, 0, 0, None), (1024, 128, 256, 300, 0, 0, None),
+    (1024, 256, 128, 1, 0, 0, None), (128, 32, 32, 40, 64, 0, None),
+    (64, 16, 16, 24, 40, 24, None), (64, 16, 16, 8, 0, 10, None)])
+def test_window_sweeps_visit_the_live_tiles_alone(T, bq, bk, window, qoff,
+                                                  koff, tiles):
+    """By the bounds' own functions, no kernel run: the forward sweeps the K
+    blocks [first, bound) of a Q block, the backward the Q blocks [start,
+    end) of a K block; both visit exactly the tiles that hold a visible
+    (query, key) pair. At the cell's shape that is 252 of the 528 causal
+    tiles, and the pairs inside them are what ``flash_attn_banded.work``
+    counts."""
+    from benchmarks.kernels import flash_attn_banded
+
+    nq, nk = T // bq, T // bk
+
+    def live(i, j):  # some kp <= qp with kp > qp - window
+        q_lo, k_lo = qoff + i * bq, koff + j * bk
+        return k_lo <= q_lo + bq - 1 and k_lo + bk - 1 > q_lo - window
+
+    fwd = {(i, j) for i in range(nq) for j in range(
+        int(fa._window_kv_start(i, bq, qoff, koff, bk, nk, window)),
+        int(fa._causal_kv_bound(qoff + (i + 1) * bq - 1, koff, bk, nk)))}
+    bwd = {(i, j) for j in range(nk) for i in range(
+        int(fa._first_q_block(koff + j * bk, qoff, bq, nq)),
+        int(fa._window_q_end(j, bk, koff, qoff, bq, nq, window)))}
+    want = {(i, j) for i in range(nq) for j in range(nk) if live(i, j)}
+    assert fwd == want and bwd == want
+    if tiles is None:
+        return
+    causal = {(i, j) for i in range(nq) for j in range(int(
+        fa._causal_kv_bound(qoff + (i + 1) * bq - 1, koff, bk, nk)))}
+    assert (len(want), len(causal)) == tiles
+    inside = 0
+    for i, j in want:
+        qp = i * bq + np.arange(bq)[:, None]
+        kp = j * bk + np.arange(bk)[None, :]
+        inside += int(np.sum((kp <= qp) & (kp > qp - window)))
+    assert inside == flash_attn_banded.pairs(T, window) == \
+        window * T - window * (window - 1) // 2
+    flops, nbytes = flash_attn_banded.work(1, 28, T, 128, window)
+    assert flops == 6 * 2.0 * 28 * 128 * inside
+    assert nbytes == 12.0 * 28 * T * 128 * 2
+    assert flash_attn_banded.pairs(T, 0) == T * (T + 1) / 2 \
+        == flash_attn_banded.pairs(T, T)

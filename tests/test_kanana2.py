@@ -410,8 +410,8 @@ def test_the_pallas_grouped_product_is_ragged_dot():
                                    np.asarray(dot(a, w)[:valid]), atol=1e-4)
     pe = {"w_gate": w, "w_up": w + 0.05,
           "w_down": jnp.swapaxes(w, 1, 2) * 0.5}
-    y, vjp = jax.vjp(lambda a: dropless._grouped_swiglu(
-        pe, a, sizes, kanana2.GMM_TILING), a)
+    y, vjp = jax.vjp(lambda a: dropless._grouped_glu(
+        pe, a, sizes, kanana2.GMM_TILING, jax.nn.silu), a)
     assert not np.any(np.asarray(y[valid:]))
     assert not np.any(np.asarray(vjp(jnp.ones_like(y))[0][valid:]))
 
@@ -452,8 +452,8 @@ def test_the_grouped_swiglu_keeps_out_what_the_products_leave_past_the_runs(
         dot.defvjp(fwd, bwd)
         return dot(a, w)
 
-    swiglu = lambda pe, rows: dropless._grouped_swiglu(
-        pe, rows, sizes, kanana2.GMM_TILING)
+    swiglu = lambda pe, rows: dropless._grouped_glu(
+        pe, rows, sizes, kanana2.GMM_TILING, jax.nn.silu)
     loss = lambda pe, rows: jnp.sum(jnp.sin(swiglu(pe, rows)))
     want = jax.value_and_grad(loss, (0, 1))(pe, rows)
     monkeypatch.setattr(dropless, "grouped_dot", stops_at_the_sum)

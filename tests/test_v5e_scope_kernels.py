@@ -327,3 +327,53 @@ def test_the_compressed_attention_block_compiles_with_named_kernels(
     assert all(any(e in n for e in EVENTS) for n in products), products
     assert all("/route/" in found[n] and "/experts/" in found[n]
                and "/router/" not in found[n] for n in products)
+
+
+@pytest.mark.parametrize("windowed", [False, True], ids=["global", "window"])
+def test_the_window_and_global_blocks_compile_with_named_kernels(
+        one_chip, as_on_tpu, windowed):
+    """smallthinker-21b-a3b's two kinds of layer at their published widths
+    and the cell's batch (1 x 16384): 28 query heads over 4 key/value heads
+    in the flash kernels — the forward resident, the backward the one-pass
+    kernel, with a window of 4,096 or none —, under the block's ``attn`` and,
+    on a window layer alone, under ``window``; the grouped products' Pallas
+    kernels under ``route`` / ``experts``, not under ``router``."""
+    from ddlbench_tpu.models import smallthinker
+    from ddlbench_tpu.models.layers import apply_slice
+
+    dims = smallthinker.FAMILY["smallthinker_21b_a3b"]
+    block = smallthinker.block("block2", dims, (0, 16), windowed, "auto")
+    T = 16384
+    params, state = jax.eval_shape(
+        lambda k: block.init(k, (T, dims.d_model))[:2], jax.random.key(0))
+    state = jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), state)
+    leaves, tree = jax.tree.flatten(params)
+    router = [path[0].key == "router" for path, _ in
+              jax.tree_util.tree_flatten_with_path(params)[0]]
+
+    def loss(x, *flat):
+        y, _ = apply_slice([block], [jax.tree.unflatten(tree, flat)],
+                           [state], x, True)
+        return y.astype(jnp.float32).sum()
+
+    found = _mosaic_calls(
+        jax.grad(loss), one_chip, ((1, T, dims.d_model), jnp.bfloat16),
+        *[(a.shape, jnp.float32 if r else jnp.bfloat16)
+          for a, r in zip(leaves, router)])
+    flash = sorted(n.split(".")[0] for n in found if "flash" in n)
+    assert flash == ["flash_attn_dq_dkv", "flash_attn_fwd"]
+    for n, op in found.items():
+        if "flash" in n:
+            assert "(block2)" in op and "/attn/" in op, op
+            assert ("/attn/window/" in op) == windowed, op
+    from benchmarks.kernels.flash_attn_banded import EVENTS as FLASH
+    from benchmarks.kernels.moe_gmm import EVENTS
+
+    assert all(any(e in n for e in FLASH) for n in found if "flash" in n)
+    products = [n for n in found if "flash" not in n]
+    # the common buffer (2 x the balanced 24,576 slots) and the cond's
+    # second one, each 3 forward + 3 for the rows' gradient
+    assert len(products) == 2 * 6
+    assert all(any(e in n for e in EVENTS) for n in products), products
+    assert all("/route/" in found[n] and "/experts/" in found[n]
+               and "/router/" not in found[n] for n in products)
