@@ -258,8 +258,8 @@ def dense_block(name: str, dims: Dims, attention_backend: str) -> Layer:
 def expert_block(name: str, dims: Dims, held: Tuple[int, int],
                  attention_backend: str) -> Layer:
     """Its state holds the step's routing counters (``moe/held_slots``,
-    ``moe/load_max_over_mean``): outputs of the apply, so they leave a
-    rematerialized layer like BatchNorm's statistics do."""
+    ``moe/load_max_over_mean``, ``moe/buffer_fill``): outputs of the apply,
+    so they leave a rematerialized layer like BatchNorm's statistics do."""
     count = held[1]
 
     def init(key, in_shape):
@@ -277,8 +277,7 @@ def expert_block(name: str, dims: Dims, held: Tuple[int, int],
             shared=_swiglu_init(ks[2], d, dims.n_shared * f),
             experts={"w_gate": stack(kg, d, f), "w_up": stack(ku, d, f),
                      "w_down": stack(kd, f, d)})
-        state = {"moe": {"held_slots": jnp.float32(0.0),
-                         "load_max_over_mean": jnp.float32(0.0)}}
+        state = {"moe": dropless.initial_counters()}
         return p, state, (T, d)
 
     def apply(p, s, x, train):

@@ -725,9 +725,10 @@ def inverted_residual(name: str, out_ch: int, stride: int, expand: int) -> Layer
 def routing_counters(model_state):
     """The step's routing counters out of a model state, {} for a model
     without expert layers (whose state holds them under ``"moe"``,
-    models/kanana2.expert_block, models/zaya.hybrid_block): held slots
-    summed over the layers, the load ratio of the most uneven layer and,
-    where the layers count it, the mean weight of the one expert chosen."""
+    models/dropless.initial_counters): held slots summed over the layers,
+    the load ratio of the most uneven layer, the fill of the fullest
+    layer's common buffer and, where the layers count it, the mean weight
+    of the one expert chosen."""
     found = [s["moe"] for s in model_state
              if isinstance(s, dict) and "moe" in s]
     if not found:
@@ -735,6 +736,9 @@ def routing_counters(model_state):
     out = {"moe_held_slots": sum(c["held_slots"] for c in found),
            "moe_load_max_over_mean": jnp.max(jnp.stack(
                [c["load_max_over_mean"] for c in found]))}
+    fill = [c["buffer_fill"] for c in found if "buffer_fill" in c]
+    if fill:
+        out["moe_buffer_fill"] = jnp.max(jnp.stack(fill))
     top1 = [c["top1_weight_mean"] for c in found if "top1_weight_mean" in c]
     if top1:
         out["moe_top1_weight_mean"] = sum(top1) / len(top1)

@@ -138,8 +138,8 @@ def block(name: str, dims: Dims, held: Tuple[int, int], windowed: bool,
           attention_backend: str) -> Layer:
     """One layer of the kind ``windowed`` says. Its state holds the step's
     routing counters (``moe/held_slots``, ``moe/load_max_over_mean``,
-    ``moe/top1_weight_mean``): outputs of the apply, so they leave a
-    rematerialized layer like BatchNorm's statistics do."""
+    ``moe/buffer_fill``, ``moe/top1_weight_mean``): outputs of the apply,
+    so they leave a rematerialized layer like BatchNorm's statistics do."""
     count = held[1]
 
     def init(key, in_shape):
@@ -160,9 +160,7 @@ def block(name: str, dims: Dims, held: Tuple[int, int], windowed: bool,
              "experts": {"w_gate": stack(ks[5], D, f),
                          "w_up": stack(ks[6], D, f),
                          "w_down": stack(ks[7], f, D)}}
-        state = {"moe": {"held_slots": jnp.float32(0.0),
-                         "load_max_over_mean": jnp.float32(0.0),
-                         "top1_weight_mean": jnp.float32(0.0)}}
+        state = {"moe": dropless.initial_counters("top1_weight_mean")}
         return p, state, (T, D)
 
     def apply(p, s, x, train):

@@ -330,7 +330,8 @@ def hybrid_block(name: str, dims: Dims, held: Tuple[int, int],
     the selection bias (``select_bias``: nought at the start, moved by
     ``balance`` in every training step, left alone in evaluation) and the
     step's routing counters (``moe/held_slots``,
-    ``moe/load_max_over_mean``, ``moe/top1_weight_mean``): outputs of the
+    ``moe/load_max_over_mean``, ``moe/buffer_fill``,
+    ``moe/top1_weight_mean``): outputs of the
     apply, so they leave a rematerialized layer like BatchNorm's statistics
     do."""
     count = held[1]
@@ -346,9 +347,7 @@ def hybrid_block(name: str, dims: Dims, held: Tuple[int, int],
                  router=_router_init(ks[1], dims, carried=not first),
                  experts=stack(ks[2]), merge_moe=_merge_init(D))
         state = {"select_bias": jnp.zeros((dims.n_experts,), jnp.float32),
-                 "moe": {"held_slots": jnp.float32(0.0),
-                         "load_max_over_mean": jnp.float32(0.0),
-                         "top1_weight_mean": jnp.float32(0.0)}}
+                 "moe": dropless.initial_counters("top1_weight_mean")}
         out = (T, D) if last else ((T, D), (T, dims.router_dim))
         return p, state, out
 

@@ -756,6 +756,7 @@ def _run_benchmark(cfg: RunConfig, strategy, data, logger: MetricLogger,
                             # an expert model's routing counters of this
                             # step (the interval's sync has just landed)
                             for key in ("moe_held_slots",
+                                        "moe_buffer_fill",
                                         "moe_load_max_over_mean",
                                         "moe_top1_weight_mean"):
                                 if key in metrics:

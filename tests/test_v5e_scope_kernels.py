@@ -377,3 +377,74 @@ def test_the_window_and_global_blocks_compile_with_named_kernels(
     assert all(any(e in n for e in EVENTS) for n in products), products
     assert all("/route/" in found[n] and "/experts/" in found[n]
                and "/router/" not in found[n] for n in products)
+
+
+def _called(line: str):
+    """The computations an HLO instruction calls."""
+    names = re.findall(r"(?:calls|to_apply|body|condition|true_computation|"
+                       r"false_computation)=%?([\w.\-]+)", line)
+    for group in re.findall(r"branch_computations=\{([^}]*)\}", line):
+        names += re.findall(r"%?([\w.\-]+)", group)
+    return names
+
+
+def test_the_common_buffer_scatters_nothing_on_the_chip(one_chip, as_on_tpu):
+    """kanana2_t-e4r1's whole train step as XLA:TPU compiles it, 8 x 64
+    tokens, bf16, remat: 1,536 token-slots and a common buffer of 1,024
+    rows, so each expert layer has the cond whose taken branch runs the
+    slots past the buffer. Under ``route``, a scatter (XLA:TPU's slow way
+    to sum rows) is left only in what that branch calls: the common
+    buffer's rows move by gathers, forward and backward, and the branch
+    keeps the gather and scatter-add around its grouped products."""
+    from ddlbench_tpu import config as pcfg
+    from ddlbench_tpu.models import dropless
+    from ddlbench_tpu.parallel import make_strategy
+
+    assert dropless.buffer_rows(8 * 64 * 3, 16, 4) == 1024
+    name = "kanana2-v5e-64"
+    pcfg.DATASETS[name] = pcfg.DatasetSpec(name, (64,), 128, 1 << 20, 1 << 10,
+                                           kind="tokens")
+    try:
+        cfg = pcfg.RunConfig(benchmark=name, arch="kanana2_t-e4r1",
+                             strategy="single", num_devices=1, batch_size=8,
+                             compute_dtype="bfloat16", remat_layers=True,
+                             # the fused head's blocks want a width of 128
+                             fused_head_loss=False, optimizer="adam",
+                             lr=1e-3)
+        cfg.validate()
+        s = make_strategy(cfg)
+        on_chip = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                                 sharding=one_chip)
+        state = jax.tree.map(on_chip, jax.eval_shape(s.init,
+                                                     jax.random.key(0)))
+        x = on_chip(jax.ShapeDtypeStruct((8, 64), jnp.int32))
+        text = s.train_step.lower(
+            state, x, x, on_chip(jax.ShapeDtypeStruct((), jnp.float32))
+        ).compile().as_text()
+    finally:
+        del pcfg.DATASETS[name]
+    comps, current = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY\s+)?%?([\w.\-]+) \(.*\) -> .*\{$", line)
+        if head:
+            current = head.group(1)
+            comps[current] = []
+        elif current is not None:
+            comps[current].append(line)
+    # everything the conditionals' branches reach
+    todo = [c for lines in comps.values() for line in lines
+            if " conditional(" in line for c in _called(line)]
+    assert todo, "the step has no conditional"
+    branch = set()
+    while todo:
+        c = todo.pop()
+        if c not in branch:
+            branch.add(c)
+            todo += [d for line in comps.get(c, ()) for d in _called(line)]
+
+    def route(kind, inside):
+        return [line for c, lines in comps.items() if (c in branch) == inside
+                for line in lines if f" {kind}(" in line and "/route/" in line]
+
+    assert route("scatter", True), "the branch keeps its scatter-add"
+    assert not route("scatter", False), route("scatter", False)[:3]
