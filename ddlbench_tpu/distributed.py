@@ -59,7 +59,12 @@ def enable_compilation_cache() -> str:
     before its first compile: repeat invocations reuse compiled executables
     keyed by HLO hash. Where ``JAX_COMPILATION_CACHE_DIR`` is set jax
     already reads it and no directory is set in code; otherwise the cache
-    lives at ``<repo>/.jax_cache``. Returns the directory in use."""
+    lives at ``<repo>/.jax_cache``. Also installs the process's tally of
+    jax's compile events (``telemetry/compiles.py``), once. Returns the
+    directory in use."""
+    from ddlbench_tpu.telemetry import compiles
+
+    compiles.install()
     cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not cache_dir:
         cache_dir = _REPO_CACHE_DIR

@@ -27,6 +27,11 @@ This package is the decomposition layer the ROADMAP north star needs:
   out of the optimized HLO) cross-checked EXACTLY against the analytic
   models (``comm_stats`` wire bytes, the planner's HBM model,
   ``pool_page_bytes``), plus the ``auditbench diff`` regression gate.
+* :mod:`telemetry.compiles` — jax's own compile path, tallied: each
+  trace, lowering, compile or cache load with its seconds and the stamp it
+  ended at on the tracer's clock, installed once per process by
+  ``distributed.enable_compilation_cache``; ``compile/<kind>`` ring events
+  while the ring is on.
 
 Host spans align with device traces through
 ``jax.profiler.StepTraceAnnotation`` wrapping in ``train/loop.py`` and the
@@ -43,6 +48,7 @@ from ddlbench_tpu.telemetry.tracer import (  # noqa: F401
     get_tracer,
     set_tracer,
 )
+from ddlbench_tpu.telemetry.compiles import CompileTally  # noqa: F401
 from ddlbench_tpu.telemetry.export import (  # noqa: F401
     export_chrome_trace,
     trace_truncation,

@@ -18,7 +18,10 @@ every sample a true device-step latency.
 
 Warmup/compile accounting is explicit: XLA's first-compile seconds are
 clocked separately (``warmup_compile_s``) and NEVER mixed into the step
-distribution, so percentiles describe steady-state only.
+distribution, so percentiles describe steady-state only. Where jax's compile
+tally is installed (``telemetry/compiles.py``) the block is split too:
+``warmup_trace_lower_s``, ``warmup_backend_s`` (compile or cache load),
+``warmup_cache_misses`` and ``warmup_cache_hits``.
 """
 
 from __future__ import annotations
@@ -182,14 +185,19 @@ class StepLatencyStats:
     def __init__(self) -> None:
         self._epochs: Dict[int, List[float]] = {}
         self.warmup_compile_s: Optional[float] = None
+        self.warmup_split: Dict[str, float] = {}
 
     def record_step(self, epoch: int, seconds: float) -> None:
         self._epochs.setdefault(epoch, []).append(seconds)
 
-    def set_warmup(self, seconds: float) -> None:
+    def set_warmup(self, seconds: float,
+                   split: Optional[Dict[str, float]] = None) -> None:
         """Clock the out-of-band warmup/compile block (train/loop.py runs
-        it on a throwaway state before the measured epochs)."""
+        it on a throwaway state before the measured epochs). ``split``:
+        what jax's compile tally says of the block
+        (``telemetry.compiles.between``), reported as ``warmup_<key>``."""
         self.warmup_compile_s = seconds
+        self.warmup_split = dict(split or {})
 
     def epoch_summary(self, epoch: int) -> Optional[Dict[str, float]]:
         samples = self._epochs.get(epoch)
@@ -206,4 +214,6 @@ class StepLatencyStats:
         out = latency_summary(samples)
         if self.warmup_compile_s is not None:
             out["warmup_compile_s"] = self.warmup_compile_s
+            out.update({f"warmup_{k}": v
+                        for k, v in self.warmup_split.items()})
         return out

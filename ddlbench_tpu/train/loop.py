@@ -25,7 +25,7 @@ from ddlbench_tpu.data.synthetic import make_synthetic
 from ddlbench_tpu.guard import (GracefulPreemption, GuardRewind,
                                 PreemptionHandler, StabilityGuard)
 from ddlbench_tpu.parallel.api import make_strategy
-from ddlbench_tpu.telemetry import (StepLatencyStats, Tracer,
+from ddlbench_tpu.telemetry import (StepLatencyStats, Tracer, compiles,
                                     export_chrome_trace, get_tracer,
                                     set_tracer)
 from ddlbench_tpu.train.metrics import MetricLogger
@@ -415,23 +415,26 @@ def _run_benchmark(cfg: RunConfig, strategy, data, logger: MetricLogger,
     # imagenet_pytorch.py:58-66). Runs on a throwaway state so the measured run
     # starts from pristine params/momentum/BN stats. The wall time is kept
     # as the run's explicit warmup/compile accounting — never mixed into
-    # the step-latency distribution.
+    # the step-latency distribution. jax's compile tally
+    # (telemetry/compiles.py) splits it into trace + lower, compile or cache
+    # load, and the programs the persistent cache lacked.
     if warmup_steps > 0:
         t_warm = time.perf_counter_ns()
-        ts_warm = strategy.init(jax.random.key(cfg.seed))
-        batch = strategy.shard_batch(*data.batch(epoch=0, step=0))
-        for _ in range(warmup_steps):
-            ts_warm, m = strategy.train_step(ts_warm, *batch,
-                                             jnp.float32(base_lr))
-        jax.block_until_ready(m)
-        if wd:
-            # also compile eval_step now, so the watchdog deadline (armed
-            # below) never spans a first-eval XLA compile
-            jax.block_until_ready(strategy.eval_step(ts_warm, *batch))
-        del ts_warm
+        with tracer.span("warmup_compile"):
+            ts_warm = strategy.init(jax.random.key(cfg.seed))
+            batch = strategy.shard_batch(*data.batch(epoch=0, step=0))
+            for _ in range(warmup_steps):
+                ts_warm, m = strategy.train_step(ts_warm, *batch,
+                                                 jnp.float32(base_lr))
+            jax.block_until_ready(m)
+            if wd:
+                # also compile eval_step now, so the watchdog deadline
+                # (armed below) never spans a first-eval XLA compile
+                jax.block_until_ready(strategy.eval_step(ts_warm, *batch))
+            del ts_warm
         t_warm_end = time.perf_counter_ns()
-        stats.set_warmup((t_warm_end - t_warm) / 1e9)
-        tracer.complete("warmup_compile", t_warm, t_warm_end)
+        stats.set_warmup((t_warm_end - t_warm) / 1e9,
+                         compiles.between(t_warm, t_warm_end))
 
     ts = strategy.init(jax.random.key(cfg.seed))
 

@@ -201,8 +201,8 @@ class MetricLogger:
                 "step_time_p99_ms": step_time["p99_ms"],
                 "step_time_max_ms": step_time["max_ms"],
             }
-            if "warmup_compile_s" in step_time:
-                extras["warmup_compile_s"] = step_time["warmup_compile_s"]
+            extras.update({k: v for k, v in step_time.items()
+                           if k.startswith("warmup_")})
             record.update(extras)
             result.update(extras)
         if self.epoch_stall_ms:

@@ -67,8 +67,9 @@ def test_ring_off_span_is_a_cheap_profiler_annotation():
 
 def test_spans_land_in_the_profiler_trace(tmp_path):
     """Under a profiler session the program's host spans are ``ddl/<name>``
-    events of the same ``.xplane.pb`` as the device ops, ring on or off;
-    ``complete`` (a region already over) stays ring-only."""
+    events of the same ``.xplane.pb`` as the device ops, ring on or off
+    (the train loop's warm-up block is one); ``complete`` (a region already
+    over, as jax's compile events are when reported) stays ring-only."""
     import glob
 
     import jax
@@ -81,7 +82,9 @@ def test_spans_land_in_the_profiler_trace(tmp_path):
         tr.enable()
         with tr.span("step_sync"):
             pass
-        tr.complete("warmup_compile", 0, 10)
+        with tr.span("warmup_compile"):
+            pass
+        tr.complete("compile/backend", 0, 10)
     finally:
         jax.profiler.stop_trace()
     (path,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
@@ -89,8 +92,9 @@ def test_spans_land_in_the_profiler_trace(tmp_path):
     names = {e.name for plane in data.planes if plane.name.startswith("/host:")
              for line in plane.lines for e in line.events
              if e.name.startswith("ddl/")}
-    assert names == {"ddl/ring_wait", "ddl/step_sync"}
-    assert [e[1] for e in tr.events()] == ["step_sync", "warmup_compile"]
+    assert names == {"ddl/ring_wait", "ddl/step_sync", "ddl/warmup_compile"}
+    assert [e[1] for e in tr.events()] == ["step_sync", "warmup_compile",
+                                           "compile/backend"]
 
 
 def test_span_records_name_duration_and_args():
