@@ -137,16 +137,28 @@ def _grouped_glu(pe, rows, sizes, tiling, act, masked: bool = True):
         return jnp.where(live, dot(act(g) * u, pe["w_down"]), 0)
 
 
+def _read_rows(pos, rows: int):
+    """The row each slot's gather reads, [S, k]: its own where ``pos``
+    names one, else row t mod ``rows`` of its token t, which the sum
+    selects away. Not one row for every empty slot: on a v5e a gather whose
+    indices are mostly one row takes a fifth longer than one of as many
+    distinct rows (PERF.md section 6)."""
+    t = jnp.arange(pos.shape[0], dtype=pos.dtype) % rows
+    return jnp.where(pos >= 0, pos, t[:, None])
+
+
 def _token_sum(rows, w, pos, dtype):
     """``out[t] = sum_j w[t, j] * rows[pos[t, j]]`` over the slots with a
     row (``pos`` >= 0; ``w`` None: unweighted), summed in float32: k
-    gathers of S rows. A slot without a row reads row 0 and is selected
-    away, so nothing in the rows it does not name, a NaN neither, reaches
-    the sum."""
+    gathers of S rows. A slot without a row reads a row of the buffer
+    (``_read_rows``) and is selected away, so nothing in the rows it does
+    not name, a NaN neither, reaches the sum."""
+    read = _read_rows(pos, rows.shape[0])
     out = 0.0
     for j in range(pos.shape[1]):
         at = pos[:, j]
-        x = jnp.take(rows, jnp.maximum(at, 0), axis=0).astype(jnp.float32)
+        x = jnp.take(rows, read[:, j], axis=0,
+                     mode="clip").astype(jnp.float32)
         if w is not None:
             x = x * w[:, j:j + 1]
         out = out + jnp.where(at[:, None] >= 0, x, 0.0)
@@ -158,7 +170,9 @@ def _dispatch(h, slot, pos):
     """The buffer's rows, ``h[slot // k]`` [R, d] (``slot``: the token-slots
     in buffer order; ``pos`` [S, k]: each slot's row in the buffer, -1
     without one). Its transpose sums each token's rows, token-major."""
-    return jnp.take(h, slot // pos.shape[1], axis=0)
+    # in range by construction; the default mode would add a select over
+    # the whole buffer for the indices past the end
+    return jnp.take(h, slot // pos.shape[1], axis=0, mode="clip")
 
 
 def _dispatch_fwd(h, slot, pos):

@@ -140,6 +140,87 @@ def test_the_gathers_move_what_the_gather_and_scatter_move(case,
         np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
 
 
+
+def _positions(case):
+    """``pos`` [S, k] as ``dropless._gathered`` builds it for the case, and
+    the common buffer's rows."""
+    k, n_experts, held, S, how = CASES[case]
+    rng = np.random.default_rng(sorted(CASES).index(case))
+    per_token = _held_per_token(rng, S, k, n_experts, held[1], how)
+    idx = jnp.asarray(_choices(rng, S, k, n_experts, held, per_token))
+    w = jnp.ones((S, k), jnp.float32)
+    order, _, ends, _ = dropless._route(idx, w, held)
+    rows = dropless.buffer_rows(S * k, n_experts, held[1])
+    at = jnp.argsort(order)
+    pos = jnp.where(at < jnp.minimum(ends[-1], rows), at, -1).reshape(S, k)
+    return np.asarray(pos), rows
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_an_empty_slot_reads_a_row_of_its_own(case):
+    """Each gather of the token sum reads a slot's own row where it holds
+    one, and where it does not a row of the buffer that no more than
+    ceil(S / rows) tokens of that gather read: never one row for all."""
+    pos, rows = _positions(case)
+    read = np.asarray(dropless._read_rows(jnp.asarray(pos), rows))
+    S = pos.shape[0]
+    held = pos >= 0
+    np.testing.assert_array_equal(read[held], pos[held])
+    assert ((0 <= read) & (read < rows)).all()
+    for j in range(pos.shape[1]):
+        empty = read[~held[:, j], j]
+        if empty.size:
+            assert np.bincount(empty).max() <= -(-S // rows)
+
+
+# how many of each token's k slots hold a row: 0, 1 and all by turns, and
+# between; none; one token alone
+HELD = {
+    "0-1-all": [0, 1, 6, 2, 0, 6, 1, 3, 6, 0, 1, 4, 5, 6, 0, 1] * 8,
+    "none-held": [0] * 128,
+    "one-token-holds": [0] * 60 + [6] + [0] * 67,
+    "all-held": [6] * 128,
+}
+
+
+@pytest.mark.parametrize("k", [1, 6])
+@pytest.mark.parametrize("weighted", [True, False],
+                         ids=["weighted", "unweighted"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", sorted(HELD))
+def test_the_token_sum_is_the_sum_of_the_held_rows(case, dtype, weighted, k):
+    """``_token_sum`` against numpy: each token's weighted float32 sum of
+    the rows its held slots name, with NaN in every buffer row past the
+    held ones, which the empty slots may read."""
+    per_token = np.minimum(np.asarray(HELD[case]), k)
+    S = len(per_token)
+    rng = np.random.default_rng(sorted(HELD).index(case) + 10 * k)
+    n_held = int(per_token.sum())
+    R = max(n_held, 1) + 96
+    rows_of = rng.permutation(n_held)
+    pos = np.full((S, k), -1, np.int32)
+    at = 0
+    for t, n in enumerate(per_token):
+        pos[t, np.sort(rng.choice(k, n, replace=False))] = \
+            rows_of[at:at + n]
+        at += n
+    rows = rng.normal(size=(R, D)).astype(np.float32)
+    rows[n_held:] = np.nan
+    rows = np.asarray(jnp.asarray(rows, dtype).astype(jnp.float32))
+    w = rng.uniform(0.05, 1.0, (S, k)).astype(np.float32)
+    want = np.zeros((S, D), np.float64)
+    for t in range(S):
+        for j in range(k):
+            if pos[t, j] >= 0:
+                want[t] += (w[t, j] if weighted else 1.0) * \
+                    rows[pos[t, j]].astype(np.float64)
+    got = dropless._token_sum(jnp.asarray(rows, dtype),
+                              jnp.asarray(w) if weighted else None,
+                              jnp.asarray(pos), jnp.float32)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    assert not np.asarray(got)[per_token == 0].any()
+
 def _scatters(jaxpr, in_branch=False, found=None):
     """(scatter-adds outside every cond branch, inside one)."""
     found = found if found is not None else [0, 0]

@@ -461,14 +461,17 @@ def test_validate_refuses_strategies_the_model_is_not_brought_up_on(
 # five, recorded before these, stand in tests/test_zaya.py.
 # Recorded again when the dropless layer's common buffer came to move its
 # rows by gathers alone and its layers to count ``buffer_fill``: both
-# changed the step's text.
+# changed the step's text. Recorded again when the token sum's empty slots
+# came to read a row each of their own in place of row 0
+# (``dropless._read_rows``) and the layer's row gathers to clamp their
+# indices (``mode="clip"``): both changed the step's text.
 STEPS_AT_PARENT = {
     ("zaya_t-e4r1", True, "float32"):
-        "f420e5e0f6f7560dd6b1d5fcac5dde6ba57c9e761343684046e21b6f16e1c014",
+        "4ffd1ecaf54af9b81730c9eeec15d3bbcb04df034ea0626410ed5b33eaa264a2",
     ("zaya_t-e4r1", True, "bfloat16"):
-        "43c352adce8943aa39c4c4b3e4a040639223a4705050d0eedce7870e6019cac3",
+        "b8f80a598b61529d592c81d2a5ab875e4a4bdd14e6f2aaf99e201b09374a5c93",
     ("zaya_t", False, "float32"):
-        "10f3eae8235b829f05a3eabcbce0f548d2790310480320e0c22da87ce0b0b342",
+        "e23b9dbfa7dd718c57cdb491e5ccf3e711cd1a5b8d0cd19c774c09f98aa52fc0",
 }
 
 

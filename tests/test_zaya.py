@@ -575,18 +575,21 @@ def test_the_profiler_refuses_a_pair_valued_boundary(dataset):
 # gave flash_attention a second head count (e009dd1): neither changed an
 # operation of that family's step. Recorded again when the dropless
 # layer's common buffer came to move its rows by gathers alone and its
-# layers to count ``buffer_fill``: both changed the step's text.
+# layers to count ``buffer_fill``: both changed the step's text. Recorded
+# again when the token sum's empty slots came to read a row each of their
+# own in place of row 0 (``dropless._read_rows``) and the layer's row
+# gathers to clamp their indices (``mode="clip"``): both changed the text.
 KANANA2_STEP_AT_PARENT = {
     ("kanana2_t", False, "float32"):
-        "f3b88d4f78478d07a9e602d1d79bd7285b2138ba57f8dfd213b30e9c802037ba",
+        "e2e4d0cd27634e4d05e19d7acee5e9a09cb0dfe0a7d77664d40c166167dd60f7",
     ("kanana2_t", True, "float32"):
-        "6e71a82a9e2e30fe69dd03e440af546d1e4c8f28ce13b50e94e675fed8679ccb",
+        "a3463b3b8e97163f6fa281dfd67c8276ea7a84d2c2b5bdea6fec0bdbf1d7182e",
     ("kanana2_t-e4r1", False, "float32"):
-        "30a4f57c77b5c15ba31d1ed62a1eb61f8e9704ee4272dcb5e4d259f307ee529f",
+        "2c10a1dbd7d884eb0a2762909cdab0e528e08ce2fd4d0612dbedd9df982173d9",
     ("kanana2_t-e4r1", True, "float32"):
-        "0633a8126304f879791f5c33ed3381d74a5fd0c29b181f3dc62b338a73c2edab",
+        "f844e5672b5f6f95c2da53e3b7362b50d971b7111e55ff9d862b4db4f0a29e20",
     ("kanana2_t-e4r1", True, "bfloat16"):
-        "6dd5c0b5cba52323785e173cba35f8f8467e0297ff433d389228a5d8749fd8c1",
+        "11e4049d3b44e93c5dc6f8e47079e26fc8e4169d2b7c0a86cab056f6f6cca855",
 }
 
 
